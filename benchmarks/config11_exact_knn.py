@@ -1,5 +1,5 @@
 """Config 11: exact kNN through the PUBLIC NearestNeighbors estimator
-(VERDICT r3 #3 — the families with no benchmark row).
+(the families with no benchmark row).
 
 1M items x 96, 10k queries, k=10 — the same shape as the ANN headline
 (config 7) so the exact/approx gap is directly readable. Device-resident
@@ -13,12 +13,14 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from benchmarks.common import bytes_roofline, emit, roofline, time_amortized
+from benchmarks.common import bytes_roofline, emit, require_chip, roofline, time_amortized
 
 N_ITEMS, D, N_QUERIES, K = 1_000_000, 96, 10_000, 10
 
 
 def main() -> None:
+    require_chip()
+
     import jax
     import jax.numpy as jnp
 

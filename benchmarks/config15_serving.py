@@ -26,7 +26,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from benchmarks.common import bytes_roofline, emit, time_amortized
+from benchmarks.common import bytes_roofline, emit, require_chip, time_amortized
 from spark_rapids_ml_tpu.utils.envknobs import env_int
 
 N = env_int("TPUML_BENCH_ROWS", 1_000_000)
@@ -36,6 +36,8 @@ BLOCK = env_int("TPUML_BENCH_BLOCK", 131_072)
 
 
 def main() -> None:
+    require_chip()
+
     import jax
     import jax.numpy as jnp
     import numpy as np

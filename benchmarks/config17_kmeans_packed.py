@@ -1,5 +1,4 @@
-"""Config 17: KMeans small-d lane packing shoot-out (BASELINE.md
-"KMeans lane packing").
+"""Config 17: KMeans small-d lane packing shoot-out.
 
 At d=16 the fused assignment kernel wastes 7/8 of every MXU tile: the
 (8, 128) x (128, 128) systolic step contracts only 16 live lanes. The
@@ -14,8 +13,8 @@ the packable small-k regime (config 3's k=100 stays on the unpacked
 kernel, and `packed_feasible` routes it there). Off-TPU the Pallas kernels
 only run under the interpreter (which times the interpreter, not the
 layout), so the shoot-out falls back to the XLA GEMM-shape proxy of the
-SAME two shape pairs — the measurement behind the 4.93x CPU figure in
-BASELINE.md.
+SAME two shape pairs — a CPU figure (4.93x when last run), never a chip
+speed; every line it prints names the device it ran on.
 """
 
 from __future__ import annotations

@@ -3,12 +3,12 @@
 Synthetic data at the HIGGS shape (zero-egress image: no dataset download).
 
 Since r4 this times the PUBLIC estimator — ``LinearRegression().fit((X, y))``
-with device-resident arrays (VERDICT r3 #1) — not the ops-layer kernels:
+with device-resident arrays — not the ops-layer kernels:
 the normal-equation path (XtX/Xty sufficient-statistics GEMM + jitted
 device solve) runs end-to-end inside the fit, and the model's host views
 convert lazily, so the timed quantity is exactly what a user gets.
 
-Both rooflines reported (VERDICT r3 #2): at d=28 the config is
+Both rooflines reported: at d=28 the config is
 bytes-bound by construction (1.6 kFLOP per 112-byte row), so
 pct_hbm_roofline is the honest utilization figure and pct_ceiling just
 documents how far from MXU-relevant this shape is.
@@ -21,12 +21,14 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from benchmarks.common import bytes_roofline, emit, roofline, time_amortized
+from benchmarks.common import bytes_roofline, emit, require_chip, roofline, time_amortized
 
 N, D = 11_000_000, 28
 
 
 def main() -> None:
+    require_chip()
+
     import jax
     import jax.numpy as jnp
 

@@ -17,12 +17,14 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from benchmarks.common import bytes_roofline, emit, roofline, time_amortized
+from benchmarks.common import bytes_roofline, emit, require_chip, roofline, time_amortized
 
 N, D, K = 1_000_000, 1024, 16
 
 
 def main() -> None:
+    require_chip()
+
     import jax
     import jax.numpy as jnp
     import numpy as np

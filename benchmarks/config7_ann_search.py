@@ -4,7 +4,7 @@ modern RAPIDS Spark-ML line's approximateNearestNeighbors).
 Measures the three single-chip search methods at 1M items x 96 dims,
 10k queries, k=10 — since r4 through the PUBLIC estimator API
 (``ApproximateNearestNeighbors().fit(items_dev).kneighbors(q_dev)`` with
-device-resident arrays, VERDICT r3 #1):
+device-resident arrays):
 
   - ``brute_approx`` (dense MXU distance GEMM + hardware approximate
     top-k, ``lax.approx_min_k``) — the headline: the TPU-first result is
@@ -26,12 +26,14 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from benchmarks.common import bytes_roofline, emit, roofline, time_amortized
+from benchmarks.common import bytes_roofline, emit, require_chip, roofline, time_amortized
 
 N_ITEMS, D, N_LISTS, N_QUERIES, N_PROBE, K = 1_000_000, 96, 1024, 10_000, 32, 10
 
 
 def main() -> None:
+    require_chip()
+
     import jax
     import jax.numpy as jnp
     import numpy as np

@@ -119,13 +119,22 @@ class Estimator(Params):
         (streaming sources the runtime cannot re-block, exotic paths)
         re-raises as the structured
         :class:`~spark_rapids_ml_tpu.core.membudget.FitMemoryError` —
-        a raw ``XlaRuntimeError`` never escapes a fit."""
+        a raw ``XlaRuntimeError`` never escapes a fit.
+
+        It also places jax's persistent compilation cache
+        (:func:`~spark_rapids_ml_tpu.core.serving.configure_compile_cache`),
+        so a second process fitting the same shapes replays the compiles
+        from disk."""
+        from spark_rapids_ml_tpu.core.serving import configure_compile_cache
         from spark_rapids_ml_tpu.observability.report import RunRecorder
 
         with RunRecorder("fit", type(self).__name__) as rec:
             try:
                 if self.getDeployMode() == "gang":
                     self._join_gang()
+                # After the gang bring-up: resolving the backend before
+                # jax.distributed.initialize would wedge it.
+                configure_compile_cache()
                 model = self._fit(dataset)
             except RuntimeError as exc:
                 from spark_rapids_ml_tpu.core.membudget import reraise_if_oom

@@ -40,11 +40,13 @@ letting caches grow without limit. This module is the one home for both:
     issued while the program for block k is still running (dispatch is
     async), overlapping transfer with compute.
   - **Persistent compilation cache** (:func:`configure_compile_cache`):
-    ``TPUML_COMPILE_CACHE_DIR`` wires ``jax_compilation_cache_dir`` so a
-    process restart replays compiles from disk instead of paying them
-    cold. Guarded OFF on the CPU backend by default — XLA:CPU's
-    executable (de)serialization has crashed mid-suite on this jaxlib
-    (see tests/conftest.py); ``TPUML_COMPILE_CACHE_FORCE=1`` overrides.
+    a process restart replays compiles from disk instead of paying them
+    cold. ``JAX_COMPILATION_CACHE_DIR`` places it from outside (nothing
+    is then set in code); otherwise, off the CPU, it goes to
+    ``TPUML_COMPILE_CACHE_DIR`` or one fixed path under the checkout.
+    Guarded OFF on the CPU backend by default — XLA:CPU's executable
+    (de)serialization has crashed mid-suite on this jaxlib (see
+    tests/conftest.py); ``TPUML_COMPILE_CACHE_FORCE=1`` overrides.
 
 Residence contract (mirrors the model families'): host batches in, host
 results out; device batches in, device results out. Multi-device (mesh-
@@ -150,24 +152,43 @@ _cache_lock = make_lock("core_serving.cache_wiring")
 _cache_wired: Optional[str] = None  # guarded-by: _cache_lock
 _cache_checked = False  # guarded-by: _cache_lock
 
+JAX_COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+#: Where the cache goes off the CPU when nothing outside names a place:
+#: ONE fixed path beside the package (the checkout's root; git ignores
+#: it). The directory is part of jax's cache key, so a temp-, pid- or
+#: time-made name would never hit.
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_compile_cache",
+)
+
 
 def configure_compile_cache(path: Optional[str] = None, *, force: bool = False):
-    """Wire jax's persistent compilation cache to ``path`` (or the
-    ``TPUML_COMPILE_CACHE_DIR`` knob). Idempotent; returns the active
-    directory or None.
+    """Place jax's persistent compilation cache — the ONE function the
+    estimators' fit path, the serving path, ``bench.py`` and
+    ``chip_smoke.py`` all call. Idempotent; returns the active directory
+    or None.
+
+    Precedence: where ``JAX_COMPILATION_CACHE_DIR`` is set the cache was
+    placed from outside — jax reads that variable itself and this
+    function sets NOTHING in code. Otherwise ``path``, then the repo's
+    own spelling ``TPUML_COMPILE_CACHE_DIR``, then (off the CPU only)
+    :data:`DEFAULT_COMPILE_CACHE_DIR`.
 
     CPU guard: XLA:CPU's AOT (de)serializer has SIGABRT/SIGSEGVed on this
     jaxlib when replaying or writing cache entries (tests/conftest.py
-    documents both crashes), so on the ``cpu`` backend the knob is
-    ignored unless forced (``force=True`` / ``TPUML_COMPILE_CACHE_FORCE=1``).
+    documents both crashes), so on the ``cpu`` backend nothing is wired
+    unless forced (``force=True`` / ``TPUML_COMPILE_CACHE_FORCE=1``).
     """
     global _cache_wired, _cache_checked
     with _cache_lock:
         if _cache_checked and path is None:
             return _cache_wired
         _cache_checked = True
-        path = path or env_str("TPUML_COMPILE_CACHE_DIR")
-        if not path or path == _cache_wired:
+        outside = os.environ.get(JAX_COMPILE_CACHE_ENV)
+        if outside:
+            _cache_wired = outside
             return _cache_wired
         import jax
 
@@ -175,6 +196,9 @@ def configure_compile_cache(path: Optional[str] = None, *, force: bool = False):
             "TPUML_COMPILE_CACHE_FORCE", ("0", "1"), "0"
         ) == "1"
         if jax.default_backend() == "cpu" and not force:
+            return _cache_wired
+        path = path or env_str("TPUML_COMPILE_CACHE_DIR") or DEFAULT_COMPILE_CACHE_DIR
+        if path == _cache_wired:
             return _cache_wired
         os.makedirs(path, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", path)

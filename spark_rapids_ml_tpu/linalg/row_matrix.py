@@ -172,8 +172,9 @@ class RowMatrix:
                     "merge); single-process mesh fits use "
                     "precision='highest'"
                 )
-        # Covariance kernel backend for the GEMM path. Measured on v5e at
-        # 1M x 1024 f32/HIGHEST (BASELINE.md): XLA whole-array fusion 24.9
+        # Covariance kernel backend for the GEMM path. An earlier round's
+        # v5e run at 1M x 1024 f32/HIGHEST (unverified on today's chip)
+        # ordered them: XLA whole-array fusion 24.9
         # TFLOP/s > pallas fused streaming 22.0 > XLA scan-blocked 21.7 —
         # so "xla" is the default and "pallas" is the explicit choice when
         # row blocking is required anyway (it keeps the centered tile and

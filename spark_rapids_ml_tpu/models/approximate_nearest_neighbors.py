@@ -8,16 +8,16 @@ with this param surface: ``k``, ``algorithm`` (default "ivfflat"),
 ``ops/ann.py`` — see its docstring for the dense-tensor redesign of cuML's
 inverted lists), ``brute`` (exact, delegates to ``ops/knn.py``), and
 ``brute_approx`` (dense MXU scoring + the TPU-native hardware approximate
-top-k, ``lax.approx_min_k``). The measured TPU-first result (BASELINE.md
-config 7): at 1M items × 96 dims, ``brute_approx`` answers 10k queries
+top-k, ``lax.approx_min_k``). The TPU-first result of an earlier round's v5e run
+(benchmarks/config7_ann_search.py; unverified on today's chip): at 1M items × 96 dims, ``brute_approx`` answers 10k queries
 ~3.9× faster than ivfflat at ~0.997 recall — TPU gathers are scalarized
 while dense GEMMs ride the systolic array, so the inverted-list
 structure that wins on GPUs loses here at resident scales. Under a mesh,
 ``brute_approx`` runs the hardware per-shard top-k with an exact
 cross-shard merge (``ops/knn.knn_sharded(approx=True)``).
 
-BEYOND single-chip HBM the choice is measured, not assumed (BASELINE.md
-config 8): a re-iterable block source fits a STREAMED brute index
+BEYOND single-chip HBM the choice is measured, not assumed
+(benchmarks/config8_ann_beyond_hbm.py): a re-iterable block source fits a STREAMED brute index
 (``ops/knn.knn_host_streamed`` — running top-k merge, capacity bounded
 by the source). The measured crossover is effectively zero: the
 compressed resident alternative (``ivfpq`` — the only structure whose
@@ -214,17 +214,20 @@ class ApproximateNearestNeighbors(_ANNParams, Estimator, MLReadable):
 
     def fit(self, dataset: Any) -> "ApproximateNearestNeighborsModel":
         """Device arrays are indexed in place for the brute paths — no
-        host round trip (VERDICT r3 #1). IVF builds still pull the items
+        host round trip. IVF builds still pull the items
         to host ONCE (transiently) for the inverted-list packing, which
         is host-side by design (ops/ann.build_ivf_index).
 
         A RE-ITERABLE streaming source becomes a STREAMED brute index
         (``brute``/``brute_approx`` only): items never materialize — each
         search streams blocks through the running top-k merge, so item
-        capacity is bounded by the source, not HBM (VERDICT r3 #4).
-        Inverted lists need the resident (compressed) index; see
-        BASELINE.md config 8 for the measured streaming-vs-ivfpq
-        crossover."""
+        capacity is bounded by the source, not HBM.
+        Inverted lists need the resident (compressed) index;
+        benchmarks/config8_ann_beyond_hbm.py measures the
+        streaming-vs-ivfpq crossover."""
+        from spark_rapids_ml_tpu.core.serving import configure_compile_cache
+
+        configure_compile_cache()
         from spark_rapids_ml_tpu.core.data import (
             is_reiterable_stream,
             is_streaming_source,
@@ -324,7 +327,7 @@ class ApproximateNearestNeighborsModel(_ANNParams, Model, LazyHostState):
     _pickle_clear = ("_items_dev", "_sharded_brute", "_index")
 
     def __getstate__(self):
-        # Same contract as _save_impl (ADVICE r4): a streamed-index model
+        # Same contract as _save_impl: a streamed-index model
         # must not pickle — cloudpickling (Spark broadcast, UDF closures)
         # would either ship the whole item set the streamed mode exists to
         # avoid, or fail opaquely on an unpicklable reader.
@@ -447,7 +450,7 @@ class ApproximateNearestNeighborsModel(_ANNParams, Model, LazyHostState):
         device_q = is_device_array(q_in)
         if device_q:
             # Device queries stay resident: normalize on device, results
-            # return as device arrays (VERDICT r3 #1).
+            # return as device arrays.
             q = q_in.astype(_dtype())
             if metric == "cosine":
                 q = q / jnp.maximum(

@@ -130,8 +130,11 @@ class DBSCAN(_DBSCANParams, Estimator, MLReadable):
         return self
 
     def fit(self, dataset: Any) -> "DBSCANModel":
+        from spark_rapids_ml_tpu.core.serving import configure_compile_cache
+
+        configure_compile_cache()
         # Device arrays are consumed in place — no host round trip
-        # (VERDICT r3 #1); host input densifies straight to compute dtype.
+        #; host input densifies straight to compute dtype.
         x = matrix_like(extract_features(dataset, self.getFeaturesCol()), dtype=_dtype())
         with TraceRange("dbscan fit", TraceColor.RED):
             if self.mesh is not None:

@@ -6,7 +6,7 @@ Param surface mirrors ``org.apache.spark.ml.regression.LinearRegression``:
 solve; > 0 -> Lasso/elastic net via FISTA on the same sufficient
 statistics — solver="normal" rejects it, as in Spark), ``standardization``,
 ``solver`` ("normal" | "auto"). Beyond-the-reference capability
-(BASELINE.md config 4).
+(benchmark config 4).
 """
 
 from __future__ import annotations
@@ -387,7 +387,7 @@ class LinearRegression(_LinearRegressionParams, Estimator, MLReadable):
 
         with TraceRange("linreg fit", TraceColor.DARK_GREEN):
             # One funnel for every residence: device arrays fit in place
-            # (VERDICT r3 #1), host data places once, dtype-preserving.
+            #, host data places once, dtype-preserving.
             xs, mask, n, d = prepare_rows(x_in, mesh=self.mesh, weights=w_host)
             ys = prepare_labels(
                 y_in, int(xs.shape[0]), n_true=n, mesh=self.mesh, dtype=xs.dtype

@@ -366,7 +366,7 @@ class LogisticRegression(_LogisticRegressionParams, Estimator, MLReadable):
 
         with TraceRange("logreg fit", TraceColor.YELLOW):
             # One funnel for every residence: device arrays fit in place
-            # (VERDICT r3 #1), host data places once, dtype-preserving.
+            #, host data places once, dtype-preserving.
             xs, mask, n, d = prepare_rows(x_in, mesh=self.mesh, weights=w_host)
             dtype = xs.dtype
             ys = prepare_labels(
@@ -471,7 +471,7 @@ class LogisticRegression(_LogisticRegressionParams, Estimator, MLReadable):
         """Re-iterable (X_stream, y) sources: multi-pass L-BFGS at
         O(block + d*c) memory — one stats pass (moments + label scan),
         then one data pass per objective evaluation
-        (:func:`ops.logistic.fit_logistic_streaming`). VERDICT r3 #6."""
+        (:func:`ops.logistic.fit_logistic_streaming`)."""
         from spark_rapids_ml_tpu.core.data import is_reiterable_stream
         from spark_rapids_ml_tpu.models.linear_regression import _streaming_blocks
         from spark_rapids_ml_tpu.ops.logistic import (
@@ -792,7 +792,7 @@ class LogisticRegressionModel(_LogisticRegressionParams, Model, LazyHostState):
         # class): numClasses, numFeatures, interceptVector,
         # coefficientMatrix ((1, d) binomial / (C, d) multinomial),
         # isMultinomial — byte-compatible with upstream readers
-        # (VERDICT r4 #6; the SURVEY §3.4 discipline).
+        # (the SURVEY §3.4 discipline).
         save_data(
             path,
             {

@@ -97,13 +97,16 @@ class NearestNeighbors(_NearestNeighborsParams, Estimator, MLReadable):
 
     def fit(self, dataset: Any) -> "NearestNeighborsModel":
         """Index the item set (brute force: store + pre-shard). Device
-        arrays are indexed in place — no host round trip (VERDICT r3 #1).
+        arrays are indexed in place — no host round trip.
 
         A RE-ITERABLE streaming source (iterator factory / block reader)
         becomes a STREAMED index: items never materialize on device or
         host — each ``kneighbors`` call streams the blocks through the
         running top-k merge (``ops.knn.knn_host_streamed``), so item
-        capacity is bounded by the source, not HBM (VERDICT r3 #4)."""
+        capacity is bounded by the source, not HBM."""
+        from spark_rapids_ml_tpu.core.serving import configure_compile_cache
+
+        configure_compile_cache()
         from spark_rapids_ml_tpu.core.data import (
             is_reiterable_stream,
             is_streaming_source,
@@ -183,7 +186,7 @@ class NearestNeighborsModel(_NearestNeighborsParams, Model, LazyHostState):
     _pickle_clear = ("_sharded",)
 
     def __getstate__(self):
-        # Same contract as _save_impl (ADVICE r4): a streamed-index model
+        # Same contract as _save_impl: a streamed-index model
         # must not pickle — cloudpickling (Spark broadcast, UDF closures)
         # would either ship the whole item set the streamed mode exists to
         # avoid, or fail opaquely on an unpicklable reader.

@@ -221,8 +221,9 @@ class PCA(_PCAParams, Estimator, MLReadable):
         return self
 
     def setCovarianceBackend(self, value: str) -> "PCA":
-        """Kernel backend for the covariance GEMM. Measured on v5e
-        (BASELINE.md): "xla" (whole-array fusion) is fastest when the
+        """Kernel backend for the covariance GEMM. An earlier round's v5e
+        run (unverified on today's chip) found "xla" (whole-array fusion)
+        fastest when the
         dataset fits HBM; "pallas" fuses centering + accumulation in VMEM
         and beats the XLA scan path when row-blocking is required."""
         if value not in ("xla", "pallas"):
@@ -425,7 +426,7 @@ class PCA(_PCAParams, Estimator, MLReadable):
     def _fit_randomized(self, rows) -> "PCAModel":
         """Wide-feature path: subspace sketch, no (d, d) covariance.
 
-        Covers every input mode (VERDICT r2 #6): device arrays in place;
+        Covers every input mode: device arrays in place;
         host data on one chip; host partitions over a MESH (row-sharded
         with a padding mask — the sketch GEMMs shard like the covariance,
         one psum per rmatmul, no (d, d) on any device); and re-iterable

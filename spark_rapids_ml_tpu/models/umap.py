@@ -318,7 +318,7 @@ class UMAP(_UMAPParams, Estimator, MLReadable):
             mesh=self.mesh, dtype=np.float32, ledger_families=("umap",),
         )
         # Device arrays are consumed in place — no host round trip
-        # (VERDICT r3 #1); the mesh index upload still wants a host copy,
+        #; the mesh index upload still wants a host copy,
         # which matrix_like keeps for host sources.
         device_in = is_device_array(rows)
         x_in = matrix_like(rows)
@@ -344,7 +344,7 @@ class UMAP(_UMAPParams, Estimator, MLReadable):
                 approx=self.getBuildAlgo() == "brute_approx",
             )
             graph = fuzzy_simplicial_set(idx, dists)
-            # Tail-scatter backend (VERDICT r5 #1): the edge list is static
+            # Tail-scatter backend: the edge list is static
             # per fit, so 'pallas' sorts it by tail ONCE here and the epoch
             # SGD accumulates tail gradients densely per tile instead of
             # XLA's per-element scatter. 'auto' engages it on the TPU

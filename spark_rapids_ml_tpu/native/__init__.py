@@ -125,6 +125,12 @@ def available() -> bool:
     return get_lib() is not None
 
 
+def loaded() -> bool:
+    """Whether this process has the library loaded — a pure query: unlike
+    :func:`available` it never loads, and so never builds, anything."""
+    return _lib is not None
+
+
 def _as_c(arr: np.ndarray, ctype):
     return arr.ctypes.data_as(ctypes.POINTER(ctype))
 

@@ -108,8 +108,7 @@ def build_ivf_index(
     """Train the coarse quantizer and pack the inverted lists.
 
     The quantizer runs on device (k-means++ init + Lloyd — mesh-sharded
-    over the data axis when ``mesh`` is given, closing VERDICT r1 missing
-    item 6); the group-by-list packing is a host-side argsort (one pass,
+    over the data axis when ``mesh`` is given); the group-by-list packing is a host-side argsort (one pass,
     done once at fit time).
     """
     items = np.asarray(items)
@@ -256,7 +255,7 @@ def build_ivfpq_index(
     Builds on the IVF-Flat packer for grouping; the PQ training runs one
     GEMM Lloyd per subspace over the residuals — with a mesh, both the
     coarse quantizer AND each codebook Lloyd shard their rows over the
-    data axis (VERDICT r1 missing item 6).
+    data axis.
     """
     items = np.asarray(items)
     n, d = items.shape

@@ -171,7 +171,7 @@ def _compress_labels(labels: jax.Array, core: jax.Array, n: int) -> jax.Array:
     iteration doubles the compressed hop depth, so a chain of length L
     collapses in O(log L) cheap (n,) gathers. Running this to convergence
     between epsilon sweeps is what makes the number of EXPENSIVE O(n^2 d)
-    sweeps O(log n) instead of O(cluster diameter) (VERDICT r4 #5 — a
+    sweeps O(log n) instead of O(cluster diameter) (a
     long-chain dataset previously degraded the sweep count arbitrarily).
     _INT_MAX entries clamp to a safe no-op gather.
     """

@@ -67,8 +67,8 @@ def knn_sq_euclidean(
     ``approx=True`` replaces the per-block exact ``top_k`` with the
     TPU-native ``lax.approx_min_k`` (the PartialReduce op the hardware
     has a fast path for; exact on CPU) while the cross-block candidate
-    merge stays exact. This is the TPU-first ANN finding (measured
-    numbers in BASELINE.md config 7): a dense MXU scoring pass +
+    merge stays exact. This is the TPU-first ANN finding
+    (benchmarks/config7_ann_search.py): a dense MXU scoring pass +
     hardware approximate top-k beats the inverted-list gathers of
     ``ops/ann.ivf_search`` at 1M×96 with ~0.995 recall, because TPU
     gathers are scalarized while the distance GEMM rides the systolic
@@ -220,11 +220,11 @@ def knn_host_streamed(
     (nq, k) state on device (:func:`_merge_block_topk` — the same merge
     discipline as the resident-scan path), and the block's buffers are
     then free: device memory is O(nq*k + block), item capacity is bounded
-    by the SOURCE, not HBM (VERDICT r3 #4 — the regime the
+    by the SOURCE, not HBM (the regime the
     models/approximate_nearest_neighbors docstring used to hand to
     inverted lists on faith). Whether streaming beats a compressed
-    resident index (ivfpq) depends on source bandwidth; BASELINE.md
-    config 8 records the measured crossover.
+    resident index (ivfpq) depends on source bandwidth;
+    benchmarks/config8_ann_beyond_hbm.py measures the crossover.
 
     Equal-size blocks reuse one compiled merge; a ragged final block
     compiles once more.

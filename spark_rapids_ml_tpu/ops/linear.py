@@ -1,6 +1,6 @@
 """Linear model kernels — normal-equation sufficient statistics on the MXU.
 
-Beyond-PCA capability (BASELINE.md config 4: "LinearRegression / Ridge on
+Beyond-PCA capability (benchmark config 4: "LinearRegression / Ridge on
 HIGGS 11M x 28 — normal-equation GEMM path"). The sufficient statistics
 (X^T X, X^T y, column sums) are one fused jitted computation — the same
 masked/shardable shape as the covariance kernel, so the distributed story is

@@ -4,7 +4,7 @@ The XLA Lloyd step (ops.kmeans.lloyd_step) materializes two (n, k) HBM
 temporaries per iteration — the distance matrix (consumed by argmin/min)
 and the one-hot matrix (operand of the stats GEMM). At 20M x 16, k=100
 that is ~32 GB of HBM write+read traffic per pass against a 1.3 GB data
-read: the pass is temporary-bound, not data-bound (VERDICT r3 #2 — the
+read: the pass is temporary-bound, not data-bound (the
 bytes-roofline gap). This kernel keeps both temporaries in VMEM: per row
 block it computes scores, argmin, one-hot, and the (k, d) partial sums
 without writing anything block-sized back to HBM. The only HBM traffic is
@@ -145,8 +145,7 @@ def assign_stats_fused(
     over THIS buffer, not a recomputation from ``centers`` — a different
     reduction order/layout can flip the argmin on a near-tie (e.g. cosine
     mode where every unit-norm center has c2 ~ 1), subtracting the padding
-    count from a different cluster than the kernel assigned it to
-    (ADVICE r4).
+    count from a different cluster than the kernel assigned it to.
     """
     precision = pallas_precision(precision)
     d_pad, n_pad = xt.shape
@@ -210,7 +209,7 @@ def packed_feasible(d: int, k: int) -> bool:
 
 
 def _assign_stats_packed_kernel(
-    xp_ref, cp_ref, c2p_ref, sums_ref, counts_ref, cost_ref,
+    xp_ref, cpt_ref, c2p_ref, sums_ref, counts_ref, cost_ref,
     *, precision, groups, kg,
 ):
     i = pl.program_id(0)
@@ -223,35 +222,45 @@ def _assign_stats_packed_kernel(
 
     xp = xp_ref[:]  # (128, bn): P groups of dg feature sublanes
     bn = xp.shape[1]
-    # ONE 128-lane contraction scores all P groups: cp is block-diagonal,
-    # so group g's score slot sees only group g's features.
+    # ONE 128-deep contraction scores all P groups: cpt is block-diagonal,
+    # so group g's score slot sees only group g's features. The scores
+    # come out TRANSPOSED, (P*kg, bn): the groups lie along SUBLANES, so
+    # each group's slot is a static tile-aligned slice (kg is a multiple
+    # of 16) — see assign_stats_packed on why not (bn, P*kg).
     xc = _dot_prec(
-        xp, cp_ref[:], (((0,), (0,)), ((), ())), precision
-    )  # (bn, P*kg)
+        cpt_ref[:], xp, (((1,), (0,)), ((), ())), precision
+    )  # (P*kg, bn)
     scores = c2p_ref[:] - 2.0 * xc
-    s3 = scores.reshape(bn, groups, kg)
-    labels = jnp.argmin(s3, axis=2)  # (bn, groups)
-    m = jnp.min(s3, axis=2)
-    oh = (
-        jax.lax.broadcasted_iota(jnp.int32, s3.shape, 2) == labels[:, :, None]
-    ).astype(jnp.float32).reshape(bn, groups * kg)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (kg, bn), 0)
+    one_hots = []
+    min_sum = jnp.float32(0.0)
+    for g in range(groups):
+        s = scores[g * kg:(g + 1) * kg, :]  # (kg, bn)
+        m = jnp.min(s, axis=0, keepdims=True)
+        # First slot attaining the minimum == argmin's tie-break, written
+        # with min/compare/select only.
+        first = jnp.min(jnp.where(s == m, slot, kg), axis=0, keepdims=True)
+        one_hots.append((slot == first).astype(jnp.float32))
+        min_sum += jnp.sum(m)
+    oh = jnp.concatenate(one_hots, axis=0)  # (P*kg, bn), exact 0/1
     # Packed stats GEMM: (P*kg, P*dg) in one tile; only the P diagonal
     # (kg, dg) blocks are wanted — the off-diagonal blocks are the price
     # of the shared contraction and are discarded by the caller.
+    stats_dims = (((1,), (1,)), ((), ()))
     if precision == "high":
         xp_hi, xp_lo = _split_hi_lo(xp)
         default = jax.lax.Precision.DEFAULT
         kw = dict(
-            dimension_numbers=(((0,), (1,)), ((), ())),
+            dimension_numbers=stats_dims,
             preferred_element_type=jnp.float32,
         )
         sums_ref[:] += jax.lax.dot_general(
             oh, xp_hi, precision=default, **kw
         ) + jax.lax.dot_general(oh, xp_lo, precision=default, **kw)
     else:
-        sums_ref[:] += _dot_prec(oh, xp, (((0,), (1,)), ((), ())), precision)
-    counts_ref[:] += jnp.sum(oh, axis=0, keepdims=True)
-    cost_ref[0, 0] += jnp.sum(xp * xp) + jnp.sum(m)
+        sums_ref[:] += _dot_prec(oh, xp, stats_dims, precision)
+    counts_ref[:] += jnp.sum(oh, axis=1, keepdims=True)  # (P*kg, 1)
+    cost_ref[0, 0] += jnp.sum(xp * xp) + min_sum
 
 
 @partial(jax.jit, static_argnames=("block_n", "precision", "interpret"))
@@ -266,7 +275,7 @@ def assign_stats_packed(
 
     At d=16, k<=16 the fused kernel's score contraction uses 16 of 128
     MXU lanes and 16 of 128 output columns — 112 lanes of zeros ride
-    along every tile (VERDICT r5 #3). This variant packs P = 128/dg
+    along every tile. This variant packs P = 128/dg
     INDEPENDENT row blocks into one contraction: X regroups to (128,
     n/P) with each group's d features at its own sublane offset, the
     centers become a block-diagonal (128, 128) operand, and both the
@@ -275,11 +284,18 @@ def assign_stats_packed(
     argmin) at 1/P the tile count. Same contract as
     :func:`assign_stats_fused` (raw stats INCLUDING padding rows).
 
-    Measured verdict lives in BASELINE.md ("KMeans lane packing"): the
-    tile-count win is a TPU systolic-array property; on this CPU-only
-    environment the packed shapes run the same algebraic FLOPs, so the
-    entry records the measured CPU number and the model, not a claimed
-    TPU speedup.
+    The tile-count win is a TPU systolic-array property and is NOT
+    measured: the only timing behind this kernel is a CPU GEMM-shape proxy
+    (benchmarks/config17_kmeans_packed.py), which is no chip speed.
+
+    What the chip's compiler accepts (found compile-only for a described
+    v5e, then run on the chip by ``chip_smoke.py``): the packed array's
+    lane block must be a multiple of 128 (``block_n % (128 * P) == 0`` —
+    :func:`auto_block_n` with ``packed=True``; the earlier ``block_n // P``
+    of 1008/1696/2496 columns was refused by the Pallas TPU lowering), and
+    the per-group argmin runs over sublane slices of TRANSPOSED scores (a
+    (bn, P*kg) -> (bn, P, kg) lane-splitting reshape is refused by Mosaic:
+    "infer-vector-layout: unsupported shape cast").
     """
     precision = pallas_precision(precision)
     d_pad, n_pad = xt.shape
@@ -290,17 +306,23 @@ def assign_stats_packed(
     if geom is None:
         raise ValueError(f"packing infeasible at d_pad={d_pad}, k={k}")
     p, dg, kg = geom
-    if n_pad % p:
-        raise ValueError(f"n_pad {n_pad} not divisible by pack factor {p}")
-    np_rows = n_pad // p
-    if np_rows % block_n:
-        block_n = max(
-            128, min(block_n, (np_rows // max(np_rows // block_n, 1)))
+    # block_n counts rows of X per grid step, as in the unpacked kernel;
+    # the packed array carries block_n // P of them per lane block.
+    if n_pad % block_n or block_n % p:
+        raise ValueError(
+            f"n_pad {n_pad} must be a multiple of block_n {block_n}, and "
+            f"block_n of the pack factor {p}"
         )
-        while np_rows % block_n:
-            block_n //= 2
-        if block_n < 8:
-            raise ValueError(f"no block size divides {np_rows}")
+    np_rows = n_pad // p
+    block_p = block_n // p
+    if not interpret and block_p % 128 and block_p != np_rows:
+        # The chip's compiler tiles the lane dimension in 128s (the Pallas
+        # TPU lowering refuses any other block); auto_block_n(packed=True)
+        # hands out aligned sizes.
+        raise ValueError(
+            f"compiled packed kernel needs block_n % {128 * p} == 0, got "
+            f"{block_n}"
+        )
     if precision not in ("highest", "high", "default"):
         raise ValueError(f"precision must be highest|high|default, got {precision!r}")
 
@@ -312,17 +334,19 @@ def assign_stats_packed(
     )
     ct = centers.T  # (d_pad, k)
     c2_col = jnp.sum(ct * ct, axis=0)  # (k,) — same reduction as fused
-    # Block-diagonal centers: group g rows [g*dg, g*dg+d_pad) x cols
-    # [g*kg, g*kg+k).
+    # Block-diagonal centers, slot-major: group g rows [g*kg, g*kg+k) x
+    # cols [g*dg, g*dg+d_pad).
     eye = jnp.eye(p, dtype=xt.dtype)  # (P, P)
-    cp = jnp.einsum("ab,dk->adbk", eye, jnp.pad(ct, ((0, dg - d_pad), (0, kg - k)))).reshape(p * dg, p * kg)
+    cpt = jnp.einsum(
+        "ab,kd->akbd", eye, jnp.pad(centers, ((0, kg - k), (0, dg - d_pad)))
+    ).reshape(p * kg, p * dg)
     # Unused score slots (k..kg) push to the finite sentinel so no row
     # lands there (NOT +inf: the "high" split turns inf into NaN).
     slot = jax.lax.broadcasted_iota(jnp.int32, (kg,), 0)
     c2_slot = jnp.where(slot < k, jnp.pad(c2_col, (0, kg - k)), _UNUSED_SCORE)
-    c2p = jnp.tile(c2_slot, p)[None, :]  # (1, 128)
+    c2p = jnp.tile(c2_slot, p)[:, None]  # (P*kg, 1)
 
-    nb = np_rows // block_n
+    nb = np_rows // block_p
     sums, counts, cost = pl.pallas_call(
         partial(
             _assign_stats_packed_kernel,
@@ -330,22 +354,22 @@ def assign_stats_packed(
         ),
         grid=(nb,),
         in_specs=[
-            pl.BlockSpec((p * dg, block_n), lambda i: (0, i), memory_space=pltpu.VMEM),
-            pl.BlockSpec((p * dg, p * kg), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, p * kg), lambda i: (0, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((p * dg, block_p), lambda i: (0, i), memory_space=pltpu.VMEM),
+            pl.BlockSpec((p * kg, p * dg), lambda i: (0, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((p * kg, 1), lambda i: (0, 0), memory_space=pltpu.VMEM),
         ],
         out_specs=(
             pl.BlockSpec((p * kg, p * dg), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, p * kg), lambda i: (0, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((p * kg, 1), lambda i: (0, 0), memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM),
         ),
         out_shape=(
             jax.ShapeDtypeStruct((p * kg, p * dg), jnp.float32),
-            jax.ShapeDtypeStruct((1, p * kg), jnp.float32),
+            jax.ShapeDtypeStruct((p * kg, 1), jnp.float32),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ),
         interpret=interpret,
-    )(xp, cp, c2p)
+    )(xp, cpt, c2p)
 
     # Keep the P diagonal (kg, dg) blocks; the off-diagonal blocks are
     # cross-group garbage from the shared stats tile.
@@ -368,11 +392,15 @@ def fused_feasible(d: int, k: int) -> bool:
     return auto_block_n(d, k) is not None
 
 
-def auto_block_n(d: int, k: int):
+def auto_block_n(d: int, k: int, packed: bool = False):
     """Row-block size that keeps the kernel's VMEM residents (x tile
     double-buffered + scores + one-hot + split scratch) within ~10 MB,
     or None when even the minimum 128-column block would not fit (very
-    wide d x large k — the XLA path handles those)."""
+    wide d x large k — the XLA path handles those). ``packed=True``
+    (caller checked :func:`packed_feasible`) rounds down to a multiple of
+    128 * P, so the packed array's block of ``block_n // P`` columns stays
+    a whole number of 128-lane tiles — the chip's compiler accepts no
+    other block shape."""
     d_pad = d + ((-d) % 8)
     k_pad = k + ((-k) % 128)
     per_col = 4 * d_pad + 2 * k_pad  # f32 elements per block column
@@ -381,7 +409,8 @@ def auto_block_n(d: int, k: int):
     bn = budget_elems // per_col if budget_elems > 0 else 0
     if bn < 128:
         return None
-    return (min(8192, bn) // 128) * 128
+    align = 128 * _packed_geometry(d_pad, k)[0] if packed else 128
+    return (min(8192, bn) // align) * align
 
 
 def pad_transposed(x: jax.Array, block_n: int = 4096) -> Tuple[jax.Array, int]:
@@ -445,7 +474,7 @@ def lloyd_fused(
     def correct(stats):
         # c2 comes back from the kernel call — the same buffer the scores
         # were computed against, so this argmin agrees with the kernel's
-        # padding-row assignment even on exact ties (ADVICE r4).
+        # padding-row assignment even on exact ties.
         sums, counts, cost, c2 = stats
         pad_label = jnp.argmin(c2)
         counts = counts.at[pad_label].add(-jnp.float32(n_pad_rows))

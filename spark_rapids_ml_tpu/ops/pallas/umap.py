@@ -4,7 +4,7 @@ The synchronous UMAP epoch (ops.umap._make_epoch_fn) applies every edge's
 attractive gradient twice: once to the head (a DENSE (n, k, dim) sum — free)
 and once to the tail (``zeros.at[dst].add(g)`` — a true scatter over random
 indices). XLA lowers that scatter element-serialized, and it measured ~70%
-of the whole SGD wall at config 13 (VERDICT r5 #1: 10.9 ms/epoch of a
+of the whole SGD wall at config 13 (10.9 ms/epoch of a
 15.6 ms epoch).
 
 The edge list is STATIC per fit, so the randomness can be paid ONCE on the
@@ -152,10 +152,24 @@ def _tail_kernel(base_ref, nblk_ref, t_ref, v_ref, out_ref, *, rows_per_tile):
             )
             == local
         ).astype(jnp.float32)  # (R, EB)
-        out_ref[:] += jax.lax.dot_general(
-            v_ref[:], oh, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # (sub, R)
+        # Three exact bf16 pieces of v (8 + 8 + 8 mantissa bits) against
+        # the one-hot, which is exact in bf16: every product is exact and
+        # the sums accumulate in f32 — the XLA scatter's numerics. A bare
+        # f32 dot takes ONE bf16 pass on the chip (the interpreter's is
+        # exact, so only a chip run shows it): 2.5e-4 of the largest row
+        # sum against the scatter at 50k x 15 x 2, measured by
+        # chip_smoke.py. HIGHEST would also split the one-hot, for six
+        # passes where three are exact.
+        v = v_ref[:]
+        v_hi = v.astype(jnp.bfloat16).astype(jnp.float32)
+        rest = v - v_hi
+        v_mid = rest.astype(jnp.bfloat16).astype(jnp.float32)
+        for part in (v_hi, v_mid, rest - v_mid):
+            out_ref[:] += jax.lax.dot_general(
+                part, oh, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.DEFAULT,
+            )  # (sub, R)
 
 
 @partial(jax.jit, static_argnames=("cfg", "interpret"))

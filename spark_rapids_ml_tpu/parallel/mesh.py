@@ -115,7 +115,7 @@ def shard_rows_from_partitions(partitions, mesh: Mesh, dtype=None):
     ``shard_rows(np.concatenate(partitions), mesh)`` — the shape every
     device sees, the padding, and the mask are the same — but the extra
     full-dataset host copy is gone (at the north-star 100M x 1024 scale
-    that copy is 400 GB; VERDICT r1 missing item 2).
+    that copy is 400 GB).
 
     Returns ``(x_sharded, row_mask_sharded, n_true_rows)``.
     """

@@ -368,7 +368,7 @@ if HAS_PYSPARK:  # pragma: no cover - no pyspark in the CI image
         heavyweight callable (training matrix + fitted values) serializes
         ONCE at broadcast() time — the reference's broadcast of the
         column means (RapidsRowMatrix.scala:162-166), applied to the
-        transform closures (VERDICT r3 #7)."""
+        transform closures."""
 
         def __init__(self, bc):
             self.bc = bc
@@ -844,7 +844,7 @@ if HAS_PYSPARK:  # pragma: no cover - no pyspark in the CI image
             return self._set(elasticNetParam=value)
 
         def _fit(self, dataset):
-            # ONE distributed path (VERDICT r2 #3 — no full-dataset
+            # ONE distributed path (no full-dataset
             # collect): the gang deploy switch. Partitions coalesce onto
             # the gang roster (TPUML_GANG_FIT_MEMBERS), each barrier
             # member materializes only ITS rows and calls the public
@@ -937,7 +937,7 @@ if HAS_PYSPARK:  # pragma: no cover - no pyspark in the CI image
             return LogisticRegressionModel
 
     # ------------------------------------------------------------------
-    # Distributed random-forest fit (VERDICT r2 #3): per-level executor
+    # Distributed random-forest fit: per-level executor
     # histogram partials merged by treeReduce, split decisions on the
     # driver with the SAME math the core solver uses
     # (ops.trees.split_level) — the mapPartitions+treeAggregate structure
@@ -1299,7 +1299,7 @@ if HAS_PYSPARK:  # pragma: no cover - no pyspark in the CI image
 
         def setIndexMode(self, value):
             """``"sharded"`` keeps each partition's items ON ITS EXECUTOR
-            as a local index shard (VERDICT r3 #5): queries broadcast,
+            as a local index shard: queries broadcast,
             shard-local numpy top-k (executor_math.knn_shard_topk), one
             treeReduce candidate merge — the partition-local
             compute+merge shape of the reference's covariance path
@@ -1417,7 +1417,7 @@ if HAS_PYSPARK:  # pragma: no cover - no pyspark in the CI image
             return out.drop(tmp)
 
         def _kneighbors_sharded(self, dataset, k_eff):
-            """Executor-sharded search (VERDICT r3 #5): the QUERY batch
+            """Executor-sharded search: the QUERY batch
             crosses to the driver once (queries are the small side of an
             ANN deployment), each item shard computes its local numpy
             top-k where it lives, and one treeReduce merges candidates —
@@ -1553,7 +1553,7 @@ if HAS_PYSPARK:  # pragma: no cover - no pyspark in the CI image
         """ANN — the modern spark-rapids-ml ANN family. Algorithms pass
         through to the core model: ivfflat | ivfpq | brute |
         brute_approx (the TPU-first hardware-top-k winner at
-        single-chip scales — BASELINE.md config 7)."""
+        single-chip scales — benchmarks/config7_ann_search.py)."""
 
         algorithm = Param(
             Params._dummy(), "algorithm",
@@ -1786,7 +1786,7 @@ if HAS_PYSPARK:  # pragma: no cover - no pyspark in the CI image
                 # fit_transform semantics of the reference) even though
                 # Arrow batches slice the dataset below the core model's
                 # whole-array shortcut. Ships as a BROADCAST: one
-                # serialization total, a handle per task (VERDICT r3 #7).
+                # serialization total, a handle per task.
                 bc = dataset.sparkSession.sparkContext.broadcast(
                     _FittedOrTransform(
                         np.asarray(self._core.trainData),

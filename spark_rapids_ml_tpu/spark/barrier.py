@@ -10,8 +10,7 @@ peers are dead or hung. The correct Spark deployment is a **barrier
 stage** (``rdd.barrier().mapPartitions``): the scheduler launches all
 tasks together and retries the WHOLE stage when any task fails — exactly
 the relaunch-the-gang semantic the distributed fits need
-(docs/PARITY.md "Failure detection / recovery"; previously prose-only,
-VERDICT r4 #3).
+(docs/PARITY.md "Failure detection / recovery").
 
 This module is the small launcher that recipe describes:
 

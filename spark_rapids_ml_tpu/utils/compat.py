@@ -1,46 +1,16 @@
-"""JAX version-compatibility shims — ONE home for API-drift hazards.
+"""The JAX names the kernels share — ONE import home.
 
-The library targets the modern JAX surface (top-level ``jax.shard_map``
-with ``check_vma=``), but deployment images pin older jaxlibs where the
-same functionality lives at ``jax.experimental.shard_map.shard_map`` with
-the ``check_rep=`` spelling. Before this module, six kernels imported the
-top-level name directly, so on an older pin the IMPORT failed — taking
-down every family that routes through those kernels (~60 collection
-errors in the tier-1 suite) for what is purely a naming difference.
-
-Import :data:`shard_map` from here instead of from ``jax``: it resolves
-to the native export when present and otherwise adapts the experimental
-one (mapping ``check_vma`` -> ``check_rep``), so kernels are written once
-against the modern API and degrade transparently on older runtimes.
+The library runs on one installation (JAX 0.9): ``jax.shard_map`` with
+``check_vma=``, ``jax.lax.axis_size``, and a ``jax.distributed.initialize``
+that takes ``heartbeat_timeout_seconds``. Kernels import these names from
+here so that the next rename lands in one file.
 """
 
 from __future__ import annotations
 
-import functools
-
-try:  # jax >= 0.6: first-class export, check_vma spelling
-    from jax import shard_map as _native_shard_map
-
-    shard_map = _native_shard_map
-except ImportError:  # older jax: experimental home, check_rep spelling
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-    @functools.wraps(_experimental_shard_map)
-    def shard_map(f, *args, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _experimental_shard_map(f, *args, **kwargs)
-
-
-try:  # jax >= 0.5: static axis size as a public lax API
-    from jax.lax import axis_size
-except ImportError:  # older jax: the core axis frame IS the static size
-
-    def axis_size(axis_name):
-        import jax.core as _core
-
-        frame = _core.axis_frame(axis_name)
-        return getattr(frame, "size", frame)
+import jax
+from jax import shard_map
+from jax.lax import axis_size
 
 
 def distributed_initialize(
@@ -50,117 +20,18 @@ def distributed_initialize(
     local_device_ids=None,
     heartbeat_timeout_seconds=None,
 ):
-    """``jax.distributed.initialize`` with the ``heartbeat_timeout_seconds``
-    failure-detection knob made version-portable: passed through where the
-    public API grew it, mapped onto the internal client/service heartbeat
-    (interval x max-missing, same product) on older jax — the knob bounds
-    how long survivors wait before a dead peer's absence raises, so
-    silently dropping it would turn a 10 s fail-fast into jax's 100 s
-    default."""
-    import inspect
-
-    import jax
-
+    """``jax.distributed.initialize`` with the failure-detection knob
+    passed only when set: it bounds how long survivors wait before a dead
+    peer's absence raises, and ``None`` keeps jax's own 100 s default."""
     kwargs = dict(
         coordinator_address=coordinator_address,
         num_processes=num_processes,
         process_id=process_id,
         local_device_ids=local_device_ids,
     )
-    if heartbeat_timeout_seconds is None:
-        jax.distributed.initialize(**kwargs)
-        return
-    public = inspect.signature(jax.distributed.initialize).parameters
-    if "heartbeat_timeout_seconds" in public:
-        jax.distributed.initialize(
-            heartbeat_timeout_seconds=heartbeat_timeout_seconds, **kwargs
-        )
-        return
-    from jax._src import distributed as _dist
-    from jax._src import xla_bridge as _bridge
-
-    internal = inspect.signature(_dist.State.initialize).parameters
-    if "client_heartbeat_interval_seconds" in internal:
-        # timeout = interval x max_missing; keep the 10-beat shape jax
-        # itself uses so one lost packet never kills a healthy job.
-        interval = max(1, int(heartbeat_timeout_seconds) // 10)
-        misses = max(1, int(heartbeat_timeout_seconds) // interval)
-        if _bridge.backends_are_initialized():
-            raise RuntimeError(
-                "jax.distributed.initialize() must be called before any "
-                "JAX computations are executed."
-            )
-        _dist.global_state.initialize(
-            coordinator_address,
-            num_processes,
-            process_id,
-            local_device_ids,
-            service_heartbeat_interval_seconds=interval,
-            service_max_missing_heartbeats=misses,
-            client_heartbeat_interval_seconds=interval,
-            client_max_missing_heartbeats=misses,
-        )
-        return
-    # No heartbeat control on this jax at all: bring up without it.
+    if heartbeat_timeout_seconds is not None:
+        kwargs["heartbeat_timeout_seconds"] = heartbeat_timeout_seconds
     jax.distributed.initialize(**kwargs)
 
 
-@functools.lru_cache(maxsize=None)
-def optax_lbfgs_f32_works() -> bool:
-    """Probe whether optax's L-BFGS (zoom linesearch included) traces
-    with FLOAT32 params under the current x64 setting. Older optax mixes
-    weak-f64 literals (``inf`` caches, stepsize math) into the f32
-    linesearch state, so internal lax.cond branches disagree (f64 vs f32)
-    and raise TypeError at trace time. One abstract step reproduces it."""
-    import jax
-    import jax.numpy as jnp
-    import optax
-
-    def loss(p):
-        return jnp.sum(p * p)
-
-    solver = optax.lbfgs()
-    vg = optax.value_and_grad_from_state(loss)
-
-    def step(p, s):
-        value, grad = vg(p, state=s)
-        updates, s2 = solver.update(
-            grad, s, p, value=value, grad=grad, value_fn=loss
-        )
-        return updates, s2
-
-    p0 = jnp.ones((2,), jnp.float32)
-    try:
-        jax.eval_shape(step, p0, solver.init(p0))
-        return True
-    except TypeError:
-        return False
-
-
-def value_and_grad_from_state(loss_fn):
-    """optax.value_and_grad_from_state when it works on this version;
-    otherwise plain jax.value_and_grad (correct, merely re-evaluating the
-    loss the linesearch already computed — the cache is an optimization,
-    not a semantic)."""
-    import optax
-
-    if optax_lbfgs_f32_works():
-        return optax.value_and_grad_from_state(loss_fn)
-    import jax
-
-    vg = jax.value_and_grad(loss_fn)
-
-    def fallback(params, *args, state=None, **kwargs):
-        del state
-        return vg(params, *args, **kwargs)
-
-    return fallback
-
-
-__all__ = [
-    "axis_size",
-    "distributed_initialize",
-    "optax_lbfgs_f32_works",
-    "shard_map",
-    "value_and_grad_from_state",
-]
+__all__ = ["axis_size", "distributed_initialize", "shard_map"]

@@ -65,8 +65,8 @@ class Params:
     keyed by the Param OBJECTS, not by name — consumers such as
     persistence writers iterate `p.name for p in map`.
 
-    Pinned to pyspark 3.5 ``pyspark/ml/param/__init__.py`` semantics
-    (VERDICT r2 #5a): ``Params.__init__`` COPIES every class-level Param
+    Pinned to pyspark 3.5 ``pyspark/ml/param/__init__.py`` semantics:
+    ``Params.__init__`` COPIES every class-level Param
     onto the instance with ``parent = self.uid`` (``_copy_params``), so
     ``TpuPCA().k is not TpuPCA.k`` and ``param.parent == instance.uid`` —
     adapter code that assumed shared class-level Param identity would

@@ -1,5 +1,5 @@
 """The pyspark adapter CONTRACT SUITE — one set of assertions, two
-runners (VERDICT r2 #5b):
+runners:
 
   - ``tests/test_spark_adapter.py`` runs it against ``tests/pyspark_stub``
     (the CI image has no pyspark; the stub implements the exact surface
@@ -352,7 +352,7 @@ class TestDistributedLogistic:
     def test_elastic_net_distributed_matches_core_optimum(self, spark_env, rng):
         """Driver-side FISTA over executor gradient sums optimizes the
         same strictly convex objective as the core solver — coefficients
-        must agree to optimizer tolerance (VERDICT r2 #3)."""
+        must agree to optimizer tolerance."""
         adapter, spark = spark_env
         from spark_rapids_ml_tpu.classification import LogisticRegression
 
@@ -395,8 +395,8 @@ class TestDistributedLogistic:
 
 
 class TestPySparkPinnedBehaviors:
-    """Behaviors the stub pins to pyspark 3.5 documentation (VERDICT r2
-    #5a). Run against the stub these guard the pins; run against genuine
+    """Behaviors the stub pins to pyspark 3.5 documentation.
+    Run against the stub these guard the pins; run against genuine
     pyspark (tests/test_spark_real.py) they validate that the pins match
     the real thing — the same assertions either way."""
 
@@ -489,7 +489,7 @@ class TestPySparkPinnedBehaviors:
 
 
 class TestNoDriverCollect:
-    """VERDICT r2 #3 done-criterion: instrument the stub RDD and assert
+    """Done-criterion of the distributed fits: instrument the stub RDD and assert
     the forest / elastic-net fits never collect the dataset to the driver
     (only the bounded quantile sample for forests)."""
 
@@ -669,7 +669,7 @@ class TestNeighborsAdapters:
         np.testing.assert_array_equal(idx[:, 0], np.arange(200))
 
     def test_sharded_index_matches_collected(self, spark_env, rng):
-        """indexMode='sharded' (VERDICT r3 #5): executor-local shards +
+        """indexMode='sharded': executor-local shards +
         treeReduce merge must return exactly the collected path's
         neighbors."""
         adapter, spark = spark_env
@@ -784,7 +784,7 @@ class TestNeighborsAdapters:
 
 class TestTpuDBSCANAndUMAP:
     def test_transform_closure_broadcast_once(self, spark_env, rng):
-        """VERDICT r3 #7: the training matrix + fitted values ship as ONE
+        """The training matrix + fitted values ship as ONE
         broadcast serialization, not one per task closure — the stub's
         torrent-broadcast counter proves it across a multi-partition
         transform and a REPEATED transform (the handle is cached)."""
@@ -970,7 +970,7 @@ class TestEstimatorPersistence:
 
 
 class TestBarrierGangRecovery:
-    """VERDICT r4 #3: the documented barrier-stage gang-relaunch recipe
+    """The documented barrier-stage gang-relaunch recipe
     (docs/PARITY.md "Failure detection / recovery"), EXECUTED — a
     partition task is killed mid-fit on its first attempt; the barrier
     stage must relaunch the WHOLE gang (not just the dead task) and the

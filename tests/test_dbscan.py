@@ -102,7 +102,7 @@ class TestOps:
         same_partition(relabel_consecutive(np.asarray(labels)), sk.labels_)
 
     def test_chain_sweep_count_logarithmic(self):
-        # Adversarial topology (VERDICT r4 #5): a 4096-point chain has
+        # Adversarial topology: a 4096-point chain has
         # cluster diameter ~n, which the old one-jump-per-sweep diffusion
         # resolved in O(n) expensive eps sweeps. With full path
         # compression between sweeps the EXPENSIVE sweep count is O(log n)

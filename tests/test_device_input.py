@@ -1,7 +1,7 @@
-"""Device-resident input path (VERDICT r2 #1): a jax.Array fed to the
+"""Device-resident input path: a jax.Array fed to the
 public estimator runs the whole fit as one XLA program with no host
 round-trip, and the model converts to host float64 lazily. Also covers the
-self-selecting eigensolver (ops.eigh.eigh_auto, VERDICT r2 #2)."""
+self-selecting eigensolver (ops.eigh.eigh_auto)."""
 
 import jax
 import jax.numpy as jnp
@@ -72,7 +72,7 @@ class TestDeviceInputFit:
         assert np.abs(np.abs(model.pc) - np.abs(pc_o)).max() < 1e-3
 
     def test_randomized_solver_device_input_honors_mesh(self, decaying):
-        # ADVICE r3: a device array + explicit mesh must reshard onto the
+        # A device array + explicit mesh must reshard onto the
         # mesh (never silently compute single-device), matching the
         # covariance path's _device_array_on_mesh stance.
         from jax.sharding import Mesh
@@ -119,7 +119,7 @@ class TestDeviceInputFit:
         assert np.abs(np.abs(model.pc) - np.abs(pc_o)).max() < 1e-3
 
     def test_device_fitted_model_pickles_host_state(self, decaying):
-        # ADVICE r3: pickling a device-fitted model (Spark broadcast,
+        # Pickling a device-fitted model (Spark broadcast,
         # cloudpickle closure) must ship host float64, not live device
         # buffers.
         cloudpickle = pytest.importorskip("cloudpickle")

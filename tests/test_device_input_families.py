@@ -1,4 +1,4 @@
-"""Device-resident input across EVERY accelerator family (VERDICT r3 #1).
+"""Device-resident input across EVERY accelerator family.
 
 Round 3 proved the jax.Array fast path for PCA only; these tests pin the
 generalized contract for KMeans, Linear/LogisticRegression, RandomForest,
@@ -62,7 +62,7 @@ def no_device_as_matrix(monkeypatch):
 
 class TestKMeansDevice:
     def test_fit_no_device_to_host_transfer(self, blobs):
-        """THE regression test VERDICT r3 asked for: the whole fit under a
+        """THE regression test for device residence: the whole fit under a
         disallow-device-to-host guard — not one byte may come back."""
         x, _ = blobs
         xd = jnp.asarray(x)

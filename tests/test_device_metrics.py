@@ -1,6 +1,6 @@
 """Device-side evaluator kernels must agree with the host evaluators —
 the scale path (jax-array or >=1M-row tuples) vs the validation-fold path
-(VERDICT r1 weak item 7: the AUC sort no longer collects to host)."""
+(the AUC sort no longer collects to host)."""
 
 import numpy as np
 import pytest
@@ -143,7 +143,7 @@ class TestPrecisionRouting:
 
 
 class TestAUCSortAttack:
-    """The sort-attack rewrite (BASELINE.md "AUC sort shoot-out") has two
+    """The sort-attack rewrite has two
     code paths: the packed-uint64 single sort (f32 scores under x64) and
     the variadic key+label sort (everything else). Both must reproduce
     the host tie-grouped curve; the packed path must survive the exact

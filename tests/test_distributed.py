@@ -275,7 +275,7 @@ class TestDistributedANN:
 class TestDistributedIndexBuild:
     """The ANN index BUILD is mesh-sharded now, not just the search:
     coarse quantizer + PQ codebook Lloyds run over sharded rows with
-    psum-merged stats (VERDICT r1 missing item 6)."""
+    psum-merged stats."""
 
     def test_ivf_build_parity(self, rng, mesh_8x1):
         from spark_rapids_ml_tpu.ops.ann import build_ivf_index, ivf_search

@@ -299,7 +299,7 @@ class TestLoadSparkWrittenForests:
     """Spark's EnsembleModelReadWrite on-disk shape (treeID + NodeData
     struct rows, preorder ids, explicit child pointers, leaf sentinels)
     must load into the heap-array Forest and predict correctly
-    (VERDICT r4 #6 — the RF families joined the golden suite in r5)."""
+    (the RF families joined the golden suite in r5)."""
 
     def test_rf_classifier_golden(self, tmp_path, rng):
         path = str(tmp_path / "spark_rfc")
@@ -369,7 +369,7 @@ class TestLoadSparkWrittenForests:
         TASK; NodeData split across two parts (tree 1 entirely in
         part-00001) must load every tree — the pre-r6 reader took only
         ``parquets[0]`` and silently dropped the rest of the forest
-        (ROADMAP 5a / ADVICE.md medium)."""
+        (ROADMAP 5a)."""
         rows = [
             (0, _node(0, 1.0, 0.495, [9, 11], 20, gain=0.3, left=1, right=2,
                       feat=0, thr=0.5)),

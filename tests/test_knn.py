@@ -30,7 +30,7 @@ class TestOps:
     def test_approx_topk_matches_exact_on_cpu(self, rng):
         # lax.approx_min_k is exact on the CPU backend, so the approx path
         # must reproduce the exact kernel bit-for-bit here; on TPU it is
-        # the hardware partial-reduce (recall ~0.995 measured, BASELINE.md).
+        # the hardware partial-reduce (recall ~0.995 in an earlier round).
         q = rng.normal(size=(20, 8))
         x = rng.normal(size=(700, 8))
         d_ex, i_ex = knn(q, x, k=6)

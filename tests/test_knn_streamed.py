@@ -1,4 +1,4 @@
-"""Host-streamed kNN/ANN indexes (VERDICT r3 #4): item sets beyond HBM
+"""Host-streamed kNN/ANN indexes: item sets beyond HBM
 stream through a running top-k merge; results must match the resident
 path exactly (the merge math is shared)."""
 
@@ -131,7 +131,7 @@ class TestStreamedEstimators:
             model.write.overwrite().save(str(tmp_path / "m"))
 
     def test_streamed_model_does_not_pickle(self, corpus):
-        # ADVICE r4: cloudpickling a streamed model (Spark broadcast, UDF
+        # Cloudpickling a streamed model (Spark broadcast, UDF
         # closure) must fail with the same clear contract as _save_impl,
         # not ship the whole item set through the iterator factory.
         import pickle

@@ -356,7 +356,7 @@ class TestWarmStart:
 
 
 class TestFusedObjective:
-    """Fused one-pass loss+grad (VERDICT r5 #4): the custom_vjp objective
+    """Fused one-pass loss+grad: the custom_vjp objective
     streams X once per evaluation instead of saving the standardized
     design as an AD residual. Fused and legacy must agree to float
     tolerance on every driver — monolithic, blocked, streaming — and the

@@ -1,7 +1,7 @@
 """Multi-process distributed execution: N OS processes, each owning a
 slice of the data and a share of the (virtual CPU) devices, brought up via
 jax.distributed and fitting through the ordinary estimator API — the
-executor-per-chip deployment shape (VERDICT r1 missing item 2; the
+executor-per-chip deployment shape (the
 reference's per-partition compute + cross-process reduce,
 RapidsRowMatrix.scala:170-201)."""
 
@@ -126,7 +126,7 @@ class TestMultiProcess:
 
     @pytest.mark.slow
     def test_4x2_data_model_mesh(self):
-        """VERDICT r2 #4: a 4-process x 2-device fit on a (4, 2)
+        """A 4-process x 2-device fit on a (4, 2)
         data x model mesh — features sharded across each process's own
         devices, rows across processes — must match the oracle in every
         process. d=13 does NOT divide the model axis, so the zero-pad +
@@ -175,7 +175,7 @@ class TestMultiProcess:
 
     @pytest.mark.slow
     def test_worker_death_fails_fast_on_survivors_no_hang(self):
-        """VERDICT r2 #7 fault path: one executor hard-dies mid-stream
+        """Fault path: one executor hard-dies mid-stream
         (os._exit inside its block generator, before the merge
         collective). Survivors must FAIL FAST within the tightened
         heartbeat window — no hang, no wrong model. jax's coordination
@@ -241,7 +241,7 @@ class TestMultiProcess:
 
     @pytest.mark.slow
     def test_8_process_north_star_8x1(self):
-        """VERDICT r4 #4: the EXACT north-star software topology — 8
+        """The EXACT north-star software topology — 8
         processes, one (virtual) device each, streamed per-executor
         blocks, psum moment merge on an (8, 1) mesh. The BASELINE config
         5 ×8 projection's software preconditions (bring-up, wire format,

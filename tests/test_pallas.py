@@ -46,7 +46,7 @@ class TestCenteredGramPallas:
 
 
 class TestPallasBackendSelection:
-    """The kernel is a selectable covariance backend (VERDICT r1 item 4),
+    """The kernel is a selectable covariance backend,
     not dead code: PCA(covarianceBackend='pallas') must produce the same
     model as the default XLA fusion."""
 

@@ -90,7 +90,7 @@ class TestPipeline:
 
     def test_load_rejects_foreign_class(self, tmp_path):
         """Metadata naming a class outside this package must not be imported
-        (ADVICE r1: untrusted model dirs as import gadgets)."""
+        (untrusted model dirs as import gadgets)."""
         import json
 
         pipe = Pipeline(stages=[PCA().setK(2)])

@@ -47,6 +47,7 @@ from spark_rapids_ml_tpu.tuning import (
     TrainValidationSplit,
     _device_fold_prep,
 )
+from spark_rapids_ml_tpu.utils.envknobs import env_str
 
 D = 12  # input feature width shared by the chain fixtures
 
@@ -54,7 +55,7 @@ D = 12  # input feature width shared by the chain fixtures
 @contextmanager
 def fusion_off():
     """Force the staged path (the in-test reference for parity checks)."""
-    prev = os.environ.get("TPUML_PIPELINE_FUSION")
+    prev = env_str("TPUML_PIPELINE_FUSION")
     os.environ["TPUML_PIPELINE_FUSION"] = "off"
     try:
         yield

@@ -11,6 +11,7 @@ behavior) fails loudly.
 """
 
 import logging
+import os
 
 import jax
 import jax.numpy as jnp
@@ -295,41 +296,89 @@ class TestFamiliesServed:
 
 
 class TestCompileCacheKnob:
-    def test_env_knob_wires_jax_config(self, tmp_path, monkeypatch):
+    @pytest.fixture(autouse=True)
+    def _fresh_wiring(self, monkeypatch):
+        # The harness may itself have been handed a cache directory.
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        monkeypatch.delenv("TPUML_COMPILE_CACHE_DIR", raising=False)
+        serving._reset_compile_cache_wiring_for_tests()
+        yield
+        serving._reset_compile_cache_wiring_for_tests()
+
+    @pytest.fixture
+    def config_calls(self, monkeypatch):
         calls = {}
         monkeypatch.setattr(
             jax.config, "update", lambda k, v: calls.setdefault(k, v)
         )
-        serving._reset_compile_cache_wiring_for_tests()
-        try:
-            monkeypatch.setenv("TPUML_COMPILE_CACHE_DIR", str(tmp_path / "cc"))
-            # force=True stands in for a non-CPU backend (the CPU guard is
-            # the point of the next test).
-            active = serving.configure_compile_cache(force=True)
-            assert active == str(tmp_path / "cc")
-            assert calls["jax_compilation_cache_dir"] == str(tmp_path / "cc")
-            assert calls["jax_persistent_cache_min_compile_time_secs"] == 0
-            assert (tmp_path / "cc").is_dir()
-        finally:
-            serving._reset_compile_cache_wiring_for_tests()
+        return calls
+
+    def test_env_knob_wires_jax_config(self, tmp_path, monkeypatch, config_calls):
+        monkeypatch.setenv("TPUML_COMPILE_CACHE_DIR", str(tmp_path / "cc"))
+        # force=True stands in for a non-CPU backend (the CPU guard is
+        # the point of the next test).
+        active = serving.configure_compile_cache(force=True)
+        assert active == str(tmp_path / "cc")
+        assert config_calls["jax_compilation_cache_dir"] == str(tmp_path / "cc")
+        assert config_calls["jax_persistent_cache_min_compile_time_secs"] == 0
+        assert (tmp_path / "cc").is_dir()
 
     def test_cpu_backend_guard(self, tmp_path, monkeypatch):
         """XLA:CPU AOT (de)serialization is unstable on this jaxlib
         (tests/conftest.py) — the knob must be inert on CPU by default."""
-        serving._reset_compile_cache_wiring_for_tests()
-        try:
-            monkeypatch.setenv("TPUML_COMPILE_CACHE_DIR", str(tmp_path / "cc"))
-            assert serving.configure_compile_cache() is None
-        finally:
-            serving._reset_compile_cache_wiring_for_tests()
+        monkeypatch.setenv("TPUML_COMPILE_CACHE_DIR", str(tmp_path / "cc"))
+        assert serving.configure_compile_cache() is None
 
-    def test_unset_knob_is_noop(self, monkeypatch):
-        monkeypatch.delenv("TPUML_COMPILE_CACHE_DIR", raising=False)
-        serving._reset_compile_cache_wiring_for_tests()
-        try:
-            assert serving.configure_compile_cache() is None
-        finally:
-            serving._reset_compile_cache_wiring_for_tests()
+    def test_unset_knob_is_noop(self):
+        assert serving.configure_compile_cache() is None
+
+    @pytest.mark.parametrize("force", [False, True])
+    def test_jax_variable_wins_and_sets_nothing(
+        self, tmp_path, monkeypatch, config_calls, force
+    ):
+        """Placed from outside: jax reads JAX_COMPILATION_CACHE_DIR itself,
+        so neither the repo's own knob nor an explicit path may set a
+        directory (or anything else) in code — on any backend."""
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "outside"))
+        monkeypatch.setenv("TPUML_COMPILE_CACHE_DIR", str(tmp_path / "cc"))
+        assert serving.configure_compile_cache(force=force) == str(
+            tmp_path / "outside"
+        )
+        assert serving.configure_compile_cache(
+            str(tmp_path / "explicit"), force=force
+        ) == str(tmp_path / "outside")
+        assert config_calls == {}
+        assert not (tmp_path / "cc").exists()
+        assert not (tmp_path / "explicit").exists()
+
+    def test_neither_set_on_cpu_leaves_config_untouched(self, config_calls):
+        assert serving.configure_compile_cache() is None
+        assert config_calls == {}
+
+    def test_neither_set_off_cpu_uses_the_fixed_checkout_path(
+        self, tmp_path, monkeypatch, config_calls
+    ):
+        """force=True stands in for a non-CPU backend: with no directory
+        named anywhere the cache goes to ONE fixed path under the
+        checkout — never a temp-, pid- or time-made name (the directory
+        is part of jax's cache key)."""
+        fixed = serving.DEFAULT_COMPILE_CACHE_DIR
+        repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert fixed == os.path.join(repo_root, ".jax_compile_cache")
+        monkeypatch.setattr(serving, "DEFAULT_COMPILE_CACHE_DIR", str(tmp_path / "fx"))
+        assert serving.configure_compile_cache(force=True) == str(tmp_path / "fx")
+        assert config_calls["jax_compilation_cache_dir"] == str(tmp_path / "fx")
+
+    def test_fit_path_calls_the_one_function(self, monkeypatch):
+        from spark_rapids_ml_tpu.feature import PCA
+
+        seen = []
+        monkeypatch.setattr(
+            serving, "configure_compile_cache", lambda *a, **k: seen.append(1)
+        )
+        x = np.random.default_rng(0).normal(size=(32, 4))
+        PCA().setK(2).fit(x)
+        assert seen
 
 
 class TestIngestWeightMask:

@@ -5,7 +5,7 @@ API surface the adapter consumes (with real partition semantics and
 cloudpickle serialization boundaries), so every line of
 ``spark_rapids_ml_tpu.spark.adapter`` executes here — fit on an RDD with
 mapPartitions/treeReduce, Arrow-batch pandas_udf transforms, and
-save/load round-trips (VERDICT r1 item 1, stub alternative). The test
+save/load round-trips. The test
 classes live in ``tests/spark_contract_suite.py`` and are shared with
 ``tests/test_spark_real.py``, which runs the same assertions against
 genuine pyspark when installed.

@@ -1,5 +1,4 @@
-"""The SAME adapter contract suite, against GENUINE pyspark (VERDICT r2
-#5b / advisor r2 medium): skipped wherever pyspark is not installed (this
+"""The SAME adapter contract suite, against GENUINE pyspark: skipped wherever pyspark is not installed (this
 CI image), and the complete proof the day an environment has it.
 
 Smoke procedure for such an environment (documented here AND in

@@ -1,4 +1,4 @@
-"""Re-iterable streaming fits for the ITERATIVE families (VERDICT r3 #6).
+"""Re-iterable streaming fits for the ITERATIVE families.
 
 LinearRegression and PCA already stream (single-pass moments / sketch);
 these tests pin the new multi-pass streaming paths: KMeans (one data pass

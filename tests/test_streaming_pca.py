@@ -30,7 +30,7 @@ class TestStreamingSourceDetection:
         assert not is_streaming_source("nope")
 
     def test_callable_requiring_args_is_not_a_factory(self, rng):
-        # ADVICE r3: a callable that NEEDS arguments is not a zero-arg
+        # A callable that NEEDS arguments is not a zero-arg
         # iterator factory — classifying it as one routes it into
         # multi-pass paths that die with an opaque TypeError.
         from spark_rapids_ml_tpu.core.data import is_reiterable_stream
@@ -171,7 +171,7 @@ class TestConstantMemory:
     def test_peak_rss_bounded_below_file_size(self, tmp_path):
         """Fit a file much larger than one block; peak RSS growth over the
         post-import baseline must stay far below the file size — the
-        constant-memory contract (VERDICT r1 item 5)."""
+        constant-memory contract."""
         n, d = 400_000, 64  # 400k x 64 f64 = ~205 MB
         path = str(tmp_path / "big.npy")
         rng = np.random.default_rng(0)

@@ -196,7 +196,7 @@ class TestCrossValidator:
 
     def test_binary_evaluator_gets_scores_not_labels(self, rng):
         """AUC on a tuple dataset must rank by continuous probabilities —
-        hard 0/1 labels would tie whole grid cells (ADVICE r1, medium)."""
+        hard 0/1 labels would tie whole grid cells."""
         from spark_rapids_ml_tpu.classification import LogisticRegression
         from spark_rapids_ml_tpu.tuning import _eval_dataset
 

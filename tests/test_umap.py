@@ -319,10 +319,10 @@ class TestResume:
 
 
 class TestTailScatterPallas:
-    """Bucketed tail scatter-add kernel (VERDICT r5 #1): the per-epoch
+    """Bucketed tail scatter-add kernel: the per-epoch
     XLA scatter replaced by a static tail-sort + dense per-tile
-    accumulation. Interpret mode on CPU; the TPU walls live in
-    BASELINE.md's "UMAP tail scatter" entry."""
+    accumulation. Interpret mode on CPU; the compiled kernel runs on the
+    chip in ``chip_smoke.py`` (its speed there is not measured)."""
 
     @pytest.mark.parametrize(
         "n,k,dim",

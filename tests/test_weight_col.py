@@ -175,7 +175,7 @@ class TestForestWeights:
 
     def test_fractional_weights_route_to_exact_histograms(self):
         """bf16 one-pass histograms are only used when the full histogram
-        operand — sample_weight * stat — survives bf16 rounding (ADVICE r1:
+        operand — sample_weight * stat — survives bf16 rounding (a
         fractional weightCol could flip near-tie splits under DEFAULT
         precision; the bound must cover the bootstrap multiplicity too)."""
         from spark_rapids_ml_tpu.models.random_forest import _hist_exact_in_bf16

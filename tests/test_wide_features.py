@@ -1,4 +1,4 @@
-"""Wide-feature regime (VERDICT r2 #6): the randomized sketch now covers
+"""Wide-feature regime: the randomized sketch now covers
 mesh-sharded and re-iterable streaming inputs, so d >= 4096 has a story
 that never materializes a (d, d) covariance on one device — beating the
 reference's 65535 packed cap (RapidsRowMatrix.scala:66-68) AND its GEMM

@@ -65,7 +65,9 @@ REPO_CHECKERS = (knobs.check_repo,)
 REPO_ROOT = Path(__file__).resolve().parents[2]
 
 #: The acceptance surface: every tree the CI gate sweeps.
-DEFAULT_PATHS = ("spark_rapids_ml_tpu", "tests", "benchmarks", "tools")
+DEFAULT_PATHS = (
+    "spark_rapids_ml_tpu", "tests", "benchmarks", "tools", "chip_smoke.py",
+)
 
 DEFAULT_BASELINE = Path(__file__).resolve().parent / "baseline.json"
 
