@@ -335,10 +335,7 @@ def as_partitions(
     analogue); anything else becomes one partition, optionally re-split into
     ``num_partitions`` roughly equal row blocks.
     """
-    if isinstance(data, (list, tuple)) and data and _is_block(data[0]):
-        parts = [_block_to_dense(b, dtype=dtype) for b in data]
-    else:
-        parts = [_block_to_dense(data, dtype=dtype)]
+    parts = [_block_to_dense(b, dtype=dtype) for b in partition_blocks(data)]
     d = parts[0].shape[1]
     for p in parts:
         if p.shape[1] != d:
@@ -346,6 +343,15 @@ def as_partitions(
     if num_partitions is not None and len(parts) == 1 and num_partitions > 1:
         parts = [np.ascontiguousarray(b) for b in np.array_split(parts[0], num_partitions)]
     return parts
+
+
+def partition_blocks(data: Any) -> Sequence[Any]:
+    """The blocks :func:`as_partitions` densifies one by one: the items of
+    a pre-partitioned ``list``/``tuple`` of 2-D blocks, else ``data``
+    itself as the only one."""
+    if isinstance(data, (list, tuple)) and data and _is_block(data[0]):
+        return data
+    return [data]
 
 
 def _is_block(obj: Any) -> bool:

@@ -36,11 +36,15 @@ from typing import Any, NamedTuple, Optional
 
 import numpy as np
 
-from spark_rapids_ml_tpu.core.data import as_partitions, is_device_array
+from spark_rapids_ml_tpu.core.data import (
+    as_partitions,
+    is_device_array,
+    partition_blocks,
+)
 from spark_rapids_ml_tpu.robustness.degrade import cpu_device, run_degradable
 from spark_rapids_ml_tpu.robustness.faults import fault_point
 from spark_rapids_ml_tpu.robustness.retry import default_policy, is_oom_error
-from spark_rapids_ml_tpu.utils.tracing import TraceColor, TraceRange
+from spark_rapids_ml_tpu.utils.tracing import StageRange, TraceColor, TraceRange
 
 
 def _reclaim_between_attempts(attempt: int, exc: BaseException) -> None:
@@ -62,6 +66,36 @@ def default_dtype():
     import jax.numpy as jnp
 
     return jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
+
+
+def dense_partitions(rows: Any, dtype=None) -> list:
+    """:func:`~spark_rapids_ml_tpu.core.data.as_partitions` as the fit
+    paths call it: under the ``densify`` stage, with the bytes of every
+    partition that had to be written (a block already dense in ``dtype``
+    is handed on as it is and costs none) added to
+    ``fit.stage.densify.bytes``."""
+    with StageRange("densify", TraceColor.PURPLE) as stage:
+        parts = as_partitions(rows, dtype=dtype)
+    stage.count_bytes(
+        sum(
+            part.nbytes
+            for part, block in zip(parts, partition_blocks(rows))
+            if not (isinstance(block, np.ndarray) and np.may_share_memory(part, block))
+        )
+    )
+    return parts
+
+
+def place_block(block: Any, put):
+    """``put(block)`` — the placement call of one block of rows — under
+    the ``place`` stage, with the block's bytes added to
+    ``fit.stage.place.bytes``. The stage times the call and waits for
+    nothing: a transfer the runtime finishes on its own threads after the
+    call has returned is not in it."""
+    with StageRange("place", TraceColor.CYAN) as stage:
+        out = put(block)
+    stage.count_bytes(block.nbytes)
+    return out
 
 
 class PreparedRows(NamedTuple):
@@ -88,9 +122,9 @@ def prepare_rows(
 ) -> PreparedRows:
     """Normalize any supported input into device-resident rows + mask.
 
-    Runs inside an ``ingest`` trace range (with nested ``ingest H2D``
-    ranges around each device placement) so fit reports attribute ingest
-    vs H2D vs solve time per stage."""
+    Runs inside an ``ingest`` trace range, with the ``densify`` stage
+    round the host copy and a ``place`` stage round each device placement
+    nested in it, so fit reports split ingest into the two."""
     with TraceRange("ingest", TraceColor.BLUE):
         return _prepare_rows_impl(rows, mesh, dtype, device_id, weights)
 
@@ -143,8 +177,9 @@ def _prepare_rows_impl(
                 # quietly moving to one CPU device would change the
                 # collective topology under the caller.
                 fault_point("ingest.device_put")
-                with TraceRange("ingest H2D", TraceColor.CYAN):
-                    return jax.device_put(arr, row_sharding(mesh))
+                return place_block(
+                    arr, lambda a: jax.device_put(a, row_sharding(mesh))
+                )
 
             x = default_policy().run(
                 _reshard, name="ingest.device_put",
@@ -159,7 +194,7 @@ def _prepare_rows_impl(
         return PreparedRows(x, mask, n, d)
 
     np_dtype = np.dtype(dtype or default_dtype())
-    parts = as_partitions(rows, dtype=np_dtype)
+    parts = dense_partitions(rows, dtype=np_dtype)
     n = sum(p.shape[0] for p in parts)
     d = parts[0].shape[1]
     m_dtype = _mask_dtype(np_dtype)
@@ -201,13 +236,19 @@ def _prepare_rows_impl(
         if m_dtype != x.dtype:
             mask = mask.astype(m_dtype)
     else:
-        x_host = parts[0] if len(parts) == 1 else np.concatenate(parts, axis=0)
+        if len(parts) == 1:
+            x_host = parts[0]
+        else:
+            with StageRange("densify", TraceColor.PURPLE) as stage:
+                x_host = np.concatenate(parts, axis=0)
+            stage.count_bytes(x_host.nbytes)
         device = jax.local_devices()[device_id] if device_id >= 0 else None
 
         def _place():
             fault_point("ingest.device_put")
-            with TraceRange("ingest H2D", TraceColor.CYAN):
-                return jax.device_put(jnp.asarray(x_host), device)
+            return place_block(
+                x_host, lambda h: jax.device_put(jnp.asarray(h), device)
+            )
 
         # Single-process placement is the degradable rung: if the
         # accelerator is unavailable (or placement exhausts its retry
@@ -264,8 +305,9 @@ def place_array(arr: Any, dtype=None, device=None):
 
     def _place():
         fault_point("ingest.device_put")
-        with TraceRange("ingest H2D", TraceColor.CYAN):
-            return jax.device_put(jnp.asarray(host), device)
+        return place_block(
+            host, lambda h: jax.device_put(jnp.asarray(h), device)
+        )
 
     return default_policy().run(
         _place, name="ingest.device_put", on_retry=_reclaim_between_attempts

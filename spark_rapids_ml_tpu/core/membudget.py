@@ -44,7 +44,7 @@ from spark_rapids_ml_tpu.observability.events import emit
 from spark_rapids_ml_tpu.robustness.degrade import record_degradation
 from spark_rapids_ml_tpu.robustness.retry import is_oom_error
 from spark_rapids_ml_tpu.utils.envknobs import env_choice, env_int
-from spark_rapids_ml_tpu.utils.tracing import bump_counter
+from spark_rapids_ml_tpu.utils.tracing import StageRange, bump_counter
 
 T = TypeVar("T")
 
@@ -248,7 +248,26 @@ def fit_memory_guard(
     ``extra_bytes`` prices sidecar device arrays sized with the input
     (labels, per-row stats); ``ledger_families`` names the cost-ledger
     program families whose measured temp+output bytes ride on top.
+
+    The whole decision is the ``admit`` stage of the fit.
     """
+    with StageRange("admit"):
+        return _price_fit_input(
+            family, rows, can_stream, why_cannot_stream, mesh, dtype,
+            ledger_families, extra_bytes,
+        )
+
+
+def _price_fit_input(
+    family: str,
+    rows: Any,
+    can_stream: bool,
+    why_cannot_stream: str,
+    mesh: Any,
+    dtype: Any,
+    ledger_families: Sequence[str],
+    extra_bytes: int,
+) -> FitAdmission:
     from spark_rapids_ml_tpu.core.data import host_rows_shape, is_streaming_source
 
     if mesh is not None or is_streaming_source(rows):
@@ -434,25 +453,28 @@ def run_fit_with_oom_recovery(
     except BaseException as exc:
         if not is_oom_error(exc):
             raise
-        bump_counter("fit.oom.events")
-        emit(
-            "fit_admission", action="oom", family=family,
-            error=type(exc).__name__,
-        )
-        _reclaim()
-        if fallback is None or not degrade_to_streaming_enabled():
-            bump_counter("fit.admission.rejected")
-            raise FitMemoryError(
-                family,
-                "device memory was exhausted mid-fit and this "
-                "configuration cannot degrade to streaming",
-            ) from exc
-        record_degradation(
-            f"{family} fit",
-            "device RESOURCE_EXHAUSTED mid-fit; caches reclaimed",
-            "streaming",
-            "the streaming fit path",
-        )
+        # The recovery's own work is admission work; the fallback fit it
+        # then runs opens its own stages.
+        with StageRange("admit"):
+            bump_counter("fit.oom.events")
+            emit(
+                "fit_admission", action="oom", family=family,
+                error=type(exc).__name__,
+            )
+            _reclaim()
+            if fallback is None or not degrade_to_streaming_enabled():
+                bump_counter("fit.admission.rejected")
+                raise FitMemoryError(
+                    family,
+                    "device memory was exhausted mid-fit and this "
+                    "configuration cannot degrade to streaming",
+                ) from exc
+            record_degradation(
+                f"{family} fit",
+                "device RESOURCE_EXHAUSTED mid-fit; caches reclaimed",
+                "streaming",
+                "the streaming fit path",
+            )
         result = fallback()
         bump_counter("fit.oom.recovered")
         emit("fit_admission", action="recovered", family=family, attempt=0)

@@ -22,11 +22,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from spark_rapids_ml_tpu.core.data import (
-    as_partitions,
     is_device_array,
     is_streaming_source,
     iter_stream_blocks,
 )
+from spark_rapids_ml_tpu.core.ingest import dense_partitions, place_block
 from spark_rapids_ml_tpu.ops.covariance import (
     centered_gram,
     centered_gram_packed,
@@ -46,7 +46,7 @@ from spark_rapids_ml_tpu.ops.eigh import (
 from spark_rapids_ml_tpu.ops.linalg import resolve_precision, triu_to_full
 from spark_rapids_ml_tpu.parallel.distributed_cov import distributed_mean_and_covariance
 from spark_rapids_ml_tpu.parallel.mesh import shard_rows_from_partitions
-from spark_rapids_ml_tpu.utils.tracing import TraceColor, TraceRange
+from spark_rapids_ml_tpu.utils.tracing import StageRange, TraceColor, TraceRange
 
 
 from functools import partial as _partial
@@ -138,7 +138,7 @@ class RowMatrix:
             self.partitions = None
             self._stream = rows
         else:
-            self.partitions = as_partitions(rows)
+            self.partitions = dense_partitions(rows)
             self._stream = None
         self.mean_centering = mean_centering
         self.use_gemm = use_gemm
@@ -284,10 +284,22 @@ class RowMatrix:
                 "one-pass covariance; use compute_covariance()"
             )
         with TraceRange("mean center", TraceColor.ORANGE):
-            state = welford_init(self.num_cols, dtype=self.dtype)
+            with StageRange("solve"):
+                state = welford_init(self.num_cols, dtype=self.dtype)
             for part in self.partitions:
-                state = welford_add_block(state, jnp.asarray(part, dtype=self.dtype))
+                blk = self._place_partition(part, jnp.asarray)
+                with StageRange("solve"):
+                    state = welford_add_block(state, blk)
             return state[1]
+
+    def _place_partition(self, part: np.ndarray, put):
+        """One host partition on the device in the compute dtype: the
+        ``convert`` stage (the host conversion ``jnp.asarray(part,
+        dtype=...)`` makes inside itself, taken out of it) and the
+        ``place`` stage round ``put``, the placement call of the pass."""
+        with StageRange("convert"):
+            host = np.asarray(part, dtype=self.dtype)
+        return place_block(host, put)
 
     # --- covariance (computeCovariance, :149-257) ---
 
@@ -378,15 +390,17 @@ class RowMatrix:
                     "backend='pallas' compiles f32 kernels; disable x64 or "
                     "pass dtype=jnp.float32 (or use backend='xla')"
                 )
+        put = _partial(jax.device_put, device=device)
         for part in self.partitions:
-            with TraceRange("gemm", TraceColor.GREEN):
-                blk = jax.device_put(np.asarray(part, dtype=self.dtype), device)
+            blk = self._place_partition(part, put)
+            with StageRange("solve", TraceColor.GREEN):
                 if use_pallas:
                     gram = centered_gram_pallas(blk, mean, interpret=interpret)
                 else:
                     gram = centered_gram(blk, mean, precision=self.precision)
-            acc = gram if acc is None else acc + gram
-        return acc / (self.num_rows - 1)
+                acc = gram if acc is None else acc + gram
+        with StageRange("solve", TraceColor.GREEN):
+            return acc / (self.num_rows - 1)
 
     @staticmethod
     def _native_spr_covariance(blocks, center: bool):
@@ -446,11 +460,13 @@ class RowMatrix:
         )
         acc = None
         for part in self.partitions:
-            blk = jnp.asarray(part, dtype=self.dtype)
-            packed = centered_gram_packed(blk, mean)
-            acc = packed if acc is None else acc + packed
-        full = triu_to_full(acc)
-        return full / (self.num_rows - 1)
+            blk = self._place_partition(part, jnp.asarray)
+            with StageRange("solve"):
+                packed = centered_gram_packed(blk, mean)
+                acc = packed if acc is None else acc + packed
+        with StageRange("solve"):
+            full = triu_to_full(acc)
+            return full / (self.num_rows - 1)
 
     def _covariance_streaming(self) -> jnp.ndarray:
         """Constant-memory covariance over a streaming block source: one
@@ -618,7 +634,7 @@ class RowMatrix:
                 raise ValueError(f"need at least 2 rows, got {n}")
             if not 1 <= k <= n_cols:
                 raise ValueError(f"k must be in [1, {n_cols}], got {k}")
-            with TraceRange("fused device fit", TraceColor.RED):
+            with TraceRange("fused device fit", TraceColor.RED), StageRange("solve"):
                 u, explained = _pca_fit_device(
                     self._device_array_on_mesh(),
                     k,
@@ -653,19 +669,19 @@ class RowMatrix:
             # rather than silently ignored ("auto" stays with the exact
             # host solve: the fp64 path exists for accuracy, not speed).
             if self.eigen_solver == "topk" and k < n_cols:
-                with TraceRange("host fp64 topk", TraceColor.BLUE):
+                with TraceRange("host fp64 topk", TraceColor.BLUE), StageRange("solve"):
                     w_k, u_k = eigh_topk_host(np.asarray(cov), k)
                     w_k = np.clip(w_k, 0, None)
                     total = float(np.trace(np.asarray(cov)))
                     explained = w_k / total if total > 0 else w_k
                     return u_k, explained
-            with TraceRange("host fp64 SVD", TraceColor.BLUE):
+            with TraceRange("host fp64 SVD", TraceColor.BLUE), StageRange("solve"):
                 w, u = eigh_descending_host(np.asarray(cov))
         elif self.eigen_solver == "topk" and k < n_cols:
             # Subspace iteration + Rayleigh-Ritz: O(d^2 k) MXU matmuls
             # instead of the full O(d^3) eigensolve — exact explained-
             # variance RATIOS come from the trace, so nothing is lost.
-            with TraceRange("topk eigh", TraceColor.BLUE):
+            with TraceRange("topk eigh", TraceColor.BLUE), StageRange("solve"):
                 w_k, u_k = eigh_topk(jnp.asarray(cov), k, iters=self.eigen_iters)
                 w_k = np.clip(np.asarray(w_k), 0, None)
                 total = float(np.trace(np.asarray(cov)))
@@ -674,7 +690,7 @@ class RowMatrix:
         elif self.eigen_solver == "auto" and k < n_cols and self.use_accel_svd:
             # Self-selecting: subspace iteration that promotes itself to
             # the full eigensolver when the spectrum defeats it (eigh_auto).
-            with TraceRange("auto eigh", TraceColor.BLUE):
+            with TraceRange("auto eigh", TraceColor.BLUE), StageRange("solve"):
                 w_k, u_k, _ = eigh_auto(
                     jnp.asarray(cov), k, max_iters=auto_max_iters(self.eigen_iters)
                 )
@@ -683,11 +699,11 @@ class RowMatrix:
                 explained = w_k / total if total > 0 else w_k
                 return np.asarray(u_k), explained
         elif self.use_accel_svd:
-            with TraceRange("xla SVD", TraceColor.BLUE):
+            with TraceRange("xla SVD", TraceColor.BLUE), StageRange("solve"):
                 w, u = eigh_descending(cov)
                 u, w = np.asarray(u), np.asarray(w)
         else:
-            with TraceRange("cpu SVD", TraceColor.BLUE):
+            with TraceRange("cpu SVD", TraceColor.BLUE), StageRange("solve"):
                 # Host LAPACK SVD — the breeze brzSvd analogue (:110-123).
                 # For symmetric PSD cov the singular values ARE eigenvalues.
                 u, w, _ = np.linalg.svd(np.asarray(cov, dtype=np.float64))

@@ -11,8 +11,9 @@ the instrumented path and NOTHING when disarmed.
 :func:`dump` writes ``flight-<pid>.json`` — ring contents, all-thread
 Python stacks, lockcheck held/waiting state, a metrics snapshot, the
 cost-ledger snapshot when armed, and trace roots — into
-``TPUML_FLIGHT_DIR`` (default: the active telemetry dir). Three
-triggers install via :func:`arm`:
+``TPUML_FLIGHT_DIR`` (default: the active telemetry dir, else
+``tpuml-flight`` under the temporary directory). Three triggers install
+via :func:`arm`:
 
   - **fatal exception** — ``sys.excepthook`` / ``threading.excepthook``
     chain (the original hooks still run);
@@ -80,14 +81,19 @@ def _thread_stacks() -> List[dict]:
 
 def flight_dir() -> str:
     """Where dumps land: ``TPUML_FLIGHT_DIR``, else the active telemetry
-    dir, else the working directory."""
+    dir, else ``tpuml-flight`` under the system's temporary directory —
+    never the working directory, which is as a rule somebody's checkout."""
     d = env_str(FLIGHT_DIR_ENV)
     if d:
         return os.path.abspath(d)
     from spark_rapids_ml_tpu.observability import events as _ev
 
     tdir = _ev.telemetry_dir()
-    return os.path.abspath(tdir) if tdir else os.getcwd()
+    if tdir:
+        return os.path.abspath(tdir)
+    import tempfile
+
+    return os.path.join(tempfile.gettempdir(), "tpuml-flight")
 
 
 def build_doc(reason: str, detail: Optional[dict] = None) -> dict:

@@ -192,10 +192,24 @@ class RunReport:
         return out
 
     def _render_tree(self, nodes: List[dict], indent: int, lines: List[str]) -> None:
+        # Leaves of one name under one parent (a partition loop's stage
+        # spans: 40 partitions open 240) fold into one line, ``name xN``
+        # with their total, at the first one's place; stage_tree() keeps
+        # every span.
+        folded: Dict[str, List[dict]] = {}
         for n in nodes:
-            flag = "" if n["ok"] else f"  !! {n['exc'] or 'failed'}"
+            if not n["children"]:
+                folded.setdefault(n["name"], []).append(n)
+        for n in nodes:
+            group = [n] if n["children"] else folded.pop(n["name"], None)
+            if group is None:
+                continue  # rendered with the first leaf of its name
+            label = n["name"] if len(group) == 1 else f"{n['name']} x{len(group)}"
+            failed = next((g for g in group if not g["ok"]), None)
+            flag = "" if failed is None else f"  !! {failed['exc'] or 'failed'}"
+            dur = sum(g["dur"] for g in group)
             lines.append(
-                f"{'  ' * indent}{n['name']:<32s} {n['dur'] * 1e3:10.2f} ms{flag}"
+                f"{'  ' * indent}{label:<32s} {dur * 1e3:10.2f} ms{flag}"
             )
             self._render_tree(n["children"], indent + 1, lines)
 

@@ -356,7 +356,7 @@ KNOBS: Dict[str, Knob] = _knob_table(
          "stall strike (0 = recorder off)", default=0),
     Knob("TPUML_FLIGHT_DIR", "str", "ops-plane",
          "directory for flight-recorder dumps (default: the active "
-         "TPUML_TELEMETRY_DIR, else the process working directory)"),
+         "TPUML_TELEMETRY_DIR, else tpuml-flight under the temp dir)"),
     # benchmark shape overrides (benchmarks/ only)
     Knob("TPUML_BENCH_ROWS", "int", "benchmarks",
          "row-count override for serving benchmarks"),

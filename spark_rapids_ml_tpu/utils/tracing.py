@@ -1,25 +1,29 @@
-"""Profiling ranges + counter aliases — compat shim over ``observability/``.
+"""Spans and counters — the one import the instrumented layers use.
 
-Historically this module WAS the observability layer: an NVTX-parity
-RAII range (reference ``NvtxRange``, NvtxRange.java:37-58, 9 ARGB colors
-NvtxColor.java:20-29, JNI push/pop rapidsml_jni.cu:32-34) backed by
-``jax.profiler.TraceAnnotation``, a ring buffer of (name, start, end)
-for profiler-less assertions, and a flat counter dict. The typed metrics
-registry, the JSONL event log, reports and heartbeats now live in
-``spark_rapids_ml_tpu/observability/``; this module keeps every legacy
-name working and remains the one import the instrumented layers use:
+An NVTX-parity RAII range (reference ``NvtxRange``, NvtxRange.java:37-58,
+9 ARGB colors NvtxColor.java:20-29, JNI push/pop rapidsml_jni.cu:32-34)
+backed by ``jax.profiler.TraceAnnotation``, a ring buffer of
+(name, start, end) for profiler-less assertions, and the flat counter
+surface over the typed registry. The registry itself, the JSONL event
+log, reports and heartbeats live in ``spark_rapids_ml_tpu/observability/``;
+everything a fit or serving path calls to be seen is here:
 
-  - :class:`TraceRange` / ``NvtxRange`` — the RAII range, now also
-    recording span id / parent id / depth, an ``ok`` flag and the
-    exception type when the body raises (the old ``__exit__`` dropped
-    ``exc`` on the floor), feeding the ambient run context (for
-    ``model.fit_report()`` stage trees) and the event log (as ``span``
-    records) when either is active. The ring buffer keeps its exact
-    3-tuple shape; the disabled path stays allocation-light (budget test
-    in tests/test_observability.py).
+  - :class:`TraceRange` / ``NvtxRange`` — the RAII range: span id /
+    parent id / depth, an ``ok`` flag and the exception type when the
+    body raises, feeding the ambient run context (for
+    ``model.fit_report()`` stage trees), the ring (``recent_events``,
+    the ops plane's ``/tracez``) and the event log (as ``span`` records)
+    when either is active. Inside a profiler session every range is in
+    the trace's host plane, on the device trace's clock. The disabled
+    path stays allocation-light (budget test in
+    tests/test_observability.py).
+  - :class:`StageRange` — a leaf range of the host fit path (``admit``,
+    ``densify``, ``convert``, ``place``, ``solve``) whose exit also adds
+    its duration to ``fit.stage.<stage>.ns`` and 1 to
+    ``fit.stage.<stage>.calls``: the split of a fit's host time that the
+    benchmark's ``host_*_ms`` metrics read.
   - ``bump_counter`` / ``counter_value`` / ``counters`` /
-    ``clear_counters`` — aliases over the typed registry's counters,
-    same flat-dict semantics as before.
+    ``clear_counters`` — the typed registry's counters as a flat dict.
 """
 
 from __future__ import annotations
@@ -65,7 +69,7 @@ _events_lock = make_lock("tracing.events")
 _events: Deque[Tuple[str, float, float]] = deque(maxlen=4096)
 
 
-# --- counter aliases (the PR 2 surface, now registry-backed) ---
+# --- counters (registry-backed) ---
 
 
 def bump_counter(name: str, amount: int = 1) -> None:
@@ -161,7 +165,8 @@ class TraceRange:
 
     Same call sites as the reference's instrumentation (RapidsRowMatrix.scala:
     78 "compute cov" RED, :153 "mean center" ORANGE, :183 "concat before cov"
-    PURPLE, :193 "gemm" GREEN, :88/:111 "SVD" BLUE).
+    PURPLE, :88/:111 "SVD" BLUE); its per-partition :193 "gemm" GREEN is
+    here the three stages it held (:class:`StageRange`: convert, place, solve).
 
     Each range carries a process-unique ``span_id``; nesting is tracked
     per thread, so ``parent_id``/``depth`` let reports rebuild the stage
@@ -172,7 +177,7 @@ class TraceRange:
     """
 
     __slots__ = (
-        "name", "color", "_annotation", "_start",
+        "name", "color", "_annotation", "_start", "_end",
         "span_id", "parent_id", "depth", "ok", "exc_type",
     )
 
@@ -181,6 +186,7 @@ class TraceRange:
         self.color = color
         self._annotation = jax.profiler.TraceAnnotation(name)
         self._start = 0.0
+        self._end = 0.0
         self.ok = True
         self.exc_type: Optional[str] = None
 
@@ -209,7 +215,7 @@ class TraceRange:
 
     def __exit__(self, exc_type=None, exc=None, tb=None) -> None:
         self._annotation.__exit__(exc_type, exc, tb)
-        end = time.perf_counter()
+        end = self._end = time.perf_counter()
         stack = _stack()
         if stack and stack[-1] == self.span_id:
             stack.pop()
@@ -252,3 +258,46 @@ class TraceRange:
 
 # Alias matching the reference class name (NvtxRange.java:37).
 NvtxRange = TraceRange
+
+
+# --- stages: the leaves of the host fit path ---
+
+#: Every stage a fit path opens. The counters ``fit.stage.<stage>.ns`` and
+#: ``.calls`` (and ``.bytes`` for ``densify`` and ``place``, whose sites know
+#: the size: :meth:`StageRange.count_bytes`) are what
+#: ``perfbench/metrics/host_*`` read.
+STAGES = ("admit", "densify", "convert", "place", "solve")
+_STAGE_COUNTERS = {s: (f"fit.stage.{s}.ns", f"fit.stage.{s}.calls") for s in STAGES}
+
+
+class StageRange(TraceRange):
+    """A :class:`TraceRange` named for one of :data:`STAGES` whose exit also
+    adds its duration to ``fit.stage.<stage>.ns`` and 1 to
+    ``fit.stage.<stage>.calls``.
+
+    Stages are leaves: one thread never opens a stage inside a stage, so
+    their ``ns`` can be added up and held against a fit's wall time (the
+    tests' ``stages_never_nest`` fixture asserts it on every fit they run).
+    A stage synchronises nothing: round an asynchronous call it measures
+    what the host spent in the call, and the host blocked on the device
+    shows in the stage that makes the blocking read.
+    """
+
+    __slots__ = ()
+
+    def __init__(self, stage: str, color: Optional[TraceColor] = None):
+        if stage not in _STAGE_COUNTERS:
+            raise ValueError(f"unknown stage {stage!r}: one of {STAGES}")
+        super().__init__(stage, color)
+
+    def __exit__(self, exc_type=None, exc=None, tb=None) -> None:
+        super().__exit__(exc_type, exc, tb)
+        ns, calls = _STAGE_COUNTERS[self.name]
+        bump_counter(ns, int((self._end - self._start) * 1e9))
+        bump_counter(calls)
+
+    def count_bytes(self, nbytes: int) -> None:
+        """Add the bytes this stage wrote or handed over to
+        ``fit.stage.<stage>.bytes`` (called after the ``with`` block, so
+        the count is not in the stage's own time)."""
+        bump_counter(f"fit.stage.{self.name}.bytes", int(nbytes))

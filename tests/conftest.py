@@ -129,3 +129,30 @@ if not any(
 def _clear_jax_caches_per_module():
     yield
     jax.clear_caches()
+
+
+# Stage spans (utils/tracing.py::StageRange) are leaves: the sum of their
+# durations is held against a fit's wall time, which a stage opened inside
+# a stage on the same thread would count twice. Production pays nothing
+# for the rule; here every fit of every test is held to it.
+@pytest.fixture(autouse=True)
+def stages_never_nest(monkeypatch):
+    import threading
+
+    from spark_rapids_ml_tpu.utils import tracing
+
+    open_stage = threading.local()
+    enter, leave = tracing.StageRange.__enter__, tracing.StageRange.__exit__
+
+    def checked_enter(self):
+        outer = getattr(open_stage, "name", None)
+        assert outer is None, f"stage {self.name!r} opened inside stage {outer!r}"
+        open_stage.name = self.name
+        return enter(self)
+
+    def checked_exit(self, exc_type=None, exc=None, tb=None):
+        open_stage.name = None
+        return leave(self, exc_type, exc, tb)
+
+    monkeypatch.setattr(tracing.StageRange, "__enter__", checked_enter)
+    monkeypatch.setattr(tracing.StageRange, "__exit__", checked_exit)

@@ -1,5 +1,6 @@
 """Structured run telemetry (observability/): the metrics registry, the
-JSONL event log, fit/serve reports, heartbeats, and the compat shim.
+JSONL event log, fit/serve reports, heartbeats, and the span and counter surface
+(utils/tracing.py).
 
 The acceptance case (TestAcceptance) is the ISSUE 4 contract: one
 ``LogisticRegression.fit`` + ``transform`` on the fault-injection
@@ -352,21 +353,58 @@ class TestEventLog:
             pass
         assert events.emitted_count() == before
 
-    def test_range_path_allocation_budget(self, no_event_log):
+    @pytest.mark.parametrize(
+        "make",
+        [lambda: tracing.TraceRange("budget"), lambda: tracing.StageRange("solve")],
+        ids=["range", "stage"],
+    )
+    def test_range_path_allocation_budget(self, no_event_log, make):
         n = 300
-        with tracing.TraceRange("warmup"):
+        with make():
             pass
         tracemalloc.start()
         base, _ = tracemalloc.get_traced_memory()
         for _ in range(n):
-            with tracing.TraceRange("budget"):
+            with make():
                 pass
         current, peak = tracemalloc.get_traced_memory()
         tracemalloc.stop()
         # Disabled path: a range object, an annotation, one ring tuple —
         # nowhere near 4 KiB each. A span-record dict per range would
-        # blow this bound, which is the regression the test pins.
+        # blow this bound, which is the regression the test pins. A stage
+        # adds two counter increments and keeps nothing.
         assert peak - base < n * 4096
+
+    def test_stage_costs_a_range_and_two_counter_increments(
+        self, no_event_log, monkeypatch
+    ):
+        monkeypatch.undo()  # production's StageRange, without conftest's nesting check
+
+        def per_call(body, n=2000, repeats=7):
+            best = float("inf")
+            for _ in range(repeats):
+                t0 = time.perf_counter()
+                for _ in range(n):
+                    body()
+                best = min(best, (time.perf_counter() - t0) / n)
+            return best
+
+        def a_range():
+            with tracing.TraceRange("budget"):
+                pass
+
+        def a_stage():
+            with tracing.StageRange("solve"):
+                pass
+
+        def two_bumps():
+            tracing.bump_counter("budget.ns", 12345)
+            tracing.bump_counter("budget.calls")
+
+        # The least of seven rounds each, taken in one process: what is
+        # left over the sum pays for the counters' names and one int().
+        budget = per_call(a_range) + per_call(two_bumps)
+        assert per_call(a_stage) < 1.5 * budget
 
 
 # --- heartbeats ---------------------------------------------------------
@@ -529,11 +567,11 @@ class TestAcceptance:
         retry_nodes = [
             c for c in ingest["children"] if c["name"].startswith("retry:")
         ]
-        # Attempt 0 dies at the injected fault (before H2D); attempt 1
-        # carries the actual placement.
+        # Attempt 0 dies at the injected fault (before the placement);
+        # attempt 1 carries it, as the `place` stage.
         assert len(retry_nodes) == 2
         assert any(
-            g["name"] == "ingest H2D" for rn in retry_nodes
+            g["name"] == "place" for rn in retry_nodes
             for g in rn["children"]
         )
         assert any(s["name"] == "checkpoint write" for s in spans)

@@ -803,6 +803,38 @@ class TestFlightRecorder:
             flightrec.reset()
             events.configure()
 
+    def test_dump_lands_under_the_temp_dir_when_no_dir_is_named(
+        self, tmp_path, monkeypatch
+    ):
+        """With neither ``TPUML_FLIGHT_DIR`` nor a telemetry dir, a dump
+        goes to ``tpuml-flight`` under the temporary directory and never
+        to the working directory (dumps once landed in the checkout and
+        were committed)."""
+        import tempfile
+
+        monkeypatch.delenv(flightrec.FLIGHT_DIR_ENV, raising=False)
+        monkeypatch.delenv(events.TELEMETRY_DIR_ENV, raising=False)
+        monkeypatch.setattr(tempfile, "tempdir", str(tmp_path / "tmp"))
+        cwd = tmp_path / "checkout"
+        cwd.mkdir()
+        monkeypatch.chdir(cwd)
+        assert flightrec.flight_dir() == str(tmp_path / "tmp" / "tpuml-flight")
+        flightrec.reset()
+        try:
+            dest = flightrec.dump("test-no-dir")
+        finally:
+            flightrec.reset()
+        assert dest == str(
+            tmp_path / "tmp" / "tpuml-flight" / f"flight-{os.getpid()}.json"
+        )
+        assert json.load(open(dest))["reason"] == "test-no-dir"
+        assert not list(cwd.iterdir())
+        # a named directory still wins, then the telemetry dir
+        monkeypatch.setenv(events.TELEMETRY_DIR_ENV, str(tmp_path / "telemetry"))
+        assert flightrec.flight_dir() == str(tmp_path / "telemetry")
+        monkeypatch.setenv(flightrec.FLIGHT_DIR_ENV, str(tmp_path / "named"))
+        assert flightrec.flight_dir() == str(tmp_path / "named")
+
     def test_sigterm_flush_publishes_manifest_and_flight(self, telemetry):
         """The SIGTERM handler the serving worker and barrier members
         install: flight dump + telemetry flush BEFORE SystemExit(143),

@@ -1,0 +1,243 @@
+"""Stage spans (utils/tracing.py::StageRange): the leaves of the host fit
+path — ``admit``, ``densify``, ``convert``, ``place``, ``solve`` — whose
+``fit.stage.<stage>.ns`` / ``.calls`` / ``.bytes`` counters split a fit's
+host time for the benchmark's ``host_*`` metrics.
+
+What is held here: a stage is a TraceRange plus its two counters and
+nothing else; stages of one thread never nest (conftest's
+``stages_never_nest`` fixture fires on a planted nesting); a host-partition
+PCA fit opens every stage, places its rows twice, and its stages add up to
+no more than its wall; inside a profiler session the stages are in the
+trace's host plane; a device-array fit opens none of the host stages.
+"""
+
+import glob
+import os
+import threading
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from spark_rapids_ml_tpu.core.ingest import dense_partitions
+from spark_rapids_ml_tpu.models.kmeans import KMeans
+from spark_rapids_ml_tpu.models.pca import PCA
+from spark_rapids_ml_tpu.utils import tracing
+from spark_rapids_ml_tpu.utils.tracing import STAGES, StageRange, TraceRange
+
+
+def stage_counters() -> dict:
+    return tracing.counters("fit.stage.")
+
+
+def delta(before: dict) -> dict:
+    return {k: v - before.get(k, 0) for k, v in stage_counters().items()}
+
+
+def f32_partitions(seed: int, parts: int = 4, rows: int = 25, cols: int = 6) -> list:
+    rng = np.random.default_rng(seed)
+    return [rng.standard_normal((rows, cols)).astype(np.float32) for _ in range(parts)]
+
+
+class TestStageRange:
+    def test_adds_to_its_two_counters_and_nothing_else_new(self):
+        before = tracing.counters()
+        with StageRange("convert") as span:
+            time.sleep(0.002)
+        after = tracing.counters()
+        changed = {k: after[k] - before.get(k, 0) for k in after if after[k] != before.get(k, 0)}
+        assert set(changed) == {"fit.stage.convert.ns", "fit.stage.convert.calls"}
+        assert changed["fit.stage.convert.calls"] == 1
+        # the counter holds the span's own duration, not a second reading
+        name, start, end = tracing.recent_events()[-1]
+        assert name == "convert"
+        assert changed["fit.stage.convert.ns"] == int((end - start) * 1e9) >= 2_000_000
+        assert isinstance(span, TraceRange) and span.ok
+
+    def test_a_stage_that_raises_is_still_counted(self):
+        before = stage_counters()
+        with pytest.raises(KeyError):
+            with StageRange("place") as span:
+                raise KeyError("x")
+        assert delta(before)["fit.stage.place.calls"] == 1
+        assert not span.ok and span.exc_type == "KeyError"
+
+    def test_a_stage_is_a_node_of_the_run_tree(self):
+        from spark_rapids_ml_tpu.observability import events
+        from spark_rapids_ml_tpu.observability.report import build_stage_tree
+
+        with events.run_scope("job", "stages") as ctx:
+            with TraceRange("parent"):
+                with StageRange("solve"):
+                    pass
+            tree = build_stage_tree(ctx.span_window(0))
+        (parent,) = [n for n in tree if n["name"] == "parent"]
+        assert [c["name"] for c in parent["children"]] == ["solve"]
+
+
+class TestStagesNeverNest:
+    def test_planted_nesting_fires(self):
+        with pytest.raises(AssertionError, match="'place' opened inside stage 'solve'"):
+            with StageRange("solve"):
+                with StageRange("place"):
+                    pass
+
+    def test_unknown_stage_is_refused(self):
+        with pytest.raises(ValueError, match="unknown stage 'gemm'"):
+            StageRange("gemm")
+
+    def test_a_plain_range_inside_a_stage_is_no_nesting(self):
+        with StageRange("solve"):
+            with TraceRange("retry:0"):
+                pass
+        with StageRange("solve"):  # and the stage before it was closed
+            pass
+
+    def test_stages_of_two_threads_may_be_open_at_once(self):
+        inside, release, errors = threading.Event(), threading.Event(), []
+
+        def other():
+            try:
+                with StageRange("place"):
+                    inside.set()
+                    release.wait(10)
+            except BaseException as exc:  # noqa: BLE001 - handed to the asserting thread
+                errors.append(exc)
+
+        worker = threading.Thread(target=other)
+        worker.start()
+        assert inside.wait(10)
+        with StageRange("solve"):
+            pass
+        release.set()
+        worker.join(10)
+        assert not worker.is_alive() and not errors
+
+
+class TestHostPartitionFit:
+    def test_pca_fit_opens_every_stage_and_places_its_rows_twice(self):
+        parts = f32_partitions(11)
+        before = stage_counters()
+        t0 = time.perf_counter()
+        model = PCA().setK(2).fit(parts)
+        np.asarray(model.pc)
+        wall_ns = (time.perf_counter() - t0) * 1e9
+        got = delta(before)
+        for stage in STAGES:
+            assert got[f"fit.stage.{stage}.calls"] > 0, stage
+            assert got[f"fit.stage.{stage}.ns"] >= 0, stage
+        # one conversion and one placement a partition in each of the two
+        # passes (the means, then the Gram), in the compute dtype
+        assert got["fit.stage.convert.calls"] == got["fit.stage.place.calls"] == 2 * len(parts)
+        rows, cols = sum(p.shape[0] for p in parts), parts[0].shape[1]
+        itemsize = np.dtype(jnp.zeros(0).dtype).itemsize  # float64 under the tests' x64
+        assert got["fit.stage.place.bytes"] == 2 * rows * cols * itemsize
+        # RowMatrix densifies to float64 whatever the source: every float32
+        # partition is written anew
+        assert got["fit.stage.densify.calls"] == 1
+        assert got["fit.stage.densify.bytes"] == rows * cols * 8
+        assert sum(got[f"fit.stage.{stage}.ns"] for stage in STAGES) <= wall_ns
+
+    def test_fit_report_shows_the_stages_under_their_parents(self):
+        model = PCA().setK(2).fit(f32_partitions(12))
+        tree = model.fit_report().stage_tree()
+
+        def find(nodes, name):
+            for node in nodes:
+                if node["name"] == name:
+                    return node
+                hit = find(node["children"], name)
+                if hit is not None:
+                    return hit
+            return None
+
+        cov = find(tree, "compute cov")
+        means = find(cov["children"], "mean center")
+        assert {c["name"] for c in means["children"]} == {"convert", "place", "solve"}
+        assert {"convert", "place", "solve"} <= {c["name"] for c in cov["children"]}
+        assert [c["name"] for c in find(tree, "auto eigh")["children"]] == ["solve"]
+        assert find(tree, "admit") is not None and find(tree, "densify") is not None
+        assert find(tree, "gemm") is None  # the span the three stages replaced
+
+    def test_printed_report_folds_the_leaves_of_one_name(self):
+        parts = f32_partitions(17)
+        report = PCA().setK(2).fit(parts).fit_report()
+        text = str(report)
+        # the means pass: a conversion and a placement a partition, and a
+        # dispatch a partition after the one that makes the accumulator
+        assert f"convert x{len(parts)}" in text and f"place x{len(parts)}" in text
+        assert f"solve x{len(parts) + 1}" in text
+        assert text.count("convert") == 2  # one line a pass, not one a partition
+        assert report.stage_totals()["convert"]["calls"] == 2 * len(parts)
+
+    def test_a_float64_partition_already_dense_is_not_written(self):
+        rng = np.random.default_rng(13)
+        f64, f32 = rng.standard_normal((8, 3)), rng.standard_normal((8, 3)).astype(np.float32)
+        before = stage_counters()
+        parts = dense_partitions([f64, f32])
+        assert parts[0] is f64 and parts[1].dtype == np.float64
+        assert delta(before)["fit.stage.densify.bytes"] == f32.size * 8
+
+    def test_kmeans_host_fit_densifies_and_places_through_the_funnel(self):
+        x = np.random.default_rng(14).standard_normal((60, 4)).astype(np.float32)
+        before = stage_counters()
+        KMeans().setK(3).setMaxIter(2).fit(x)
+        got = delta(before)
+        assert got["fit.stage.densify.calls"] >= 1
+        itemsize = np.dtype(jnp.zeros(0).dtype).itemsize
+        assert got["fit.stage.place.bytes"] >= x.size * itemsize
+        assert got.get("fit.stage.convert.calls", 0) == 0  # the funnel densifies in the compute dtype
+
+
+class TestDeviceArrayFit:
+    def test_opens_no_host_stage(self):
+        x = jnp.asarray(np.random.default_rng(15).standard_normal((40, 5)))
+        before = stage_counters()
+        model = PCA().setK(2).fit(x)
+        np.asarray(model.pc)
+        got = delta(before)
+        for stage in ("densify", "convert", "place"):
+            assert got.get(f"fit.stage.{stage}.calls", 0) == 0, stage
+            assert got.get(f"fit.stage.{stage}.bytes", 0) == 0, stage
+        assert got["fit.stage.admit.calls"] == 1 and got["fit.stage.solve.calls"] == 1
+
+
+class TestProfilerSession:
+    def test_stages_are_in_the_host_plane_inside_the_enclosing_span(self, tmp_path):
+        from jax.profiler import ProfileData
+
+        parts = f32_partitions(16)
+        PCA().setK(2).fit(parts)  # compile outside the session
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with TraceRange("enclosing fit"):
+                model = PCA().setK(2).fit(parts)
+                np.asarray(model.pc)
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+        names = set(STAGES) | {"enclosing fit", "compute cov", "mean center"}
+        events = {}
+        for plane in ProfileData.from_file(path).planes:
+            if plane.name.startswith("/host:"):
+                for line in plane.lines:
+                    for ev in line.events:
+                        if ev.name in names:
+                            events.setdefault(ev.name, []).append(
+                                (ev.start_ns, ev.start_ns + ev.duration_ns)
+                            )
+        (fit,) = events["enclosing fit"]
+        for stage in STAGES:
+            assert events.get(stage), f"no {stage} event in the host plane"
+            for start, end in events[stage]:
+                assert fit[0] <= start <= end <= fit[1], stage
+        assert len(events["convert"]) == len(events["place"]) == 2 * len(parts)
+        # and under the reference's parents: every conversion and placement
+        # lies inside the covariance span, half of them inside the means pass
+        (cov,) = events["compute cov"]
+        (means,) = events["mean center"]
+        for stage in ("convert", "place"):
+            assert all(cov[0] <= s and e <= cov[1] for s, e in events[stage])
+            assert sum(means[0] <= s and e <= means[1] for s, e in events[stage]) == len(parts)
