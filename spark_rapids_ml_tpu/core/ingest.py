@@ -11,7 +11,10 @@ UMAP) shares one implementation instead of forking the dispatch.
 
 Host inputs keep their floating dtype on the way in: a float32 numpy
 source is placed as float32 — the old ``as_matrix`` path materialized an
-intermediate float64 copy (2x host RAM) only to cast back down.
+intermediate float64 copy (2x host RAM) only to cast back down. The same
+holds for PCA's ``RowMatrix``, which enters through
+:func:`dense_partitions` with the dtype its route reads (float64 only
+for the dd and packed routes, which compute on host float64).
 
 Contract of :func:`prepare_rows`:
 

@@ -112,6 +112,12 @@ class RowMatrix:
         eigen_solver: str = "full",
         eigen_iters: int = 8,
     ):
+        # Resolved before the rows are looked at: the dtype the host
+        # partitions are made in follows the route (below).
+        self.precision = self.resolve(
+            precision, mesh=mesh, input_dtype=input_dtype, backend=backend
+        )
+        self._dtype = dtype
         # Streaming sources (block iterators / readers / iterator
         # factories) are never materialized: the covariance runs as a
         # one-pass shifted accumulation at constant memory — the
@@ -138,16 +144,29 @@ class RowMatrix:
             self.partitions = None
             self._stream = rows
         else:
-            self.partitions = dense_partitions(rows)
+            # Host partitions are densified in the dtype their route
+            # reads: float64 for the routes that compute on host float64
+            # (dd's shift is an exact host-float64 subtract; the packed
+            # route's native accumulator takes float64 and its no-native
+            # fallback may route to dd), the compute dtype for every route
+            # that places them on the device (per-partition GEMM, pallas,
+            # mesh) — float32 on a chip without x64, so a float32 source
+            # is not widened here and narrowed again at every placement.
+            # A block already dense in that dtype IS the caller's buffer,
+            # and the device then reads it directly. Every host route of a
+            # fit ends with a host read of the eigensolve's result, which
+            # depends on every placed partition, so no transfer is still
+            # in flight when fit returns the buffers to the caller.
+            host_f64 = self.precision == "dd" or not use_gemm
+            self.partitions = dense_partitions(
+                rows, dtype=np.float64 if host_f64 else np.dtype(self.dtype)
+            )
             self._stream = None
         self.mean_centering = mean_centering
         self.use_gemm = use_gemm
         self.use_accel_svd = use_accel_svd
         self.device_id = device_id
         self.mesh = mesh
-        self.precision = self.resolve(
-            precision, mesh=mesh, input_dtype=input_dtype, backend=backend
-        )
         if self.precision == "dd" and self._device_x is not None:
             raise ValueError(
                 "precision='dd' is the host-streaming fp64 emulation; a "
@@ -201,16 +220,16 @@ class RowMatrix:
         if eigen_iters < 1:
             raise ValueError(f"eigen_iters must be >= 1, got {eigen_iters}")
         self.eigen_iters = int(eigen_iters)
-        self._dtype = dtype
 
     @staticmethod
     def resolve(precision: str, mesh=None, input_dtype=None, backend: str = "xla") -> str:
         """THE home of precision-request resolution (PCA calls this too —
         keep the policy in one place). ``input_dtype`` is the dtype of the
-        RAW user container, probed by the caller before as_partitions
-        coerced blocks to float64 (core.data.infer_input_dtype). Without
-        it, "auto" must not trust partitions[0].dtype (always float64
-        post-coercion) — it resolves to "highest" rather than silently
+        RAW user container, probed by the caller before the rows are
+        densified (core.data.infer_input_dtype). Without it, "auto" must
+        not trust partitions[0].dtype (the dtype the resolved route reads,
+        not the source's: the compute dtype, or float64 on the dd and
+        packed routes) — it resolves to "highest" rather than silently
         routing every fit through the slow dd emulation. With a mesh,
         "auto" defers to the mesh covariance path (dd has no mesh route).
         Under ``backend="pallas"`` (an fp32-kernel choice), auto-resolved
@@ -296,7 +315,11 @@ class RowMatrix:
         """One host partition on the device in the compute dtype: the
         ``convert`` stage (the host conversion ``jnp.asarray(part,
         dtype=...)`` makes inside itself, taken out of it) and the
-        ``place`` stage round ``put``, the placement call of the pass."""
+        ``place`` stage round ``put``, the placement call of the pass.
+        The GEMM and pallas routes hold their partitions in the compute
+        dtype already (``__init__``), so ``convert`` hands the partition
+        on as it is; only the packed route's no-native fallback, whose
+        partitions are float64, still narrows here without x64."""
         with StageRange("convert"):
             host = np.asarray(part, dtype=self.dtype)
         return place_block(host, put)

@@ -317,8 +317,8 @@ class PCA(_PCAParams, Estimator, MLReadable):
                 "source, useGemm=True, solver != 'randomized')"
             )
         # Resolve "auto" against the RAW input dtype (before densification
-        # coerces to float64) so only genuinely-fp64 sources route to dd —
-        # RowMatrix.resolve is the single home of this policy.
+        # makes the route's dtype of it) so only genuinely-fp64 sources
+        # route to dd — RowMatrix.resolve is the single home of this policy.
         requested_prec = self.getPrecision()
         # Probe the container extract_column did NOT already coerce: for a
         # pandas frame with no inputCol, extract_column densified to

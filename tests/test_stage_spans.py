@@ -134,8 +134,8 @@ class TestHostPartitionFit:
         rows, cols = sum(p.shape[0] for p in parts), parts[0].shape[1]
         itemsize = np.dtype(jnp.zeros(0).dtype).itemsize  # float64 under the tests' x64
         assert got["fit.stage.place.bytes"] == 2 * rows * cols * itemsize
-        # RowMatrix densifies to float64 whatever the source: every float32
-        # partition is written anew
+        # RowMatrix densifies in the compute dtype, float64 under the tests'
+        # x64: every float32 partition is written anew
         assert got["fit.stage.densify.calls"] == 1
         assert got["fit.stage.densify.bytes"] == rows * cols * 8
         assert sum(got[f"fit.stage.{stage}.ns"] for stage in STAGES) <= wall_ns
@@ -189,6 +189,78 @@ class TestHostPartitionFit:
         itemsize = np.dtype(jnp.zeros(0).dtype).itemsize
         assert got["fit.stage.place.bytes"] >= x.size * itemsize
         assert got.get("fit.stage.convert.calls", 0) == 0  # the funnel densifies in the compute dtype
+
+
+@pytest.fixture
+def row_matrices(monkeypatch):
+    """The RowMatrix of every ``PCA.fit`` of the test, in order."""
+    from spark_rapids_ml_tpu.models import pca as pca_module
+
+    made = []
+
+    class Recorded(pca_module.RowMatrix):
+        def __init__(self, *args, **kwargs):
+            super().__init__(*args, **kwargs)
+            made.append(self)
+
+    monkeypatch.setattr(pca_module, "RowMatrix", Recorded)
+    return made
+
+
+@pytest.fixture
+def chip_dtypes():
+    """The chip's configuration: x64 off, so the compute dtype is float32."""
+    with jax.enable_x64(False):
+        yield
+
+
+@pytest.mark.usefixtures("chip_dtypes")
+class TestHostPartitionFitWithoutX64:
+    def test_float32_partitions_are_handed_on_as_they_are(self, row_matrices):
+        parts = f32_partitions(21)
+        before = stage_counters()
+        model = PCA().setK(2).fit(parts)
+        np.asarray(model.pc)
+        got = delta(before)
+        (mat,) = row_matrices
+        assert all(held is given for held, given in zip(mat.partitions, parts))
+        assert got["fit.stage.densify.calls"] == 1
+        assert got["fit.stage.densify.bytes"] == 0
+        # the stages stay open round a conversion that has nothing to do
+        assert got["fit.stage.convert.calls"] == got["fit.stage.place.calls"] == 2 * len(parts)
+        assert got["fit.stage.place.bytes"] == 2 * sum(p.size for p in parts) * 4
+
+    def test_a_float64_source_at_highest_is_narrowed_once(self, row_matrices):
+        parts = [p.astype(np.float64) for p in f32_partitions(22)]
+        before = stage_counters()
+        PCA().setK(2).setPrecision("highest").fit(parts)
+        got = delta(before)
+        (mat,) = row_matrices
+        assert {p.dtype for p in mat.partitions} == {np.dtype(np.float32)}
+        entries = sum(p.size for p in parts)
+        assert got["fit.stage.densify.bytes"] == entries * 4
+        assert got["fit.stage.place.bytes"] == 2 * entries * 4
+
+    @pytest.mark.parametrize(
+        "route",
+        [lambda pca: pca.setPrecision("dd"), lambda pca: pca.setUseGemm(False)],
+        ids=["dd", "packed"],
+    )
+    def test_the_host_float64_routes_keep_float64_partitions(self, row_matrices, route):
+        parts = f32_partitions(23)
+        route(PCA().setK(2)).fit(parts)
+        (mat,) = row_matrices
+        assert {p.dtype for p in mat.partitions} == {np.dtype(np.float64)}
+        assert all(np.array_equal(held, given) for held, given in zip(mat.partitions, parts))
+
+    def test_float32_and_float64_partitions_of_one_value_fit_one_model(self):
+        parts = f32_partitions(24)
+        narrow = PCA().setK(3).fit(parts)
+        wide = PCA().setK(3).setPrecision("highest").fit([p.astype(np.float64) for p in parts])
+        assert np.array_equal(np.asarray(narrow.pc), np.asarray(wide.pc))
+        assert np.array_equal(
+            np.asarray(narrow.explainedVariance), np.asarray(wide.explainedVariance)
+        )
 
 
 class TestDeviceArrayFit:
