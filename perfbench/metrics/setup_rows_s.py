@@ -1,0 +1,5 @@
+from perfbench.metrics._setup import part
+
+
+def read(ctx):
+    return part(ctx, "rows")

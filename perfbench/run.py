@@ -13,7 +13,9 @@ from __future__ import annotations
 
 import time
 
-_T0 = time.perf_counter()  # "process start" for setup_s: before any heavy import
+# "process start": before any heavy import. The set-up part ``start`` runs from
+# here to the return of find_device and is NOT in setup_s (metrics/setup_s.json)
+_T0 = time.perf_counter()
 
 import argparse
 import importlib
@@ -83,6 +85,15 @@ class Context:
     scratch: str = os.path.join(ROOT, "perfbench_out")
     record: dict = field(default_factory=dict)  # filled by the driver
 
+    def mark(self, part: str, at: float | None = None) -> float:
+        """End the set-up part ``part`` now (or at ``at``). A part begins where
+        the one before it ended, the first at ``t0``: the parts add up to the
+        time from ``t0`` to the last mark. Returns the mark's time."""
+        at = time.perf_counter() if at is None else at
+        parts = self.record.setdefault("setup_parts", {})
+        parts[part] = at - self.t0 - sum(parts.values())
+        return at
+
     @property
     def rehearse(self) -> bool:
         return bool(self.args.rehearse)
@@ -123,9 +134,13 @@ def cell_metrics(bench: dict, cell: str, section: str) -> list:
 
 
 def find_device(chips: int, rehearse: bool) -> tuple:
+    t_python = time.perf_counter()
     import jax
 
+    t_jax = time.perf_counter()
     devices = jax.devices()
+    print(f"process start: to find_device {t_python - _T0:.3f} s, import jax {t_jax - t_python:.3f} s, "
+          f"jax.devices() {time.perf_counter() - t_jax:.3f} s", file=sys.stderr)
     first = devices[0]
     device = {"platform": first.platform, "kind": first.device_kind, "count": len(devices)}
     peaks = load_json("perfbench", "peaks.json").get(first.device_kind)
@@ -197,14 +212,19 @@ def main(argv=None) -> int:
                 "XLA_FLAGS", f"--xla_force_host_platform_device_count={cell['chips']}"
             )
         device, peaks = find_device(int(cell["chips"]), args.rehearse)
+        t_device = time.perf_counter()
         ctx = Context(args, bench, cell, workload, config, peaks, device)
+        ctx.mark("start", at=t_device)
         driver = module_for("drivers", workload["driver"])
         # the program's own placement of jax's persistent cache: the env
         # variable where set, else .jax_compile_cache/ in this checkout
         from spark_rapids_ml_tpu.core.serving import configure_compile_cache
 
         configure_compile_cache()
+        ctx.mark("import")
         driver.run(ctx)
+        print("set-up: " + ", ".join(f"{k} {v:.3f} s" for k, v in ctx.record["setup_parts"].items()),
+              file=sys.stderr)
         device["memory_peak_bytes"] = memory_peak_bytes()
         section = "per_layer" if args.trace else "end_to_end"
         metrics = {} if args.rehearse else read_metrics(ctx, section)
@@ -228,6 +248,7 @@ def main(argv=None) -> int:
         result["breakdown"] = breakdown
     if args.rehearse:
         result["rehearsal"] = {"comparison_passed": correct}
+    result["setup_parts"] = ctx.record["setup_parts"]
     result["compiles_in_window"] = ctx.record["compiles_in_window"]
     if result["compiles_in_window"]:
         print(f"perfbench: {result['compiles_in_window']} programs were lowered inside the "

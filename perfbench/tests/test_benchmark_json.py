@@ -58,3 +58,25 @@ def test_each_cell_reports_what_the_contract_asks():
     assert "ingest_ms" in host and "fit_call_ms" not in host
     device = [m["name"] for m in cell_metrics(bench, "kmeans_3000_k1000.device_rows", "per_layer")]
     assert "lloyd_iters" in device and "fit_mfu" in device and "ingest_ms" not in device
+
+
+def test_no_metric_file_without_an_entry_and_no_entry_twice():
+    """A metric that nothing reports leaves a line in the ledger that never
+    gets a second reading: a renamed metric takes its old files with it."""
+    bench = load("BENCHMARK.json")
+    named = [m["name"] for section in ("end_to_end", "per_layer") for m in bench[section]]
+    assert len(named) == len(set(named))
+    metrics = os.path.join(ROOT, "perfbench", "metrics")
+    files = {f[:-5] for f in os.listdir(metrics) if f.endswith(".json")}
+    readers = {f[:-3] for f in os.listdir(metrics) if f.endswith(".py") and not f.startswith("_")}
+    assert files == readers == set(named)
+
+
+def test_the_funnel_rate_goes_by_its_own_name():
+    from perfbench.run import cell_metrics
+
+    bench = load("BENCHMARK.json")
+    host = [m["name"] for m in cell_metrics(bench, "pca_3000.host_parts", "per_layer")]
+    assert "ingest_gb_per_s" in host and "h2d_gb_per_s" not in host
+    spec = load("perfbench", "metrics", "ingest_gb_per_s.json")
+    assert spec["moves"] == "host_fit_rows_per_s" and "ingest_ms" in spec["reads"]

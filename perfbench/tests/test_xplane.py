@@ -104,3 +104,62 @@ def test_recorded_trace():
     assert len(b["device_ops"]) == 10 and b["device_ops"][0][0].startswith("%fusion = f32[96,96]")
     assert "kind=kOutput" in b["device_ops"][0][0]
     assert b["idle_gaps"][0][0] == "fit" and b["idle_gaps"][0][1] == pytest.approx(0.06348, abs=1e-5)
+
+
+def nested() -> Trace:
+    """One fit of 100 ns whose stages lie inside it, then a model read.
+
+    The device is busy 25-50 and 60-70, so it idles 0-25, 50-60 and 70-110.
+    ``place`` holds a span of its own (``inner``, 22-24: two deep), and
+    ``solve`` (40-95) is cut short by nothing while ``late`` starts inside
+    ``model_read`` and outlasts it: it is cut at ``model_read``'s end.
+    """
+    ops = [Op("%fusion.1 = fusion(), kind=kOutput", 25, 25), Op("%fusion.2 = fusion(), kind=kLoop", 60, 10)]
+    spans = [
+        ("fit", 0, 100), ("admit", 2, 5), ("densify", 5, 10), ("place", 20, 30), ("inner", 22, 24),
+        ("solve", 40, 90), ("model_read", 100, 110), ("late", 105, 130),
+    ]
+    return Trace({0: ops}, spans)
+
+
+def test_a_nanosecond_belongs_to_the_innermost_span_that_holds_it():
+    own = dict(xplane.own_intervals(nested().spans))
+    assert own["fit"] == [(0, 2), (10, 20), (30, 40), (90, 100)]
+    assert own["place"] == [(20, 22), (24, 30)] and own["inner"] == [(22, 24)]
+    assert own["solve"] == [(40, 90)] and own["admit"] == [(2, 5)]
+    assert own["model_read"] == [(100, 105)] and own["late"] == [(105, 110)]
+    # every nanosecond of the outermost spans once and only once
+    assert sum(xplane.total(mine) for mine in own.values()) == 110
+    # the order of the list does not matter, and a span alone is its own
+    assert dict(xplane.own_intervals(reversed(nested().spans))) == own
+    assert xplane.own_intervals([("fit", 3, 9)]) == [("fit", [(3, 9)])]
+
+
+def test_idle_gaps_split_a_fit_by_stage_and_ingest_reads_as_before():
+    trace = nested()
+    trace.spans = [s for s in trace.spans if s[0] != "late"]
+    r = xplane.reduce(trace, chips=1)
+    assert r.window == (0, 110)
+    gaps = dict(r.breakdown()["idle_gaps"])
+    want = {"admit": 3, "densify": 5, "place": 3, "inner": 2, "solve": 30, "fit": 22, "model_read": 10}
+    assert gaps == {name: pytest.approx(ns / 1e9) for name, ns in want.items()}
+    # the stages and what is left of ``fit`` add up to what ``fit`` alone read
+    # before the stages were kept, which is what ingest_ms reads
+    plain = xplane.reduce(Trace(trace.devices, [("fit", 0, 100), ("model_read", 100, 110)]), chips=1)
+    assert dict(plain.breakdown()["idle_gaps"]) == {"fit": pytest.approx(65e-9),
+                                                    "model_read": pytest.approx(10e-9)}
+    assert sum(want.values()) - want["model_read"] == 65
+    assert r.host_wait_per_fit() == plain.host_wait_per_fit() == [65]
+
+
+def test_measure_agrees_with_clip():
+    import random
+
+    rng = random.Random(28)
+    cuts = sorted(rng.sample(range(1000), 60))
+    intervals = list(zip(cuts[0::2], cuts[1::2]))
+    inside = xplane.measure(intervals)
+    for _ in range(300):
+        lo, hi = sorted(rng.sample(range(-5, 1005), 2))
+        assert inside(lo, hi) == xplane.total(xplane.clip(intervals, lo, hi))
+    assert inside(cuts[1], cuts[2]) == 0 and xplane.measure([])(0, 10) == 0

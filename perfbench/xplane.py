@@ -9,12 +9,16 @@ nanoseconds on the trace's one clock.
 
 from __future__ import annotations
 
+import bisect
+import itertools
 import re
 from dataclasses import dataclass, field
 
 OPS_LINE = "XLA Ops"
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
-SPAN_NAMES = ("fit", "model_read")
+# the benchmark's own host spans (drivers/fit_loop.py), and the program's stage
+# spans, which lie inside ``fit`` (spark_rapids_ml_tpu/utils/tracing.py: STAGES)
+SPAN_NAMES = ("fit", "model_read", "admit", "densify", "convert", "place", "solve")
 NAME_CHARS = 200  # an operation's name is its HLO text: keep the head of it
 # operations that only contain other operations of the same line: their time
 # is their children's, so they are no leaf of a per-operation sum
@@ -106,6 +110,51 @@ def subtract(a: list, b: list) -> list:
     return out
 
 
+def measure(intervals: list):
+    """``inside(lo, hi)``: how much of the disjoint sorted ``intervals`` lies in
+    (lo, hi), by bisection, for thousands of questions to one list."""
+    starts = [s for s, _ in intervals]
+    ends = [e for _, e in intervals]
+    upto = [0.0, *itertools.accumulate(e - s for s, e in intervals)]
+
+    def inside(lo: float, hi: float) -> float:
+        i = bisect.bisect_right(ends, lo)   # the first interval that ends after lo
+        j = bisect.bisect_left(starts, hi)  # the first that starts at hi or later
+        if i >= j:
+            return 0.0
+        return upto[j] - upto[i] - max(0.0, lo - starts[i]) - max(0.0, ends[j - 1] - hi)
+
+    return inside
+
+
+def own_intervals(spans) -> list:
+    """(name, intervals) for each (name, start, end) span: the span less the
+    spans nested in it, so that a nanosecond belongs to the innermost span that
+    holds it and to no other. A span that outlasts the one it starts in is cut
+    at that one's end."""
+    out, open_spans = [], []  # an open span: [name, end, covered up to, intervals]
+
+    def close(span):
+        name, end, at, mine = span
+        if at < end:
+            mine.append((at, end))
+        out.append((name, mine))
+
+    for name, start, end in sorted(spans, key=lambda s: (s[1], -s[2])):
+        while open_spans and open_spans[-1][1] <= start:
+            close(open_spans.pop())
+        if open_spans:
+            outer = open_spans[-1]
+            end = min(end, outer[1])
+            if start > outer[2]:
+                outer[3].append((outer[2], start))
+            outer[2] = max(outer[2], end)
+        open_spans.append([name, end, start, []])
+    while open_spans:
+        close(open_spans.pop())
+    return out
+
+
 def is_container(op: Op) -> bool:
     return bool(CONTAINER.match(op.name))
 
@@ -171,9 +220,11 @@ class Reduced:
         # idle gaps of the idlest device, by what the host was doing
         ordinal = min(self.busy, key=lambda o: total(self.busy[o]))
         gaps = subtract([self.window], self.busy[ordinal])
+        idle_inside = measure(gaps)
         by_span = {}
-        for name, s, e in self.trace.spans:  # the spans follow one another
-            by_span[name] = by_span.get(name, 0.0) + total(clip(gaps, s, e))
+        for name, mine in own_intervals(self.trace.spans):  # a stage lies inside a fit
+            idle = sum(idle_inside(s, e) for s, e in mine)
+            by_span[name] = by_span.get(name, 0.0) + idle
         outside = total(gaps) - sum(by_span.values())
         if outside > 0:
             by_span["between_spans"] = outside
