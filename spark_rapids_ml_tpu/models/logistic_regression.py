@@ -61,12 +61,18 @@ from spark_rapids_ml_tpu.core.serving import (
     stream_block_rows,
 )
 from spark_rapids_ml_tpu.utils.envknobs import env_choice
-from spark_rapids_ml_tpu.utils.tracing import TraceColor, TraceRange
+from spark_rapids_ml_tpu.utils.tracing import (
+    StageRange,
+    TraceColor,
+    TraceRange,
+    bump_counter,
+)
 
 
 def _logistic_fused_knob() -> bool:
     """TPUML_LOGISTIC_FUSED, read in the model layer (outside jit) and
-    plumbed into the solvers as a static arg."""
+    plumbed into the FISTA and streaming solvers as a static arg (the
+    L-BFGS fit has one formulation)."""
     return env_choice("TPUML_LOGISTIC_FUSED", ("0", "1"), "1") == "1"
 
 
@@ -373,9 +379,6 @@ class LogisticRegression(_LogisticRegressionParams, Estimator, MLReadable):
                 y_int, int(xs.shape[0]), n_true=n, mesh=self.mesh, dtype=jnp.int32
             )
             use_multinomial = family == "multinomial"
-            # Knob read OUTSIDE jit; the flag rides into the programs as a
-            # static arg (fused one-pass loss+grad vs legacy two-pass AD).
-            fused = _logistic_fused_knob()
             precision = self._train_precision()
             enet = self.getElasticNetParam()
             # regParam == 0 means zero effective penalty whatever enet says:
@@ -396,33 +399,26 @@ class LogisticRegression(_LogisticRegressionParams, Estimator, MLReadable):
                     np.pad(w0, ((0, pad_d), (0, 0))), dtype=dtype
                 )
                 init_b = jnp.asarray(b0, dtype=dtype)
+            solver_args = dict(
+                n_classes=n_classes,
+                reg_param=self.getRegParam(),
+                fit_intercept=self.getFitIntercept(),
+                standardization=self.getStandardization(),
+                max_iter=self.getMaxIter(),
+                tol=self.getTol(),
+                multinomial=use_multinomial,
+                precision=precision,
+            )
             if enet == 0.0 or self.getRegParam() == 0.0:
                 # Preemption tolerance: the TPUML_CHECKPOINT_* knobs route
                 # the L-BFGS solve through the segmented driver (async
                 # snapshots, mid-solve resume, bit-identical results).
                 ckpt = self._fit_checkpointer("logistic.lbfgs", data=(xs, ys, mask))
                 fit_fn = fit_logistic
-                extra = {}
+                solver_args.update(init_w=init_w, init_b=init_b)
                 if ckpt is not None:
                     fit_fn = fit_logistic_resumable
-                    extra = {"checkpointer": ckpt, "mesh": self.mesh}
-                result = fit_fn(
-                    xs,
-                    ys,
-                    mask,
-                    n_classes=n_classes,
-                    reg_param=self.getRegParam(),
-                    fit_intercept=self.getFitIntercept(),
-                    standardization=self.getStandardization(),
-                    max_iter=self.getMaxIter(),
-                    tol=self.getTol(),
-                    multinomial=use_multinomial,
-                    init_w=init_w,
-                    init_b=init_b,
-                    fused=fused,
-                    precision=precision,
-                    **extra,
-                )
+                    solver_args.update(checkpointer=ckpt, mesh=self.mesh)
             else:
                 if self._initial_weights is not None:
                     raise ValueError(
@@ -434,21 +430,15 @@ class LogisticRegression(_LogisticRegressionParams, Estimator, MLReadable):
                 # OWL-QN iterations in Spark — users of the slower-
                 # converging proximal steps raise maxIter, preserving the
                 # totalIterations <= maxIter invariant.
-                result = fit_logistic_elastic_net(
-                    xs,
-                    ys,
-                    mask,
-                    n_classes=n_classes,
-                    reg_param=self.getRegParam(),
-                    elastic_net_param=enet,
-                    fit_intercept=self.getFitIntercept(),
-                    standardization=self.getStandardization(),
-                    max_iter=self.getMaxIter(),
-                    tol=self.getTol(),
-                    multinomial=use_multinomial,
-                    fused=fused,
-                    precision=precision,
+                fit_fn = fit_logistic_elastic_net
+                # Knob read OUTSIDE jit; it rides in as a static arg.
+                solver_args.update(
+                    elastic_net_param=enet, fused=_logistic_fused_knob()
                 )
+            # The stage times the dispatch: a jitted solve returns at once,
+            # a resumable one blocks between its segments.
+            with StageRange("solve"):
+                result = fit_fn(xs, ys, mask, **solver_args)
         # Gang fits can hand back sharded results; replicate them so every
         # member's host reads see identical values.
         from spark_rapids_ml_tpu.parallel.distributed import replicate_for_host
@@ -456,6 +446,10 @@ class LogisticRegression(_LogisticRegressionParams, Estimator, MLReadable):
         weights, intercepts = replicate_for_host(
             self.mesh, result.weights, result.intercepts
         )
+        grad = None
+        if result.grad is not None:  # the L-BFGS path: (d + 1, c), as the model keeps it
+            gw, gb = result.grad
+            grad = replicate_for_host(self.mesh, jnp.concatenate([gw[:d], gb[None, :]]))
         # Strip model-axis feature padding (device slice, stays async);
         # host float64 conversion happens lazily inside the model.
         model = LogisticRegressionModel(
@@ -464,6 +458,10 @@ class LogisticRegression(_LogisticRegressionParams, Estimator, MLReadable):
             intercepts,
             numClasses=n_classes,
             numIter=result.n_iter,
+            xPasses=result.x_passes,
+            linesearchTrials=result.linesearch_trials,
+            finalObjective=result.loss,
+            finalGradient=grad,
         )
         return self._copyValues(model)
 
@@ -541,6 +539,7 @@ class LogisticRegression(_LogisticRegressionParams, Estimator, MLReadable):
             np.asarray(result.intercepts, dtype=np.float64),
             numClasses=n_classes,
             numIter=int(result.n_iter),
+            finalObjective=float(result.loss),
         )
         return self._copyValues(model)
 
@@ -556,6 +555,7 @@ class LogisticRegressionModel(_LogisticRegressionParams, Model, LazyHostState):
     _lazy_host_fields = {
         "_w_raw": ("_w_np", np.float64),
         "_b_raw": ("_b_np", np.float64),
+        "_grad_raw": ("_grad_np", np.float64),
     }
     _pickle_clear = ("_wb_dev",)
 
@@ -566,6 +566,10 @@ class LogisticRegressionModel(_LogisticRegressionParams, Model, LazyHostState):
         intercepts: Optional[np.ndarray] = None,
         numClasses: int = 2,
         numIter: int = 0,
+        xPasses: Optional[int] = None,
+        linesearchTrials: Optional[int] = None,
+        finalObjective: Optional[float] = None,
+        finalGradient: Optional[np.ndarray] = None,
     ):
         super().__init__(uid)
         self._w_raw = weights
@@ -574,12 +578,15 @@ class LogisticRegressionModel(_LogisticRegressionParams, Model, LazyHostState):
         self._b_np: Optional[np.ndarray] = None
         self._wb_dev = None
         self.numClasses = numClasses
-        self._iter_raw = numIter
+        # (numIter, xPasses, linesearchTrials, finalObjective): host numbers,
+        # or a device-resident fit's scalars until the host first reads one
+        self._solver_raw = (numIter, xPasses, linesearchTrials, finalObjective)
+        self._grad_raw = finalGradient
+        self._grad_np: Optional[np.ndarray] = None
 
     def __getstate__(self):
-        state = super().__getstate__()
-        state["_iter_raw"] = self.numIter
-        return state
+        self._solver_counts()  # device scalars never pickle
+        return super().__getstate__()
 
     @property
     def weights(self) -> Optional[np.ndarray]:
@@ -589,11 +596,69 @@ class LogisticRegressionModel(_LogisticRegressionParams, Model, LazyHostState):
     def intercepts(self) -> Optional[np.ndarray]:
         return self._lazy_host_view("_b_raw")
 
+    def _solver_counts(self) -> tuple:
+        """(numIter, xPasses, linesearchTrials, finalObjective) on the host.
+        A device-resident fit's four scalars cross together on the first
+        read of any of them (the fit itself never waits for them); that
+        read is also where an L-BFGS fit's ``logreg.lbfgs.*`` counters
+        move, here and in the fit's report."""
+        if not isinstance(self._solver_raw[0], int):
+            import jax
+
+            n_iter, passes, trials, objective = jax.device_get(self._solver_raw)
+            n_iter, passes, trials = (
+                None if v is None else int(v) for v in (n_iter, passes, trials)
+            )
+            objective = None if objective is None else float(objective)
+            self._solver_raw = (n_iter, passes, trials, objective)
+            if passes is not None:
+                moved = {
+                    "logreg.lbfgs.iters": n_iter,
+                    "logreg.lbfgs.x_passes": passes,
+                    "logreg.lbfgs.linesearch_trials": trials,
+                }
+                for name, amount in moved.items():
+                    bump_counter(name, amount)
+                if self._fit_report is not None:
+                    self._fit_report.counters.update(moved)
+        return self._solver_raw
+
     @property
     def numIter(self) -> int:
-        if not isinstance(self._iter_raw, int):
-            self._iter_raw = int(self._iter_raw)
-        return self._iter_raw
+        """Optimizer iterations the fit ran (line-search trials not counted)."""
+        return self._solver_counts()[0]
+
+    @property
+    def xPasses(self) -> Optional[int]:
+        """Passes over the rows the L-BFGS fit made: a function of
+        ``numIter`` and the params alone. None off the L-BFGS path."""
+        return self._solver_counts()[1]
+
+    @property
+    def linesearchTrials(self) -> Optional[int]:
+        """Trial steps the fit's line searches took, none of which read
+        the rows. None off the L-BFGS path."""
+        return self._solver_counts()[2]
+
+    @property
+    def finalObjective(self) -> Optional[float]:
+        """The objective (scaled loss + penalty, in the space the optimizer
+        worked in) at the returned coefficients, as the optimizer last saw
+        it: the last entry of Spark's ``objectiveHistory``. None for a
+        model that no fit of this process made."""
+        return self._solver_counts()[3]
+
+    @property
+    def finalGradient(self) -> Optional[np.ndarray]:
+        """The objective's gradient at the returned point as the L-BFGS
+        fit's last iteration computed it, in the space the optimizer worked
+        in (as ``finalObjective``; with ``standardization`` false, with
+        respect to the returned coefficients): (d + 1, c), the coefficients'
+        rows then the intercepts' (zeros without an intercept). What the
+        optimizer stopped on, so a check of the fit's arithmetic against an
+        independent gradient at the same point. None off the L-BFGS path
+        and for a model no fit of this process made."""
+        return self._lazy_host_view("_grad_raw")
 
     def setFeaturesCol(self, value: str) -> "LogisticRegressionModel":
         self.set(self.featuresCol, value)
@@ -616,8 +681,11 @@ class LogisticRegressionModel(_LogisticRegressionParams, Model, LazyHostState):
         return self
 
     def copy(self, extra=None) -> "LogisticRegressionModel":
+        # the counts resolved first: the fit's counters move once, on the
+        # model that fit returned, not again on every copy's first read
         that = LogisticRegressionModel(
-            self.uid, self._w_raw, self._b_raw, self.numClasses, self._iter_raw
+            self.uid, self._w_raw, self._b_raw, self.numClasses,
+            *self._solver_counts(), self._grad_raw,
         )
         return self._copyValues(that, extra)
 

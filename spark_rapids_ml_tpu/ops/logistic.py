@@ -4,11 +4,19 @@ Beyond-the-reference capability (the reference ships only PCA — SURVEY.md §2)
 the model surface mirrors ``org.apache.spark.ml.classification
 .LogisticRegression``, whose optimizer is breeze L-BFGS over a
 DiffFunction aggregated with treeAggregate. Here the entire optimization is
-ONE jitted program: loss+gradient are masked GEMMs on the MXU and the L-BFGS
-update (optax.lbfgs with zoom linesearch) runs inside ``lax.while_loop`` —
-no per-iteration host round-trip. Under a mesh, ``x``/``y``/``mask`` arrive
-row-sharded and XLA inserts the gradient psum over ICI (GSPMD), giving the
-treeAggregate analogue for free.
+ONE jitted program inside ``lax.while_loop`` — no per-iteration host
+round-trip. The model is linear, so the L-BFGS iteration
+(:func:`_lbfgs_iteration`) keeps the margins ``z = Xs w + b`` of the current
+point and makes a CONSTANT number of passes over X: one for the direction's
+margins ``u = Xs d_w + d_b``, then a strong-Wolfe zoom line search on
+``phi(a) = mean(loss(z + a u)) + (reg/2)|w + a d_w|^2`` that reads only the
+two cached (n, c) arrays (a trial step costs no pass over X), then one for
+the gradient at the accepted point from ``z + a u``. The direction comes
+from ``optax.scale_by_lbfgs``'s two-loop recursion. What a fit costs is
+therefore a function of its configuration (``maxIter``, ``tol``), not of how
+many trial steps its data asks of the line search. Under a mesh,
+``x``/``y``/``mask`` arrive row-sharded and XLA inserts the gradient psum
+over ICI (GSPMD), giving the treeAggregate analogue for free.
 
 Objective (Spark semantics):
     (1/n) sum_i logloss_i
@@ -26,7 +34,7 @@ correction).
 from __future__ import annotations
 
 from functools import partial
-from typing import NamedTuple, Tuple
+from typing import Callable, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -44,46 +52,64 @@ class LogisticFit(NamedTuple):
     intercepts: jax.Array  # (c,)
     n_iter: jax.Array  # scalar int
     loss: jax.Array  # final objective value (standardized space)
+    #: Off the L-BFGS path the rest is None. Passes over the rows the fit
+    #: made (moments, margins, gradients); trial steps its line searches
+    #: took; the gradient (d, c), (c,) its last iteration computed at the
+    #: returned point (standardized space, as ``loss``).
+    x_passes: jax.Array | None = None
+    linesearch_trials: jax.Array | None = None
+    grad: tuple | None = None
 
 
-#: Row-block length of the fused one-pass objective: big enough that the
-#: per-evaluation GEMMs stay MXU-bound, small enough that a block's
+#: Row-block length of the blocked passes over X: big enough that the
+#: per-pass products stay bandwidth-bound, small enough that a block's
 #: standardized slice is a cache/VMEM-resident temporary instead of a
 #: materialized (n, d) HBM array.
 _FUSED_BLOCK_ROWS = 65536
 
+#: optax.lbfgs()'s own choices, kept: ten (s, y) pairs, a first step capped
+#: to the unit ball, and its zoom search (20 trials, first guess 1).
+_LBFGS_MEMORY = optax.scale_by_lbfgs(memory_size=10)
+_ZOOM = optax.scale_by_zoom_linesearch(
+    max_linesearch_steps=20, initial_guess_strategy="one"
+)
+
+
+class _RowPasses(NamedTuple):
+    """The (standardized-space) logistic objective as the solvers use it:
+    four functions of ``params = (w, b)`` and of margins ``z = xs @ w + b``,
+    each blocked over ``_FUSED_BLOCK_ROWS`` rows, named by what they read."""
+
+    #: ``params -> (value, grad)`` in one sweep: a block's two products,
+    #: ``xs @ w`` and ``xs.T @ dz``, each read the block. FISTA's gradient.
+    value_and_grad: Callable
+    #: ``(w, b) -> z`` (n, c): ONE pass over X.
+    margins: Callable
+    #: ``(z, w) -> value``: O(n c), X not read.
+    value_at: Callable
+    #: ``(z, params) -> grad`` at ``params`` whose margins are ``z``:
+    #: ``xs.T @ dz(z)``, ONE pass over X.
+    grad_at: Callable
+
 
 def _make_logistic_loss(
-    x, y_target, mask, offset, scale, n, reg_param, c, fit_intercept, dot,
-    fused=False,
-):
+    x, y_target, mask, offset, scale, n, reg_param, c, fit_intercept, dot
+) -> _RowPasses:
     """The ONE home of the (standardized-space) logistic objective —
     closed over by the monolithic :func:`fit_logistic` program, the
-    segmented :func:`_lbfgs_segment` program, and the finalizer, so all
-    three optimize/evaluate literally the same expression (the
-    bit-identity bar of the checkpoint subsystem).
+    segmented :func:`_lbfgs_segment` program, and FISTA, so all optimize
+    literally the same expression (the bit-identity bar of the checkpoint
+    subsystem).
 
-    ``fused=False`` returns the plain objective (gradients via autodiff,
-    which saves the standardized (n, d) design as a residual — X is
-    effectively streamed twice per evaluation). ``fused=True`` returns a
-    ``jax.custom_vjp`` objective whose forward pass computes the value
-    AND the analytic gradient in ONE blocked sweep over X — the algebra
-    needs only X^T(p - y) and the logloss sum, so each row block's
-    standardized slice lives and dies on-chip (the second
-    X pass was ~16.7% of the fit's HBM traffic). The fused callable also
-    exposes ``.value_and_grad(params)`` for drivers that want both
-    without round-tripping through AD. Fused and legacy agree to float
-    tolerance (per-block partial sums reduce in a different order);
-    every segmented/monolithic pair shares ONE flag, so checkpoint
-    bit-identity is preserved in both modes."""
+    A block's standardized slice lives and dies in the block, and the
+    gradient is analytic (no autodiff residual of the standardized design).
+    The L-BFGS iteration never evaluates the objective at a point: it works
+    on margins through ``margins`` / ``value_at`` / ``grad_at``, which cost
+    one pass over X, none, and one."""
     dot = as_dot(dot)
 
-    def _block_terms(xb, yb, mb, w, b):
-        """One row block's (masked loss sum, unnormalized dL/dw, dL/db)."""
-        xs = (xb - offset) / scale
-        logits = dot(xs, w)
-        if fit_intercept:
-            logits = logits + b
+    def _row_terms(logits, yb, mb):
+        """(masked loss sum, masked dL/dlogits (rows, c)) of margins."""
         if c == 1:
             z = logits[:, 0]
             # log(1+e^z) - y z, numerically stable via softplus
@@ -93,79 +119,91 @@ def _make_logistic_loss(
             logp = jax.nn.log_softmax(logits, axis=1)
             per_row = -jnp.sum(yb * logp, axis=1)
             dz = (jnp.exp(logp) - yb) * mb[:, None]
-        loss_b = jnp.sum(per_row * mb)
-        gw_b = dot(xs.T, dz)
-        gb_b = jnp.sum(dz, axis=0)
-        return loss_b, gw_b, gb_b
+        return jnp.sum(per_row * mb), dz
 
-    if not fused:
+    def _block_margins(xb, w, b):
+        logits = dot((xb - offset) / scale, w)
+        return logits + b if fit_intercept else logits
 
-        def loss_fn(params):
-            w, b = params
-            xs = (x - offset) / scale
-            logits = dot(xs, w)
-            if fit_intercept:
-                logits = logits + b
-            if c == 1:
-                z = logits[:, 0]
-                # log(1+e^z) - y z, numerically stable via softplus
-                per_row = jax.nn.softplus(z) - y_target * z
-            else:
-                per_row = -jnp.sum(
-                    y_target * jax.nn.log_softmax(logits, axis=1), axis=1
-                )
-            data_loss = jnp.sum(per_row * mask) / n
-            return data_loss + 0.5 * reg_param * jnp.sum(w * w)
+    def _block_grad(xb, dz):
+        """One row block's unnormalized (dL/dw, dL/db) from its residuals."""
+        return dot(((xb - offset) / scale).T, dz), jnp.sum(dz, axis=0)
 
-        return loss_fn
+    def _block_terms(xb, yb, mb, w, b):
+        """One row block's (masked loss sum, unnormalized dL/dw, dL/db)."""
+        loss_b, dz = _row_terms(_block_margins(xb, w, b), yb, mb)
+        return (loss_b, *_block_grad(xb, dz))
 
     nrows = x.shape[0]
     bs = min(_FUSED_BLOCK_ROWS, nrows)
+    nb = -(-nrows // bs)
+
+    def _block(i, *rows):
+        """Block ``i`` of X and of the row arrays ``rows``, with the mask
+        of its rows that no earlier block counted: the last block slides
+        back to stay in bounds."""
+        start = jnp.minimum(i * bs, nrows - bs)
+        keep = ((start + jnp.arange(bs)) >= i * bs).astype(x.dtype)
+        cut = [jax.lax.dynamic_slice_in_dim(r, start, bs) for r in (x, *rows)]
+        return start, keep, cut
+
+    def _scaled(gw_s, gb_s, w, b):
+        gb = gb_s / n if fit_intercept else jnp.zeros_like(b)
+        return gw_s / n + reg_param * w, gb.astype(b.dtype)
+
+    def value_at(z, w):
+        return _row_terms(z, y_target, mask)[0] / n + 0.5 * reg_param * jnp.sum(w * w)
 
     def value_and_grad(params):
         w, b = params
-        if nrows <= bs:
+        if nb == 1:
             loss_s, gw_s, gb_s = _block_terms(x, y_target, mask, w, b)
         else:
-            nb = -(-nrows // bs)
 
             def body(i, acc):
-                l_a, gw_a, gb_a = acc
-                # The last block slides back to stay in bounds; rows the
-                # previous block already counted mask to zero.
-                start = jnp.minimum(i * bs, nrows - bs)
-                xb = jax.lax.dynamic_slice_in_dim(x, start, bs)
-                yb = jax.lax.dynamic_slice_in_dim(y_target, start, bs)
-                mb = jax.lax.dynamic_slice_in_dim(mask, start, bs)
-                keep = (start + jnp.arange(bs)) >= i * bs
-                l_b, gw_b, gb_b = _block_terms(
-                    xb, yb, mb * keep.astype(mb.dtype), w, b
+                _, keep, (xb, yb, mb) = _block(i, y_target, mask)
+                return jax.tree_util.tree_map(
+                    jnp.add, acc, _block_terms(xb, yb, mb * keep, w, b)
                 )
-                return l_a + l_b, gw_a + gw_b, gb_a + gb_b
 
             loss_s, gw_s, gb_s = jax.lax.fori_loop(
                 0, nb, body,
                 (jnp.zeros((), x.dtype), jnp.zeros_like(w), jnp.zeros((c,), x.dtype)),
             )
         value = loss_s / n + 0.5 * reg_param * jnp.sum(w * w)
-        gw = gw_s / n + reg_param * w
-        gb = gb_s / n if fit_intercept else jnp.zeros_like(b)
-        return value, (gw, gb.astype(b.dtype))
+        return value, _scaled(gw_s, gb_s, w, b)
 
-    @jax.custom_vjp
-    def loss_fn(params):
-        return value_and_grad(params)[0]
+    def margins(w, b):
+        if nb == 1:
+            return _block_margins(x, w, b)
 
-    def _fwd(params):
-        value, grad = value_and_grad(params)
-        return value, grad
+        def body(i, z):
+            start, _, (xb,) = _block(i)
+            # rows of the slid-back block that an earlier one wrote are
+            # written again with the same margins
+            return jax.lax.dynamic_update_slice_in_dim(
+                z, _block_margins(xb, w, b), start, axis=0
+            )
 
-    def _bwd(grad, ct):
-        return (jax.tree_util.tree_map(lambda g: g * ct, grad),)
+        return jax.lax.fori_loop(0, nb, body, jnp.zeros((nrows, c), x.dtype))
 
-    loss_fn.defvjp(_fwd, _bwd)
-    loss_fn.value_and_grad = value_and_grad
-    return loss_fn
+    def grad_at(z, params):
+        w, b = params
+        if nb == 1:
+            gw_s, gb_s = _block_grad(x, _row_terms(z, y_target, mask)[1])
+        else:
+
+            def body(i, acc):
+                _, keep, (xb, zb, yb, mb) = _block(i, z, y_target, mask)
+                dz = _row_terms(zb, yb, mb * keep)[1]
+                return jax.tree_util.tree_map(jnp.add, acc, _block_grad(xb, dz))
+
+            gw_s, gb_s = jax.lax.fori_loop(
+                0, nb, body, (jnp.zeros_like(w), jnp.zeros((c,), x.dtype))
+            )
+        return _scaled(gw_s, gb_s, w, b)
+
+    return _RowPasses(value_and_grad, margins, value_at, grad_at)
 
 
 def _masked_feature_moments(x: jax.Array, mask: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -181,6 +219,125 @@ def _masked_feature_moments(x: jax.Array, mask: jax.Array) -> Tuple[jax.Array, j
     return mean, jnp.sqrt(var)
 
 
+def _standardizer(x, mask, fit_intercept: bool, standardization: bool):
+    """(offset, scale, n, passes over X): what the optimizer's space is."""
+    n = jnp.sum(mask)
+    if not standardization:
+        d = x.shape[1]
+        return jnp.zeros((d,), x.dtype), jnp.ones((d,), x.dtype), n, 0
+    mean, sigma = _masked_feature_moments(x, mask)
+    # Padded / constant features have sigma 0 — scale by 1 there (their
+    # coefficients stay 0: zero column => zero gradient under L2 from init 0).
+    scale = jnp.where(sigma > 0, sigma, 1.0)
+    # Center ONLY when an intercept exists to absorb the shift back in
+    # original space; without an intercept, scale-only (Spark does the
+    # same — otherwise the returned coefficients would describe a
+    # different function than the one optimized).
+    offset = mean if fit_intercept else jnp.zeros_like(mean)
+    return offset, scale, n, 2  # the means' pass, then the deviations'
+
+
+def _class_targets(y, c: int, dtype):
+    return (y == 1).astype(dtype) if c == 1 else jax.nn.one_hot(y, c, dtype=dtype)
+
+
+def _start_params(init_w, init_b, offset, scale, d, c, fit_intercept, dot, dtype):
+    """The optimizer's start in standardized space: zeros, or the inverse
+    of the final back-map applied to an ORIGINAL-space warm start
+    (w_std = w_orig * scale; the intercept re-absorbs the centering)."""
+    if init_w is None:
+        return jnp.zeros((d, c), dtype=dtype), jnp.zeros((c,), dtype=dtype)
+    w_orig0 = jnp.asarray(init_w, dtype=dtype)
+    if not fit_intercept:
+        # No intercept in the model: b is never optimized (zero gradient),
+        # so a stale nonzero init would leak into predict.
+        return w_orig0 * scale[:, None], jnp.zeros((c,), dtype=dtype)
+    # Absorb the centering offset whether or not an original-space
+    # intercept was supplied — (w_orig, 0) must start as the SAME decision
+    # function, not a shifted one.
+    b_orig0 = (
+        jnp.asarray(init_b, dtype=dtype) if init_b is not None
+        else jnp.zeros((c,), dtype=dtype)
+    )
+    return w_orig0 * scale[:, None], b_orig0 + dot(offset, w_orig0)
+
+
+class _LbfgsCarry(NamedTuple):
+    """The whole solver state between two L-BFGS iterations — the
+    ``while_loop`` carry of the monolithic fit, and the pytree a resumable
+    fit snapshots between segments."""
+
+    params: tuple  # (w (d, c), b (c,)) in standardized space
+    memory: optax.OptState  # scale_by_lbfgs's (s, y) pairs
+    margins: jax.Array  # (n, c): xs @ w + b at ``params``
+    grad: tuple  # the objective's gradient at ``params``
+    it: jax.Array  # iterations run
+    gnorm: jax.Array  # norm of ``grad`` (infinite before the first iteration)
+    x_passes: jax.Array  # passes over X so far
+    trials: jax.Array  # line-search trial steps so far
+
+
+def _lbfgs_start(passes: _RowPasses, params, prep_passes: int) -> _LbfgsCarry:
+    """The carry at ``params``: one pass for its margins, one for its
+    gradient. ``gnorm`` starts infinite: a fit runs one iteration at least,
+    also from a warm start that is already at the optimum."""
+    z = passes.margins(*params)
+    grad = passes.grad_at(z, params)
+    return _LbfgsCarry(
+        params, _LBFGS_MEMORY.init(params), z, grad, jnp.asarray(0),
+        jnp.asarray(jnp.inf, dtype=z.dtype),
+        jnp.asarray(prep_passes + 2), jnp.asarray(0),
+    )
+
+
+def _lbfgs_iteration(passes: _RowPasses, carry: _LbfgsCarry) -> _LbfgsCarry:
+    """One L-BFGS iteration, the ONE body of :func:`fit_logistic` and
+    :func:`_lbfgs_segment`: two passes over X whatever the data. The line
+    search sees the objective along the direction through the cached
+    margins alone; where it finds no decrease (float32 at its floor) the
+    step is 0 and the iteration ends all the same, its gradient pass made."""
+    (w, b), z = carry.params, carry.margins
+    step, memory = _LBFGS_MEMORY.update(carry.grad, carry.memory, carry.params)
+    dw, db = jax.tree_util.tree_map(jnp.negative, step)
+    u = passes.margins(dw, db)
+
+    def phi(a):
+        return passes.value_at(z + a * u, w + a * dw)
+
+    zero = jnp.zeros((), z.dtype)
+    phi0, slope0 = jax.value_and_grad(phi)(zero)
+    a, search = _ZOOM.update(
+        jnp.ones((), z.dtype), _ZOOM.init(zero), zero,
+        value=phi0, grad=slope0, value_fn=phi,
+    )
+    params = (w + a * dw, b + a * db)
+    z = z + a * u
+    grad = passes.grad_at(z, params)
+    return _LbfgsCarry(
+        params, memory, z, grad, carry.it + 1, optax.global_norm(grad),
+        carry.x_passes + 2, carry.trials + search.info.num_linesearch_steps,
+    )
+
+
+def _lbfgs_finish(passes, reg_param, carry, offset, scale, c, fit_intercept, dot):
+    """The post-solve tail: final objective from the margins, the
+    identifiability pivot, the back-map to original feature space."""
+    w, b = carry.params
+    final_loss = passes.value_at(carry.margins, w)
+    # Identifiability pivot for unregularized softmax (Spark's centering).
+    if c > 1:
+        do_center = reg_param == 0.0
+        w = jnp.where(do_center, w - jnp.mean(w, axis=1, keepdims=True), w)
+        b = jnp.where(do_center, b - jnp.mean(b), b)
+    # Map standardized-space solution back to original feature space.
+    w_orig = w / scale[:, None]
+    b_orig = b - dot(offset, w_orig) if fit_intercept else b
+    return LogisticFit(
+        w_orig, b_orig, carry.it, final_loss, carry.x_passes, carry.trials,
+        carry.grad,
+    )
+
+
 @partial(
     jax.jit,
     static_argnames=(
@@ -190,7 +347,6 @@ def _masked_feature_moments(x: jax.Array, mask: jax.Array) -> Tuple[jax.Array, j
         "max_iter",
         "precision",
         "multinomial",
-        "fused",
     ),
 )
 def fit_logistic(
@@ -207,7 +363,6 @@ def fit_logistic(
     multinomial: bool = False,
     init_w: jax.Array | None = None,
     init_b: jax.Array | None = None,
-    fused: bool = True,
 ) -> LogisticFit:
     """Fit binomial or multinomial logistic regression.
 
@@ -223,184 +378,105 @@ def fit_logistic(
     optima differ under L2 (softmax splits the penalty across both class
     columns), so the 2-class case must NOT be collapsed to sigmoid when
     multinomial semantics are requested.
+
+    ``max_iter`` iterations run unless the gradient's norm at the current
+    point is at or under ``tol``; each makes the same passes over ``x``
+    (:func:`_lbfgs_iteration`), so ``x_passes`` of the result follows from
+    ``n_iter`` alone.
     """
     if n_classes < 2:
         raise ValueError(f"need at least 2 classes, got {n_classes}")
     c = n_classes if (multinomial or n_classes > 2) else 1
-    d = x.shape[1]
     dtype = x.dtype
     dot = make_dot(precision)
-    n = jnp.sum(mask)
-
-    mean, sigma = _masked_feature_moments(x, mask)
-    # Padded / constant features have sigma 0 — scale by 1 there (their
-    # coefficients stay 0: zero column => zero gradient under L2 from init 0).
-    safe_sigma = jnp.where(sigma > 0, sigma, 1.0)
-    if standardization:
-        # Center ONLY when an intercept exists to absorb the shift back in
-        # original space; without an intercept, scale-only (Spark does the
-        # same — otherwise the returned coefficients would describe a
-        # different function than the one optimized).
-        offset = mean if fit_intercept else jnp.zeros_like(mean)
-        scale = safe_sigma
-    else:
-        offset = jnp.zeros_like(mean)
-        scale = jnp.ones_like(safe_sigma)
-
-    if c == 1:
-        y_target = (y == 1).astype(dtype)
-    else:
-        y_target = jax.nn.one_hot(y, c, dtype=dtype)
-
-    loss_fn = _make_logistic_loss(
-        x, y_target, mask, offset, scale, n, reg_param, c, fit_intercept, dot,
-        fused=fused,
+    offset, scale, n, prep_passes = _standardizer(
+        x, mask, fit_intercept, standardization
     )
-
-    if init_w is None:
-        w0 = jnp.zeros((d, c), dtype=dtype)
-        b0 = jnp.zeros((c,), dtype=dtype)
-    else:
-        # Inverse of the final back-map: the optimizer works in
-        # standardized space (w_std = w_orig * scale; the intercept
-        # re-absorbs the centering offset).
-        w_orig0 = jnp.asarray(init_w, dtype=dtype)
-        w0 = w_orig0 * scale[:, None]
-        if fit_intercept:
-            # Absorb the centering offset whether or not an original-space
-            # intercept was supplied — (w_orig, 0) must start as the SAME
-            # decision function, not a shifted one.
-            b_orig0 = (
-                jnp.asarray(init_b, dtype=dtype)
-                if init_b is not None
-                else jnp.zeros((c,), dtype=dtype)
-            )
-            b0 = b_orig0 + dot(offset, w_orig0)
-        else:
-            # No intercept in the model: b is never optimized (zero
-            # gradient), so a stale nonzero init would leak into predict.
-            b0 = jnp.zeros((c,), dtype=dtype)
-    params0 = (w0, b0)
-
-    solver = optax.lbfgs()
-    value_and_grad = optax.value_and_grad_from_state(loss_fn)
-    state0 = solver.init(params0)
-
-    def cond(carry):
-        _params, _state, it, gnorm = carry
-        return jnp.logical_and(it < max_iter, gnorm > tol)
-
-    def body(carry):
-        params, state, it, _ = carry
-        value, grad = value_and_grad(params, state=state)
-        updates, state = solver.update(
-            grad, state, params, value=value, grad=grad, value_fn=loss_fn
-        )
-        params = optax.apply_updates(params, updates)
-        gnorm = optax.global_norm(grad)
-        return params, state, it + 1, gnorm
-
-    init = (params0, state0, jnp.asarray(0), jnp.asarray(jnp.inf, dtype=dtype))
-    (w, b), state, n_iter, _ = jax.lax.while_loop(cond, body, init)
-
-    # Identifiability pivot for unregularized softmax (Spark's centering).
-    if c > 1:
-        do_center = reg_param == 0.0
-        w = jnp.where(do_center, w - jnp.mean(w, axis=1, keepdims=True), w)
-        b = jnp.where(do_center, b - jnp.mean(b), b)
-
-    # Map standardized-space solution back to original feature space.
-    w_orig = w / scale[:, None]
-    b_orig = b - dot(offset, w_orig) if fit_intercept else b
-    final_loss = loss_fn((w, b))
-    return LogisticFit(w_orig, b_orig, n_iter, final_loss)
-
-
-@partial(jax.jit, static_argnames=("fit_intercept", "standardization"))
-def _logistic_prep(x, mask, fit_intercept: bool, standardization: bool):
-    """The standardizer inputs of :func:`fit_logistic` — (offset, scale,
-    n) as one small program, shared by every segment of a resumable fit
-    instead of being refolded into each one."""
-    n = jnp.sum(mask)
-    mean, sigma = _masked_feature_moments(x, mask)
-    safe_sigma = jnp.where(sigma > 0, sigma, 1.0)
-    if standardization:
-        offset = mean if fit_intercept else jnp.zeros_like(mean)
-        scale = safe_sigma
-    else:
-        offset = jnp.zeros_like(mean)
-        scale = jnp.ones_like(safe_sigma)
-    return offset, scale, n
+    passes = _make_logistic_loss(
+        x, _class_targets(y, c, dtype), mask, offset, scale, n, reg_param, c,
+        fit_intercept, dot,
+    )
+    params0 = _start_params(
+        init_w, init_b, offset, scale, x.shape[1], c, fit_intercept, dot, dtype
+    )
+    carry = jax.lax.while_loop(
+        lambda carry: jnp.logical_and(carry.it < max_iter, carry.gnorm > tol),
+        partial(_lbfgs_iteration, passes),
+        _lbfgs_start(passes, params0, prep_passes),
+    )
+    return _lbfgs_finish(
+        passes, reg_param, carry, offset, scale, c, fit_intercept, dot
+    )
 
 
 @partial(
     jax.jit,
-    static_argnames=(
-        "c", "fit_intercept", "max_iter", "every", "precision", "fused",
-    ),
+    static_argnames=("c", "fit_intercept", "standardization", "precision"),
 )
-def _lbfgs_segment(
-    x, y_target, mask, offset, scale, n, reg_param, tol,
-    params, opt_state, it, gnorm,
-    c: int, fit_intercept: bool, max_iter: int, every: int, precision: str,
-    fused: bool = True,
+def _lbfgs_prepare(
+    x, y, mask, reg_param, init_w, init_b,
+    c: int, fit_intercept: bool, standardization: bool, precision: str,
 ):
-    """Up to ``every`` L-BFGS iterations from an explicit optimizer
-    state — exactly :func:`fit_logistic`'s loop body and stopping rule
-    plus a segment budget, with the full (params, optax state, iteration,
-    gradient norm) carry visible as a pytree between segments."""
+    """The head of :func:`fit_logistic` as its own program for the
+    segmented driver: (offset, scale, n, class targets, first carry)."""
     dot = make_dot(precision)
-    loss_fn = _make_logistic_loss(
-        x, y_target, mask, offset, scale, n, reg_param, c, fit_intercept, dot,
-        fused=fused,
+    offset, scale, n, prep_passes = _standardizer(
+        x, mask, fit_intercept, standardization
     )
-    solver = optax.lbfgs()
-    value_and_grad = optax.value_and_grad_from_state(loss_fn)
-
-    def cond(carry):
-        _params, _state, it, gnorm, seg = carry
-        return jnp.logical_and(
-            jnp.logical_and(it < max_iter, gnorm > tol), seg < every
-        )
-
-    def body(carry):
-        params, state, it, _, seg = carry
-        value, grad = value_and_grad(params, state=state)
-        updates, state = solver.update(
-            grad, state, params, value=value, grad=grad, value_fn=loss_fn
-        )
-        params = optax.apply_updates(params, updates)
-        gnorm = optax.global_norm(grad)
-        return params, state, it + 1, gnorm, seg + 1
-
-    params, opt_state, it, gnorm, _ = jax.lax.while_loop(
-        cond, body, (params, opt_state, it, gnorm, 0)
+    y_target = _class_targets(y, c, x.dtype)
+    passes = _make_logistic_loss(
+        x, y_target, mask, offset, scale, n, reg_param, c, fit_intercept, dot
     )
-    return params, opt_state, it, gnorm
+    params0 = _start_params(
+        init_w, init_b, offset, scale, x.shape[1], c, fit_intercept, dot, x.dtype
+    )
+    return offset, scale, n, y_target, _lbfgs_start(passes, params0, prep_passes)
 
 
 @partial(
-    jax.jit, static_argnames=("c", "fit_intercept", "precision", "fused")
+    jax.jit,
+    static_argnames=("c", "fit_intercept", "max_iter", "every", "precision"),
 )
-def _logistic_finalize(
-    x, y_target, mask, offset, scale, n, reg_param, w, b,
-    c: int, fit_intercept: bool, precision: str, fused: bool = True,
+def _lbfgs_segment(
+    x, y_target, mask, offset, scale, n, reg_param, tol, carry,
+    c: int, fit_intercept: bool, max_iter: int, every: int, precision: str,
 ):
-    """:func:`fit_logistic`'s post-solve tail (identifiability pivot,
-    back-map to original feature space, final objective) as its own
-    program for the segmented driver."""
-    dot = make_dot(precision)
-    loss_fn = _make_logistic_loss(
-        x, y_target, mask, offset, scale, n, reg_param, c, fit_intercept, dot,
-        fused=fused,
+    """Up to ``every`` L-BFGS iterations from an explicit carry — exactly
+    :func:`fit_logistic`'s loop body and stopping rule plus a segment
+    budget, with the full :class:`_LbfgsCarry` visible as a pytree between
+    segments."""
+    passes = _make_logistic_loss(
+        x, y_target, mask, offset, scale, n, reg_param, c, fit_intercept,
+        make_dot(precision),
     )
-    if c > 1:
-        do_center = reg_param == 0.0
-        w = jnp.where(do_center, w - jnp.mean(w, axis=1, keepdims=True), w)
-        b = jnp.where(do_center, b - jnp.mean(b), b)
-    w_orig = w / scale[:, None]
-    b_orig = b - dot(offset, w_orig) if fit_intercept else b
-    return w_orig, b_orig, loss_fn((w, b))
+
+    def cond(state):
+        carry, seg = state
+        return jnp.logical_and(
+            jnp.logical_and(carry.it < max_iter, carry.gnorm > tol), seg < every
+        )
+
+    def body(state):
+        carry, seg = state
+        return _lbfgs_iteration(passes, carry), seg + 1
+
+    return jax.lax.while_loop(cond, body, (carry, 0))[0]
+
+
+@partial(jax.jit, static_argnames=("c", "fit_intercept", "precision"))
+def _logistic_finalize(
+    x, y_target, mask, offset, scale, n, reg_param, carry,
+    c: int, fit_intercept: bool, precision: str,
+):
+    """:func:`fit_logistic`'s post-solve tail (:func:`_lbfgs_finish`) as
+    its own program for the segmented driver."""
+    dot = make_dot(precision)
+    passes = _make_logistic_loss(
+        x, y_target, mask, offset, scale, n, reg_param, c, fit_intercept, dot
+    )
+    return _lbfgs_finish(
+        passes, reg_param, carry, offset, scale, c, fit_intercept, dot
+    )
 
 
 def fit_logistic_resumable(
@@ -419,13 +495,12 @@ def fit_logistic_resumable(
     init_w: jax.Array | None = None,
     init_b: jax.Array | None = None,
     mesh=None,
-    fused: bool = True,
 ) -> LogisticFit:
     """Preemption-tolerant :func:`fit_logistic` (the L-BFGS / L2 path):
-    a host outer loop over jitted L-BFGS segments, the (params, optimizer
-    state, iteration counter, gradient norm) pytree snapshotted
-    asynchronously between segments, the fit resumed mid-solve from the
-    latest valid checkpoint. Same returns, bit-identical solution."""
+    a host outer loop over jitted L-BFGS segments, the :class:`_LbfgsCarry`
+    pytree snapshotted asynchronously between segments, the fit resumed
+    mid-solve from the latest valid checkpoint. Same returns, bit-identical
+    solution."""
     from spark_rapids_ml_tpu.robustness.checkpoint import (
         replicate_state_onto_mesh,
         segment_boundary,
@@ -440,37 +515,11 @@ def fit_logistic_resumable(
     if n_classes < 2:
         raise ValueError(f"need at least 2 classes, got {n_classes}")
     c = n_classes if (multinomial or n_classes > 2) else 1
-    d = x.shape[1]
-    dtype = x.dtype
-    dot = make_dot(precision)
-    offset, scale, n = _logistic_prep(
-        x, mask, fit_intercept=fit_intercept, standardization=standardization
+    shared = dict(c=c, fit_intercept=fit_intercept, precision=precision)
+    offset, scale, n, y_target, carry = _lbfgs_prepare(
+        x, y, mask, reg_param, init_w, init_b,
+        standardization=standardization, **shared,
     )
-
-    if c == 1:
-        y_target = (y == 1).astype(dtype)
-    else:
-        y_target = jax.nn.one_hot(y, c, dtype=dtype)
-
-    if init_w is None:
-        w0 = jnp.zeros((d, c), dtype=dtype)
-        b0 = jnp.zeros((c,), dtype=dtype)
-    else:
-        w_orig0 = jnp.asarray(init_w, dtype=dtype)
-        w0 = w_orig0 * scale[:, None]
-        if fit_intercept:
-            b_orig0 = (
-                jnp.asarray(init_b, dtype=dtype)
-                if init_b is not None
-                else jnp.zeros((c,), dtype=dtype)
-            )
-            b0 = b_orig0 + dot(offset, w_orig0)
-        else:
-            b0 = jnp.zeros((c,), dtype=dtype)
-
-    params0 = (w0, b0)
-    state0 = optax.lbfgs().init(params0)
-    carry = (params0, state0, jnp.asarray(0), jnp.asarray(jnp.inf, dtype=dtype))
     restored = checkpointer.restore_latest(template=carry)
     if restored is not None:
         _, carry = restored
@@ -478,37 +527,29 @@ def fit_logistic_resumable(
             carry = replicate_state_onto_mesh(carry, mesh)
 
     while True:
-        it, gn = int(carry[2]), float(carry[3])
+        it, gn = int(carry.it), float(carry.gnorm)
         if not (it < max_iter and gn > tol):
             break
         seg_t0 = time.perf_counter()
         with TraceRange("segment logistic.lbfgs", TraceColor.PURPLE):
             fault_point("solver.segment")
-            params, opt_state, it_a, gn_a = ledgered_call(
+            carry = ledgered_call(
                 _lbfgs_segment,
-                (x, y_target, mask, offset, scale, n,
-                 reg_param, tol, carry[0], carry[1], carry[2], carry[3]),
-                static=dict(
-                    c=c, fit_intercept=fit_intercept, max_iter=max_iter,
-                    every=checkpointer.every, precision=precision,
-                    fused=fused,
-                ),
+                (x, y_target, mask, offset, scale, n, reg_param, tol, carry),
+                static=dict(max_iter=max_iter, every=checkpointer.every, **shared),
                 name="logistic.lbfgs.segment",
             )
-            carry = (params, opt_state, it_a, gn_a)
             bump_counter("checkpoint.segments")
-            bump_counter("checkpoint.solver_iters", int(it_a) - it)
+            bump_counter("checkpoint.solver_iters", int(carry.it) - it)
         observe_segment_seconds("logistic.lbfgs", time.perf_counter() - seg_t0)
-        checkpointer.save_async(int(it_a), carry)
+        checkpointer.save_async(int(carry.it), carry)
         segment_boundary(checkpointer)
 
-    (w, b), _, n_iter, _ = carry
-    w_orig, b_orig, final_loss = _logistic_finalize(
-        x, y_target, mask, offset, scale, n, reg_param, w, b,
-        c=c, fit_intercept=fit_intercept, precision=precision, fused=fused,
+    result = _logistic_finalize(
+        x, y_target, mask, offset, scale, n, reg_param, carry, **shared
     )
     checkpointer.finalize_success()
-    return LogisticFit(w_orig, b_orig, n_iter, final_loss)
+    return result
 
 
 @partial(
@@ -553,21 +594,8 @@ def fit_logistic_elastic_net(
     d = x.shape[1]
     dtype = x.dtype
     dot = make_dot(precision)
-    n = jnp.sum(mask)
-
-    mean, sigma = _masked_feature_moments(x, mask)
-    safe_sigma = jnp.where(sigma > 0, sigma, 1.0)
-    if standardization:
-        offset = mean if fit_intercept else jnp.zeros_like(mean)
-        scale = safe_sigma
-    else:
-        offset = jnp.zeros_like(mean)
-        scale = jnp.ones_like(safe_sigma)
-
-    if c == 1:
-        y_target = (y == 1).astype(dtype)
-    else:
-        y_target = jax.nn.one_hot(y, c, dtype=dtype)
+    offset, scale, n, _ = _standardizer(x, mask, fit_intercept, standardization)
+    y_target = _class_targets(y, c, dtype)
 
     reg1 = reg_param * elastic_net_param
     reg2 = reg_param * (1.0 - elastic_net_param)
@@ -598,16 +626,18 @@ def fit_logistic_elastic_net(
     lip = 1.1 * lam_max * curvature / n + reg2 + 1e-12
 
     # The FISTA smooth part (log-loss + L2 at reg2) IS the L-BFGS
-    # objective at reg_param=reg2 — so the fused one-pass builder serves
+    # objective at reg_param=reg2 — so the blocked one-sweep builder serves
     # both solvers from the same algebra.
     if fused:
-        smooth_loss = _make_logistic_loss(
-            x, y_target, mask, offset, scale, n, reg2, c, fit_intercept,
-            dot, fused=True,
-        )
+        sweep = _make_logistic_loss(
+            x, y_target, mask, offset, scale, n, reg2, c, fit_intercept, dot
+        ).value_and_grad
+
+        def smooth_loss(params):
+            return sweep(params)[0]
 
         def grad_fn(params):
-            return smooth_loss.value_and_grad(params)[1]
+            return sweep(params)[1]
 
     else:
 

@@ -204,8 +204,9 @@ KNOBS: Dict[str, Knob] = _knob_table(
          "scatter; auto = pallas on the TPU backend",
          default="auto", choices=("auto", "pallas", "xla")),
     Knob("TPUML_LOGISTIC_FUSED", "choice", "kernels",
-         "1 = fused one-pass logistic loss+grad (X streamed once per "
-         "evaluation); 0 = legacy two-pass autodiff objective",
+         "FISTA and streaming logistic fits: 1 = one-sweep blocked loss + "
+         "analytic gradient; 0 = legacy autodiff objective (X read forward "
+         "and backward). The in-memory L-BFGS fit has one formulation",
          default="1", choices=("0", "1")),
     # serving-path program cache
     Knob("TPUML_SERVING_CACHE_SIZE", "int", "serving",

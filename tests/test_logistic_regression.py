@@ -356,49 +356,17 @@ class TestWarmStart:
 
 
 class TestFusedObjective:
-    """Fused one-pass loss+grad: the custom_vjp objective
-    streams X once per evaluation instead of saving the standardized
-    design as an AD residual. Fused and legacy must agree to float
-    tolerance on every driver — monolithic, blocked, streaming — and the
-    knob must be honored at the estimator layer."""
-
-    def _ops_fit(self, x, y, n_classes, fused, multinomial=False):
-        import jax.numpy as jnp
-
-        from spark_rapids_ml_tpu.ops.logistic import fit_logistic
-
-        return fit_logistic(
-            jnp.asarray(x, jnp.float64),
-            jnp.asarray(y),
-            jnp.ones(len(y)),
-            n_classes,
-            reg_param=0.01,
-            multinomial=multinomial,
-            fused=fused,
-        )
-
-    def test_binomial_fused_matches_legacy(self, rng):
-        x, y = make_binary(rng)
-        f = self._ops_fit(x, y, 2, fused=True)
-        g = self._ops_fit(x, y, 2, fused=False)
-        np.testing.assert_allclose(f.weights, g.weights, atol=1e-6)
-        np.testing.assert_allclose(f.intercepts, g.intercepts, atol=1e-6)
-        assert f.n_iter == g.n_iter  # same objective -> same L-BFGS path
-
-    def test_multinomial_fused_matches_legacy(self, rng):
-        x, y = make_multiclass(rng)
-        f = self._ops_fit(x, y, 4, fused=True, multinomial=True)
-        g = self._ops_fit(x, y, 4, fused=False, multinomial=True)
-        np.testing.assert_allclose(f.weights, g.weights, atol=1e-6)
-        assert f.n_iter == g.n_iter
+    """The blocked analytic passes (``_make_logistic_loss``): one sweep for
+    value and gradient (FISTA's), and the margins / value-from-margins /
+    gradient-from-margins the L-BFGS iteration works on, must equal autodiff
+    of the plain objective; ``TPUML_LOGISTIC_FUSED=0`` keeps the autodiff
+    formulation on the FISTA and streaming drivers, and the L-BFGS fit has
+    one formulation."""
 
     @pytest.mark.parametrize("c,fit_intercept", [(1, True), (3, True), (3, False)])
-    def test_blocked_value_and_grad_matches_autodiff(
-        self, rng, monkeypatch, c, fit_intercept
-    ):
-        """The analytic one-pass gradient — including the fori_loop
-        slide-back blocking — must equal autodiff of the plain objective,
-        and the custom_vjp must expose the same gradient to jax.grad."""
+    def test_blocked_passes_match_autodiff(self, rng, monkeypatch, c, fit_intercept):
+        """Including the fori_loop slide-back blocking of a ragged last
+        block."""
         import jax
         import jax.numpy as jnp
 
@@ -415,25 +383,38 @@ class TestFusedObjective:
         scale = jnp.asarray(rng.uniform(0.5, 2.0, d))
         w = jnp.asarray(rng.normal(size=(d, c)) * 0.1)
         b = jnp.asarray(rng.normal(size=c) * 0.1)
-        args = (x, y_t, mask, offset, scale, float(mask.sum()), 0.05, c,
-                fit_intercept, "highest")
+        n_eff, reg = float(mask.sum()), 0.05
 
-        legacy = lg._make_logistic_loss(*args, fused=False)
-        val_ref, grad_ref = jax.value_and_grad(legacy)((w, b))
+        def plain(params):
+            # the objective as one expression, for autodiff
+            w_, b_ = params
+            logits = ((x - offset) / scale) @ w_ + (b_ if fit_intercept else 0.0)
+            if c == 1:
+                per_row = jax.nn.softplus(logits[:, 0]) - y_t * logits[:, 0]
+            else:
+                per_row = -jnp.sum(y_t * jax.nn.log_softmax(logits, axis=1), axis=1)
+            return jnp.sum(per_row * mask) / n_eff + 0.5 * reg * jnp.sum(w_ * w_)
+
+        val_ref, grad_ref = jax.value_and_grad(plain)((w, b))
 
         # Force the multi-block path: 301 rows over 64-row blocks needs
         # the slide-back + keep-mask for the ragged final block.
         monkeypatch.setattr(lg, "_FUSED_BLOCK_ROWS", 64)
-        fused = lg._make_logistic_loss(*args, fused=True)
-        val, (gw, gb) = fused.value_and_grad((w, b))
+        passes = lg._make_logistic_loss(
+            x, y_t, mask, offset, scale, n_eff, reg, c, fit_intercept, "highest"
+        )
+        val, (gw, gb) = passes.value_and_grad((w, b))
         assert float(val) == pytest.approx(float(val_ref), rel=1e-12)
         np.testing.assert_allclose(gw, grad_ref[0], atol=1e-12)
         np.testing.assert_allclose(gb, grad_ref[1], atol=1e-12)
 
-        # The custom_vjp route (what optax linesearch trial points hit).
-        _, grad_vjp = jax.value_and_grad(fused)((w, b))
-        np.testing.assert_allclose(grad_vjp[0], gw, atol=1e-12)
-        np.testing.assert_allclose(grad_vjp[1], gb, atol=1e-12)
+        # what the L-BFGS iteration uses: margins once, then both from them
+        z = passes.margins(w, b)
+        assert z.shape == (n, c)
+        assert float(passes.value_at(z, w)) == pytest.approx(float(val_ref), rel=1e-12)
+        gw_z, gb_z = passes.grad_at(z, (w, b))
+        np.testing.assert_allclose(gw_z, grad_ref[0], atol=1e-12)
+        np.testing.assert_allclose(gb_z, grad_ref[1], atol=1e-12)
 
     def test_streaming_fused_matches_legacy(self, rng):
         from spark_rapids_ml_tpu.ops.logistic import (
@@ -459,9 +440,9 @@ class TestFusedObjective:
         np.testing.assert_allclose(f.weights, g.weights, atol=1e-5)
         np.testing.assert_allclose(f.intercepts, g.intercepts, atol=1e-5)
 
-    def test_estimator_knob_parity(self, rng, monkeypatch):
-        """TPUML_LOGISTIC_FUSED=0 restores the legacy two-pass objective
-        through the public estimator — same fitted model either way."""
+    def test_knob_moves_nothing_on_the_lbfgs_path(self, rng, monkeypatch):
+        """The L-BFGS fit has one formulation: TPUML_LOGISTIC_FUSED governs
+        FISTA and the streaming fit alone."""
         x, y = make_binary(rng)
 
         def fit(knob):
@@ -470,8 +451,8 @@ class TestFusedObjective:
             return est.fit((x, y.astype(np.float64)))
 
         m1, m0 = fit("1"), fit("0")
-        np.testing.assert_allclose(m1.coefficients, m0.coefficients, atol=1e-6)
-        assert m1.intercept == pytest.approx(m0.intercept, abs=1e-6)
+        assert np.array_equal(m1.coefficients, m0.coefficients)
+        assert m1.intercept == m0.intercept and m1.xPasses == m0.xPasses
 
     def test_elastic_net_fused_matches_legacy(self, rng, monkeypatch):
         """FISTA's smooth part shares the fused builder: the knob must
@@ -490,3 +471,212 @@ class TestFusedObjective:
 
         m1, m0 = fit("1"), fit("0")
         np.testing.assert_allclose(m1.coefficients, m0.coefficients, atol=1e-5)
+
+
+# --- the L-BFGS iteration on cached margins -------------------------------
+
+
+def plain_objective(x, y, n_classes, reg, fit_intercept, standardization, multinomial):
+    """A plain float64 statement of Spark's objective, independent of the
+    program: ``(f, grad)`` of ``theta`` = the ORIGINAL-space coefficients
+    (d, c) then, with an intercept, the intercepts (c,). The penalty is on
+    ``w_j sigma_j`` with ``standardization`` (the population deviation), on
+    ``w_j`` without; the intercept is free."""
+    from scipy.special import expit, log_softmax, softmax
+
+    n, d = x.shape
+    c = n_classes if multinomial else 1
+    sigma = x.std(axis=0) if standardization else np.ones(d)
+    onehot = np.eye(n_classes)[y] if multinomial else (y == 1).astype(np.float64)[:, None]
+
+    def fun(theta):
+        w = theta[: d * c].reshape(d, c)
+        b = theta[d * c :] if fit_intercept else np.zeros(c)
+        z = x @ w + b
+        if multinomial:
+            loss = -np.sum(onehot * log_softmax(z, axis=1)) / n
+            dz = (softmax(z, axis=1) - onehot) / n
+        else:
+            loss = np.sum(np.logaddexp(0.0, z) - onehot * z) / n
+            dz = (expit(z) - onehot) / n
+        ws = w * sigma[:, None]
+        grad = [(x.T @ dz + reg * ws * sigma[:, None]).ravel()]
+        if fit_intercept:
+            grad.append(dz.sum(axis=0))
+        return loss + 0.5 * reg * np.sum(ws * ws), np.concatenate(grad)
+
+    return fun, d * c + (c if fit_intercept else 0)
+
+
+def to_returned_space(grad, x, fit_intercept, standardization):
+    """``finalGradient`` (d + 1, c) is in the optimizer's space, w = w_orig
+    sigma and b = b_orig + mean . w_orig: by the chain rule, with respect
+    to the returned coefficients."""
+    if not standardization:
+        return grad
+    mean = x.mean(axis=0) if fit_intercept else np.zeros(x.shape[1])
+    gw = grad[:-1] * x.std(axis=0)[:, None] + mean[:, None] * grad[-1][None, :]
+    return np.concatenate([gw, grad[-1:]])
+
+
+class TestCachedMarginLbfgs:
+    """The L-BFGS iteration keeps the margins of its point and searches
+    its line on them: what it returns is held against a plain statement of
+    the objective minimised by scipy, and what it costs against the
+    configuration's formula."""
+
+    @pytest.mark.parametrize("standardization", [True, False])
+    @pytest.mark.parametrize("fit_intercept", [True, False])
+    @pytest.mark.parametrize("multinomial", [False, True])
+    def test_matches_the_plain_reference(
+        self, rng, multinomial, fit_intercept, standardization
+    ):
+        from scipy.optimize import minimize
+
+        if multinomial:
+            x, y = make_multiclass(rng, c=3)
+        else:
+            x, y = make_binary(rng)
+        x = x * np.linspace(0.5, 3.0, x.shape[1]) + 0.7  # columns that differ
+        n_classes = 3 if multinomial else 2
+        reg = 0.01
+        model = (
+            LogisticRegression()
+            .setRegParam(reg)
+            .setFitIntercept(fit_intercept)
+            .setStandardization(standardization)
+            .setMaxIter(200)
+            .setTol(1e-9)
+            .fit((x, y))
+        )
+        fun, size = plain_objective(
+            x, y, n_classes, reg, fit_intercept, standardization, multinomial
+        )
+        best = minimize(fun, np.zeros(size), jac=True, method="L-BFGS-B",
+                        options={"maxiter": 2000, "ftol": 1e-15, "gtol": 1e-10})
+        got = [np.asarray(model.weights).ravel()]
+        if fit_intercept:
+            got.append(np.asarray(model.intercepts))
+        f_got, grad_got = fun(np.concatenate(got))
+        grad0 = fun(np.zeros(size))[1]
+        # objective gap as a share, gradient norm over the norm at zero
+        assert (f_got - best.fun) / best.fun < 1e-9
+        # what the fit reports off its cached margins is the objective there
+        assert model.finalObjective == pytest.approx(f_got, rel=1e-9)
+        assert np.linalg.norm(grad_got) < 1e-6 * np.linalg.norm(grad0)
+        assert 0 < model.numIter < 200
+        # the gradient the last iteration computed from its cached margins
+        # is the plain one at the returned coefficients
+        reported = to_returned_space(model.finalGradient, x, fit_intercept, standardization)
+        assert reported.shape == (x.shape[1] + 1, model.weights.shape[1])
+        flat = [reported[:-1].ravel()] + ([reported[-1]] if fit_intercept else [])
+        assert np.linalg.norm(np.concatenate(flat) - grad_got) < 1e-9 * np.linalg.norm(grad0)
+        if not fit_intercept:
+            assert not reported[-1].any()
+
+    @pytest.mark.parametrize("standardization,start", [(False, 2), (True, 4)])
+    def test_passes_over_the_rows_do_not_depend_on_the_seed(self, standardization, start):
+        """tol 1e-30 in float32: every fit runs maxIter iterations, many of
+        them at float32's floor where the line search finds no decrease.
+        ``xPasses`` is the formula on every seed; only the trials differ."""
+        max_iter = 40
+        passes, trials = [], []
+        for seed in range(8):
+            x, y = make_binary(np.random.default_rng(seed), n=300, d=8)
+            model = (
+                LogisticRegression()
+                .setRegParam(1e-3)
+                .setStandardization(standardization)
+                .setMaxIter(max_iter)
+                .setTol(1e-30)
+                .fit((x.astype(np.float32), y))
+            )
+            assert model.numIter == max_iter
+            passes.append(model.xPasses)
+            trials.append(model.linesearchTrials)
+        assert passes == [start + 2 * max_iter] * 8
+        assert min(trials) >= max_iter and len(set(trials)) >= 2
+
+    @pytest.mark.parametrize("multinomial", [False, True])
+    def test_segmented_is_bit_identical_to_monolithic(self, rng, multinomial):
+        import jax.numpy as jnp
+
+        from spark_rapids_ml_tpu.ops.logistic import (
+            fit_logistic,
+            fit_logistic_resumable,
+        )
+        from spark_rapids_ml_tpu.robustness.checkpoint import EphemeralSegmenter
+
+        x, y = make_multiclass(rng, c=3) if multinomial else make_binary(rng)
+        args = (jnp.asarray(x), jnp.asarray(y), jnp.ones(len(y)))
+        kwargs = dict(n_classes=3 if multinomial else 2, reg_param=0.01, max_iter=30,
+                      tol=1e-12, multinomial=multinomial)
+        whole = fit_logistic(*args, **kwargs)
+        pieces = fit_logistic_resumable(*args, EphemeralSegmenter(7), **kwargs)
+        import jax
+
+        leaves = [jax.tree_util.tree_leaves(fit) for fit in (whole, pieces)]
+        assert len(leaves[0]) == len(leaves[1]) == 8  # the gradient's pair too
+        for a, b in zip(*leaves):
+            assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+        assert int(whole.x_passes) == 4 + 2 * int(whole.n_iter)
+
+    def test_counters_and_solve_stage_show_in_the_fit_report(self, rng):
+        from spark_rapids_ml_tpu.utils.tracing import counter_value
+
+        x, y = make_binary(rng)
+        before = {k: counter_value(f"logreg.lbfgs.{k}")
+                  for k in ("iters", "x_passes", "linesearch_trials")}
+        model = LogisticRegression().setRegParam(0.01).fit((x, y))
+        report = model.fit_report()
+        assert "solve" in report.stage_totals()
+        assert not any(k.startswith("logreg.lbfgs.") for k in report.counters)
+        # the counts cross to the host, and the counters move, on first read
+        moved = {"iters": model.numIter, "x_passes": model.xPasses,
+                 "linesearch_trials": model.linesearchTrials}
+        assert moved["x_passes"] == 4 + 2 * moved["iters"]
+        for k, v in moved.items():
+            assert counter_value(f"logreg.lbfgs.{k}") - before[k] == v
+            assert report.counters[f"logreg.lbfgs.{k}"] == v
+        assert "logreg.lbfgs.x_passes" in str(report)
+        _ = model.numIter  # a second read moves nothing
+        assert counter_value("logreg.lbfgs.iters") - before["iters"] == moved["iters"]
+
+    def test_a_copy_counts_its_fit_once(self, rng):
+        from spark_rapids_ml_tpu.utils.tracing import counter_value
+
+        x, y = make_binary(rng)
+        before = counter_value("logreg.lbfgs.iters")
+        model = LogisticRegression().setRegParam(0.01).fit((x, y))
+        twin = model.copy()  # before either was read
+        assert twin.numIter == model.numIter and twin.xPasses == model.xPasses
+        assert np.array_equal(twin.finalGradient, model.finalGradient)
+        assert counter_value("logreg.lbfgs.iters") - before == model.numIter
+
+    @pytest.mark.parametrize("standardization", [True, False])
+    def test_final_gradient_of_an_unconverged_fit(self, rng, standardization):
+        """Five iterations leave a gradient far from nought: what the model
+        reports is still the plain objective's gradient at what it returns,
+        to float64's rounding (the margins carried, never refreshed)."""
+        x, y = make_binary(rng)
+        x = x * np.linspace(0.5, 3.0, x.shape[1]) + 0.7
+        model = (
+            LogisticRegression().setRegParam(0.01).setMaxIter(5).setTol(0.0)
+            .setStandardization(standardization).fit((x, y))
+        )
+        fun, size = plain_objective(x, y, 2, 0.01, True, standardization, False)
+        theta = np.append(np.asarray(model.weights).ravel(), model.intercepts)
+        grad = fun(theta)[1]
+        assert model.numIter == 5 and np.linalg.norm(grad) > 1e-4
+        reported = to_returned_space(model.finalGradient, x, True, standardization)
+        np.testing.assert_allclose(reported.ravel(), grad, atol=1e-12)
+
+    def test_off_the_lbfgs_path_reports_no_passes(self, rng):
+        x, y = make_binary(rng)
+        model = (
+            LogisticRegression().setRegParam(0.05).setElasticNetParam(0.5).fit((x, y))
+        )
+        assert model.numIter > 0
+        assert model.xPasses is None and model.linesearchTrials is None
+        assert model.finalGradient is None
+        assert model.finalObjective > 0
