@@ -57,6 +57,7 @@ FULL = dict(
     host_rows=262_144, f64_rows=65_536, f64_cols=256,
     km_rows=2_000_000, km_cols=16, km_iters=10,
     umap_rows=50_000, umap_cols=64, umap_epochs=30,
+    split_values=1_000_000, update_rows=65_536, update_cols=3000, update_k=1000,
     mesh_rows_per_chip=1_000_000,
 )
 TINY = dict(
@@ -64,6 +65,7 @@ TINY = dict(
     host_rows=2048, f64_rows=8192, f64_cols=32,
     km_rows=20_000, km_cols=16, km_iters=3,
     umap_rows=600, umap_cols=16, umap_epochs=5,
+    split_values=4096, update_rows=2048, update_cols=128, update_k=16,
     mesh_rows_per_chip=2048,
 )
 REQUEST_ROWS = (1, 7, 64, 1000)
@@ -421,6 +423,7 @@ def run_kernels(key, sz, rehearse: bool):
 
     kx, ku = jax.random.split(key)
     km_model = kernels_kmeans(kx, sz, rehearse)
+    kernels_kmeans_update(jax.random.fold_in(key, 31), sz)
     kernels_umap(ku, sz, rehearse)
     return km_model
 
@@ -492,6 +495,86 @@ def kernels_kmeans(key, sz, rehearse: bool):
             route=route, **memory(),
         )
     return fitted[100]
+
+
+def kernels_kmeans_update(key, sz) -> None:
+    """The wide KMeans centre update (ops/kmeans.py, float32 rows at
+    ``highest``): three bf16 passes on an exact three-piece split of the
+    rows. Only the chip can show that its compiler keeps the split's
+    roundings (it drops an ``astype`` round trip: PERF.md 7.9); a CPU
+    passes this whatever the code does. (1) The pieces leave their program
+    AS bfloat16 arrays, so nothing can carry excess precision, and are
+    added up on the host: ``(hi + mid) + lo == x`` bit for bit. (2) The
+    three-pass sums of one block against the ``HIGHEST`` one-hot matmul
+    they replace, and both against float64 sums made on the host."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from spark_rapids_ml_tpu.ops.kmeans import _assign_and_accumulate, _onehot_sums_split3
+    from spark_rapids_ml_tpu.ops.precision import make_dot, split3_bf16
+    from spark_rapids_ml_tpu.utils.tracing import counter_value
+
+    kv, ke, kx, kl, kw, km = jax.random.split(key, 6)
+    m = sz["split_values"]
+    # Exponents over 2^-80 .. 2^80, both signs, some zeros.
+    values = jax.random.normal(kv, (m,), jnp.float32) * jnp.exp2(
+        jax.random.uniform(ke, (m,), jnp.float32, -80.0, 80.0)
+    )
+    values = values.at[::1000].set(0.0)
+    (hi, mid, lo), split_s = timed(lambda: jax.jit(split3_bf16)(values))
+    if not all(p.dtype == jnp.bfloat16 for p in (hi, mid, lo)):
+        raise SmokeFailure(f"split3_bf16 pieces are {hi.dtype}, {mid.dtype}, {lo.dtype}")
+    f32 = lambda p: np.asarray(p).astype(np.float32)
+    back = (f32(hi) + f32(mid)) + f32(lo)
+    want = np.asarray(values)
+    wrong = int(np.sum(back.view(np.uint32) != want.view(np.uint32)))
+    check("split3_bf16 (hi + mid) + lo == x, values off", wrong, 0)
+
+    n, d, k = sz["update_rows"], sz["update_cols"], sz["update_k"]
+    x = generate(make_blobs, kx, n, d, 3)
+    labels = jax.random.randint(kl, (n,), 0, k)
+    # Fractional weights and some masked rows: weightCol's path.
+    mb = jnp.where(jax.random.uniform(km, (n,)) < 0.02, 0.0,
+                   jax.random.uniform(kw, (n,), jnp.float32, 0.5, 2.0))
+
+    def old(labels, mb, x):
+        one_hot = jax.nn.one_hot(labels, k, dtype=x.dtype) * mb[:, None]
+        return make_dot("highest")(one_hot.T, x), jnp.sum(one_hot, axis=0)
+
+    (got, got_n), cold = timed(lambda: jax.jit(_onehot_sums_split3, static_argnums=1)(labels, k, mb, x))
+    (ref, ref_n), ref_s = timed(lambda: jax.jit(old)(labels, mb, x))
+    exact = np.zeros((k, d))
+    np.add.at(exact, np.asarray(labels), np.asarray(x, np.float64) * np.asarray(mb, np.float64)[:, None])
+    top = float(np.max(np.abs(exact)))
+    # The chip's HIGHEST product is itself 9.0e-7 off the float64 sums
+    # (PR 31's run; PERF.md 7.9 reads 6.1e-7 on a plain product), the three
+    # passes 8.6e-8: so the float64 sums hold the new update, and its
+    # distance from the old one is held loosely.
+    err_64 = check("update: three-pass sums vs float64 host sums",
+                   np.max(np.abs(np.asarray(got) - exact)) / top, 3e-7)
+    err_old = check("update: three-pass sums vs HIGHEST one-hot matmul",
+                    np.max(np.abs(np.asarray(got) - np.asarray(ref))) / top, 3e-6)
+    ref_64 = float(np.max(np.abs(np.asarray(ref) - exact)) / top)
+    n_err = check("update: counts vs HIGHEST",
+                  np.max(np.abs(np.asarray(got_n) - np.asarray(ref_n))) / float(np.max(ref_n)), 1e-6)
+    # Which update the Lloyd step itself traces for these rows.
+    before = counter_value("kmeans.update.split3"), counter_value("kmeans.update.matmul")
+    jax.block_until_ready(jax.jit(_assign_and_accumulate, static_argnums=(4, 5))(
+        x, mb, jnp.sum(x * x, axis=1), x[:k], k, make_dot("highest")))
+    traced = (counter_value("kmeans.update.split3") - before[0],
+              counter_value("kmeans.update.matmul") - before[1])
+    if traced != (1, 0):
+        raise SmokeFailure(f"Lloyd step traced (split3, matmul) = {traced}, want (1, 0)")
+    emit(
+        phase="kernels", kernel="kmeans.update_split3",
+        split_values=m, split_values_off=wrong, split_s=split_s,
+        rows=n, cols=d, k=k, cold_s=cold, highest_s=ref_s,
+        sums_vs_highest=err_old, sums_vs_float64=err_64, highest_vs_float64=ref_64,
+        counts_vs_highest=n_err,
+        tol={"sums_vs_float64": 3e-7, "sums_vs_highest": 3e-6, "counts": 1e-6, "split_values_off": 0},
+        traced={"split3": traced[0], "matmul": traced[1]}, **memory(),
+    )
 
 
 def kernels_umap(key, sz, rehearse: bool) -> None:

@@ -9,7 +9,11 @@ TPU formulation keeps everything on the MXU:
     ||x||^2 - 2 x C^T + ||c||^2 — one (n,d)x(d,k) matmul, no materialized
     (n,k,d) intermediate;
   - update: cluster sums as one_hot(labels)^T X — a (k,n)x(n,d) matmul —
-    so the "scatter-add" is also a systolic-array op;
+    so the "scatter-add" is also a systolic-array op. A one-hot matrix is
+    exact in ONE bfloat16 piece, so for float32 rows at full precision the
+    product is three single-pass bf16 matmuls on an exact three-piece
+    split of the rows (hi + mid + lo == x), accumulated in float32: the
+    sums a HIGHEST matmul gives, at half its six passes;
   - the whole fit is ONE jitted lax.while_loop (movement tolerance + max
     iterations), compiler-friendly static shapes throughout;
   - empty clusters keep their previous center (Spark/RAFT behavior);
@@ -30,7 +34,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from spark_rapids_ml_tpu.ops.precision import as_dot, make_dot
+from spark_rapids_ml_tpu.ops.precision import (
+    as_dot,
+    is_highest_matmul,
+    make_dot,
+    split3_bf16,
+)
+from spark_rapids_ml_tpu.utils.tracing import bump_counter
 
 
 def _sq_dists(x, centers, x2, dot):
@@ -51,17 +61,48 @@ def assign_clusters(x, centers, precision: str = "highest"):
     return labels, jnp.take_along_axis(d2, labels[:, None], axis=1)[:, 0]
 
 
+def _onehot_sums_split3(labels, k, mb, xb):
+    """Weighted cluster sums ``(k, d)`` and counts ``(k,)`` of float32
+    rows in THREE single-pass bfloat16 products. The one-hot of the labels
+    on the mask's support is 0/1, exact in one bfloat16 piece; the
+    weighted rows split exactly into three (``split3_bf16``), and each
+    product accumulates in float32: the same sums a ``HIGHEST`` matmul
+    gives (to rounding order) at half its six passes."""
+    one_hot = jax.nn.one_hot(labels, k, dtype=jnp.bool_) & (mb > 0)[:, None]
+    one_pass = partial(
+        jnp.matmul, one_hot.T.astype(jnp.bfloat16), preferred_element_type=jnp.float32
+    )
+    hi, mid, lo = split3_bf16(xb * mb[:, None])
+    sums = (one_pass(hi) + one_pass(mid)) + one_pass(lo)
+    counts = jnp.sum(jnp.where(one_hot, mb[:, None], 0.0), axis=0)
+    return sums, counts
+
+
 def _assign_and_accumulate(xb, mb, x2b, centers, k, dot):
     """Block-local assignment + sufficient stats: (sums (k,d), counts (k),
     cost) for one row block — everything stays block-sized, so XLA fuses
     the distance GEMM, argmin, and one-hot matmul without ever writing an
-    (n, k) array to HBM."""
+    (n, k) array to HBM.
+
+    The update adapts to what it can see at trace time. float32 rows under
+    the full-precision policy (``f32`` / ``highest``): the one-hot operand
+    is exact in bfloat16, so the sums take three bf16 passes
+    (:func:`_onehot_sums_split3`), not the six ``HIGHEST`` spends, three
+    of them on zero pieces. Anything else (float64 rows, ``high``,
+    ``bf16x3``, ``bf16``, ``default``, test modes): ``dot(one_hot.T, xb)``
+    as before, bit for bit. Counters ``kmeans.update.split3`` /
+    ``kmeans.update.matmul`` count the programs TRACED with each."""
     d2 = _sq_dists(xb, centers, x2b, dot)
     labels = jnp.argmin(d2, axis=1)
     min_d2 = jnp.min(d2, axis=1)
-    one_hot = jax.nn.one_hot(labels, k, dtype=xb.dtype) * mb[:, None]
-    sums = dot(one_hot.T, xb)  # (k, d) on MXU
-    counts = jnp.sum(one_hot, axis=0)
+    if xb.dtype == jnp.float32 and is_highest_matmul(dot):
+        bump_counter("kmeans.update.split3")
+        sums, counts = _onehot_sums_split3(labels, k, mb, xb)
+    else:
+        bump_counter("kmeans.update.matmul")
+        one_hot = jax.nn.one_hot(labels, k, dtype=xb.dtype) * mb[:, None]
+        sums = dot(one_hot.T, xb)  # (k, d) on MXU
+        counts = jnp.sum(one_hot, axis=0)
     cost = jnp.sum(min_d2 * mb)
     return sums, counts, cost
 
@@ -291,7 +332,7 @@ def lloyd_resumable(
     from spark_rapids_ml_tpu.observability.costs import ledgered_call
     from spark_rapids_ml_tpu.observability.metrics import observe_segment_seconds
     from spark_rapids_ml_tpu.robustness.faults import fault_point
-    from spark_rapids_ml_tpu.utils.tracing import TraceColor, TraceRange, bump_counter
+    from spark_rapids_ml_tpu.utils.tracing import TraceColor, TraceRange
 
     n = x.shape[0]
     k = init_centers.shape[0]

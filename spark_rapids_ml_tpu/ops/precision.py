@@ -18,6 +18,11 @@ gives every GEMM-dominated op family ONE policy chokepoint:
               tolerance-insensitive serving/predict paths only.
               Documented bound: max rel err ≤ 3e-2 vs f32.
 
+An operand that is exact in one bf16 piece (a one-hot matrix) needs
+THREE passes for the full f32 product, not six: :func:`split3_bf16`
+splits the other operand exactly and each piece is one pass
+(``ops/kmeans.py``'s centre update).
+
 The hi/lo parts are bf16-representable values carried in f32
 containers, so single-pass dots on the parts are EXACT products on
 both the MXU and CPU — the compensated result is backend-consistent,
@@ -109,6 +114,41 @@ def split_hi_lo(a):
     constants on compensated paths must stay finite."""
     hi = a.astype(jnp.bfloat16).astype(a.dtype)
     return hi, a - hi
+
+
+def split3_bf16(a):
+    """Exact three-piece bfloat16 split of a float32 array: returns
+    ``(hi, mid, lo)`` with dtype bfloat16 and ``(hi + mid) + lo == a`` bit
+    for bit in float32 (8 + 8 + 8 mantissa bits, each residual an exact
+    float32 subtraction). Built on ``lax.reduce_precision``, which the TPU
+    compiler may not simplify away as it does the ``astype`` round trip
+    of :func:`split_hi_lo` (PERF.md 7.9); the pieces ARE bfloat16, so a
+    dot on them is one MXU pass whatever ``precision`` says. A product
+    whose other operand is exact in one bfloat16 piece (a one-hot matrix)
+    therefore needs THREE passes for the full float32 result, not
+    HIGHEST's six. Finite inputs only, as :func:`split_hi_lo`; under
+    |a| = 2^-103 (1e-31) the ``lo`` piece is subnormal, which a TPU
+    flushes to zero."""
+    hi = jax.lax.reduce_precision(a, 8, 7)
+    r = a - hi
+    mid = jax.lax.reduce_precision(r, 8, 7)
+    lo = r - mid
+    bf16 = jnp.bfloat16
+    return hi.astype(bf16), mid.astype(bf16), lo.astype(bf16)
+
+
+def is_highest_matmul(dot) -> bool:
+    """True where ``dot`` is the plain ``jnp.matmul`` at
+    ``Precision.HIGHEST`` that :func:`make_dot` returns for ``f32`` /
+    ``highest`` (and :func:`as_dot` for the bare enum): what a caller
+    can observe of the policy at trace time when it wants to spend fewer
+    passes on an operand it knows to be exact in bfloat16."""
+    return (
+        isinstance(dot, partial)
+        and dot.func is jnp.matmul
+        and not dot.args
+        and dot.keywords == {"precision": jax.lax.Precision.HIGHEST}
+    )
 
 
 def _dot_bf16x3(a, b):

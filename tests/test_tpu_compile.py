@@ -135,6 +135,38 @@ class TestKMeansKernels:
             pk.assign_stats_packed(xt, centers, block_n=8064, interpret=False)
 
 
+class TestWideKMeansUpdate:
+    """The XLA Lloyd fit at the benchmark cell's shape (500,000 x 3000
+    float32, k = 1000, ``highest``): the centre update as three bf16
+    passes on an exact split of the rows (ops/kmeans.py)."""
+
+    def test_three_convolutions_with_fused_producers_and_no_row_sized_temporary(self, v5e):
+        import re
+
+        from spark_rapids_ml_tpu.ops import kmeans as km
+
+        n, d, k = 500_000, 3000, 1000
+        compiled = km.lloyd.lower(
+            _f32((n, d), v5e), _f32((n,), v5e), _f32((k, d), v5e),
+            max_iter=30, tol=1e-20, precision="highest",
+        ).compile()
+        text = compiled.as_text()
+        # The update is three convolutions over the row axis, and the
+        # split's roundings are kept (hi; hi, mid; hi, mid -> lo), not
+        # simplified away as an astype round trip is.
+        passes = re.findall(r"convolution\([^)]*\), dim_labels=fb_io->bf", text)
+        assert len(passes) == 3, passes
+        assert text.count(" reduce-precision(") >= 5
+        # Each piece is made inside its convolution's fusion: three
+        # written-out bfloat16 pieces would be 9 GB of temporaries beside
+        # the 6 GB of rows (the compiler is free to choose the rows'
+        # layout here, so not even its relayout copy is counted).
+        stats = compiled.memory_analysis()
+        assert stats.temp_size_in_bytes < 1 << 30, stats
+        # The distance GEMM stays the one HIGHEST product it was.
+        assert "operand_precision={highest,highest}" in text
+
+
 class TestUMAPTailKernel:
     def test_tail_accumulate_50k_x_15_x_2(self, v5e):
         from spark_rapids_ml_tpu.ops.pallas import umap as pu
