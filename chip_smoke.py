@@ -379,8 +379,8 @@ def run_host_fit(key, sz, eig, rehearse: bool):
     # float64 rows, x64 off: precision "auto" must take the double-double
     # route (ops/linalg.py::resolve_precision), seen here by a spy rather
     # than inferred from the error. Column means ~1e3 over unit spread; the
-    # emulation's covariance error (~2e-7 absolute, benchmarks/
-    # precision_sweep.py) over planted gaps >= 0.36 moves a vector ~1e-6.
+    # emulation's covariance error (~2e-7 absolute against the fp64
+    # oracle) over planted gaps >= 0.36 moves a vector ~1e-6.
     n64, d64 = sz["f64_rows"], sz["f64_cols"]
     x64 = np.asarray(generate(make_pca_rows, k2, n64, d64), dtype=np.float64)
     x64 += 1e3 * (1.0 + np.arange(d64) / d64)

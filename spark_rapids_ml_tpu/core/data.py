@@ -145,7 +145,8 @@ def is_device_array(data: Any) -> bool:
     coercion, whole fit as one XLA program). numpy arrays are NOT device
     arrays — they take the partition path. This is the input mode the
     reference cannot express (every JNI call copies host arrays,
-    rapidsml_jni.cu:112,179) and the one `bench.py` measures.
+    rapidsml_jni.cu:112,179), and the one the benchmark's
+    ``device_rows`` cells hand to ``fit`` (PERF.md section 4).
     """
     try:
         import jax

@@ -166,7 +166,7 @@ DEFAULT_COMPILE_CACHE_DIR = os.path.join(
 
 def configure_compile_cache(path: Optional[str] = None, *, force: bool = False):
     """Place jax's persistent compilation cache — the ONE function the
-    estimators' fit path, the serving path, ``bench.py`` and
+    estimators' fit path, the serving path, ``perfbench.run`` and
     ``chip_smoke.py`` all call. Idempotent; returns the active directory
     or None.
 

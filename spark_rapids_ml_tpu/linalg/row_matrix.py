@@ -60,8 +60,8 @@ def _pca_fit_device(x, k, center, precision, eigen_solver, eigen_iters):
     """The whole PCA fit as ONE XLA program on a device-resident array:
     column means + fused centered covariance GEMM + eigensolve + explained
     variance — nothing leaves the device, nothing re-traces across calls
-    (module-level jit keyed on shape + the static config). This is the
-    path `bench.py` measures through the public estimator API; the
+    (module-level jit keyed on shape + the static config). Its rate is
+    not measured on the chip (no cell: ROADMAP.md Reach 1); the
     reference's equivalent spans four JNI calls with host copies between
     each (RapidsRowMatrix.scala:149-257, rapidsml_jni.cu:159-356).
     """
@@ -191,13 +191,12 @@ class RowMatrix:
                     "merge); single-process mesh fits use "
                     "precision='highest'"
                 )
-        # Covariance kernel backend for the GEMM path. An earlier round's
-        # v5e run at 1M x 1024 f32/HIGHEST (unverified on today's chip)
-        # ordered them: XLA whole-array fusion 24.9
-        # TFLOP/s > pallas fused streaming 22.0 > XLA scan-blocked 21.7 —
-        # so "xla" is the default and "pallas" is the explicit choice when
-        # row blocking is required anyway (it keeps the centered tile and
-        # accumulator in VMEM, beating the scan path's HBM round-trip).
+        # Covariance kernel backend for the GEMM path. The default "xla"
+        # (whole-array fusion) over "pallas" (fused streaming: the
+        # centered tile and accumulator stay in VMEM) and the XLA
+        # scan-blocked path predates the chip and is not measured on it:
+        # ROADMAP.md Design 6 / Design 14 (the cell pca_3000.host_parts
+        # runs the default; no cell runs the kernel).
         if backend == "pallas":
             # The explicit kernel choice must never be silently dropped:
             # only the materialized single-device GEMM route consults it.
@@ -496,8 +495,8 @@ class RowMatrix:
         pass, one block resident at a time (shifted accumulation). Records
         the shape discovered during the pass. With a mesh, each block is
         row-sharded over the data axis and the Gram accumulates replicated
-        on device (one psum per block over ICI) — the north-star streamed
-        deployment loop (BASELINE config 5)."""
+        on device (one psum per block over ICI) — the streamed
+        deployment loop (no cell yet: ROADMAP.md Reach 5)."""
         blocks = iter_stream_blocks(self._stream)
         if self.mesh is not None:
             if jax.process_count() > 1:

@@ -8,23 +8,21 @@ with this param surface: ``k``, ``algorithm`` (default "ivfflat"),
 ``ops/ann.py`` — see its docstring for the dense-tensor redesign of cuML's
 inverted lists), ``brute`` (exact, delegates to ``ops/knn.py``), and
 ``brute_approx`` (dense MXU scoring + the TPU-native hardware approximate
-top-k, ``lax.approx_min_k``). The TPU-first result of an earlier round's v5e run
-(benchmarks/config7_ann_search.py; unverified on today's chip): at 1M items × 96 dims, ``brute_approx`` answers 10k queries
-~3.9× faster than ivfflat at ~0.997 recall — TPU gathers are scalarized
-while dense GEMMs ride the systolic array, so the inverted-list
-structure that wins on GPUs loses here at resident scales. Under a mesh,
+top-k, ``lax.approx_min_k``). The TPU-first design: TPU gathers are
+scalarized while dense GEMMs ride the systolic array, so ``brute_approx``
+is the expected winner over the inverted lists at resident scales. That
+crossover predates the chip and is not measured on it (ROADMAP.md Reach 9
+is its cell; Design 14). Under a mesh,
 ``brute_approx`` runs the hardware per-shard top-k with an exact
 cross-shard merge (``ops/knn.knn_sharded(approx=True)``).
 
-BEYOND single-chip HBM the choice is measured, not assumed
-(benchmarks/config8_ann_beyond_hbm.py): a re-iterable block source fits a STREAMED brute index
-(``ops/knn.knn_host_streamed`` — running top-k merge, capacity bounded
-by the source). The measured crossover is effectively zero: the
-compressed resident alternative (``ivfpq`` — the only structure whose
-residency shrinks relative to raw items) is so gather-bound on TPU
-(~78 q/s at 0.16 recall vs 22.4k q/s streamed-device at 1M×128) that
-~20 MB/s of source bandwidth already beats it. The TPU-native
-beyond-HBM recipe is therefore streaming (or sharding items across
+BEYOND single-chip HBM a re-iterable block source fits a STREAMED brute
+index (``ops/knn.knn_host_streamed`` — running top-k merge, capacity
+bounded by the source). The compressed resident alternative (``ivfpq`` —
+the only structure whose residency shrinks relative to raw items) is
+gather-bound on TPU; where streaming overtakes it is not measured on the
+chip (ROADMAP.md Reach 5, Design 14). The TPU-native beyond-HBM recipe
+is taken to be streaming (or sharding items across
 chips/executors — ``knn_sharded`` / the adapter's
 ``setIndexMode("sharded")``); ``ivfpq``/``ivfflat`` remain for API
 parity with the cuML lineage, not as the scale path.
@@ -222,9 +220,8 @@ class ApproximateNearestNeighbors(_ANNParams, Estimator, MLReadable):
         (``brute``/``brute_approx`` only): items never materialize — each
         search streams blocks through the running top-k merge, so item
         capacity is bounded by the source, not HBM.
-        Inverted lists need the resident (compressed) index;
-        benchmarks/config8_ann_beyond_hbm.py measures the
-        streaming-vs-ivfpq crossover."""
+        Inverted lists need the resident (compressed) index; the
+        streaming-vs-ivfpq crossover is not measured on the chip."""
         from spark_rapids_ml_tpu.core.serving import configure_compile_cache
 
         configure_compile_cache()

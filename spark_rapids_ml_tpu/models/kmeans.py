@@ -3,8 +3,8 @@
 Param surface mirrors ``org.apache.spark.ml.clustering.KMeans``:
 ``k``, ``initMode`` ("k-means||" or "random"), ``maxIter``, ``tol``,
 ``seed``, ``distanceMeasure`` ("euclidean" | "cosine"), ``featuresCol``,
-``predictionCol``. This is a beyond-the-reference capability (benchmark
-config 3); the reference repo ships only PCA, so the oracle for tests is
+``predictionCol``. This is a beyond-the-reference capability (its cell:
+kmeans_3000_k1000.device_rows); the reference repo ships only PCA, so the oracle for tests is
 scipy/numpy Lloyd rather than a reference file.
 
 "k-means||" routes to on-device k-means++ (the sequential D^2 sampler is
@@ -425,10 +425,11 @@ class KMeans(_KMeansParams, Estimator, MLReadable):
         kernel streams no mask — padding is corrected in closed form) and
         a single-device layout; explicit requests that can't be honored
         raise rather than silently fall back. "auto" takes fused for
-        eligible large fits (an earlier round's v5e shoot-out, unverified
-        on today's chip: never slower, up to ~12% faster at matched
-        precision) and keeps
-        the XLA path for small ones (no extra transposed copy/compile)."""
+        eligible large fits and keeps the XLA path for small ones (no
+        extra transposed copy/compile): a route that predates the chip
+        and is not measured on it. The benchmark's KMeans cell lies on
+        the XLA side (d=3000 is not fused_feasible); the narrow cell of
+        ROADMAP.md Reach 10 decides it (Design 14)."""
         from spark_rapids_ml_tpu.ops.pallas.kmeans import fused_feasible
 
         requested = self.getBackend()

@@ -5,8 +5,7 @@ Param surface mirrors ``org.apache.spark.ml.regression.LinearRegression``:
 ``regParam``, ``elasticNetParam`` (0 -> Ridge via the exact normal-equation
 solve; > 0 -> Lasso/elastic net via FISTA on the same sufficient
 statistics — solver="normal" rejects it, as in Spark), ``standardization``,
-``solver`` ("normal" | "auto"). Beyond-the-reference capability
-(benchmark config 4).
+``solver`` ("normal" | "auto"). Beyond-the-reference capability.
 """
 
 from __future__ import annotations

@@ -221,11 +221,11 @@ class PCA(_PCAParams, Estimator, MLReadable):
         return self
 
     def setCovarianceBackend(self, value: str) -> "PCA":
-        """Kernel backend for the covariance GEMM. An earlier round's v5e
-        run (unverified on today's chip) found "xla" (whole-array fusion)
-        fastest when the
-        dataset fits HBM; "pallas" fuses centering + accumulation in VMEM
-        and beats the XLA scan path when row-blocking is required."""
+        """Kernel backend for the covariance GEMM: "xla" (whole-array
+        fusion, the default) or "pallas" (centering + accumulation fused
+        in VMEM, for when row-blocking is required). The default predates
+        the chip; neither is measured against the other on it (ROADMAP.md
+        Design 6, Design 14)."""
         if value not in ("xla", "pallas"):
             raise ValueError(f"covarianceBackend must be xla|pallas, got {value!r}")
         self.set(self.covarianceBackend, value)

@@ -219,8 +219,8 @@ class _UMAPParams(Params):
 
     def setBuildAlgo(self, v: str):
         """``"brute_approx"`` builds the kNN graph with the hardware
-        approximate top-k (~0.995 recall, measured ~2.5× on the brute
-        search at 1M×96 — BASELINE config 7); UMAP's fuzzy graph is
+        approximate top-k (its gain on the brute search is not measured
+        on the chip); UMAP's fuzzy graph is
         robust to it, and cuML's spark UMAP likewise defaults to an
         approximate builder (nn_descent) at scale. ``"brute"`` (default)
         keeps the exact graph."""

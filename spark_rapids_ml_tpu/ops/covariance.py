@@ -193,8 +193,8 @@ def streaming_mean_and_covariance_mesh(
     blocks, mesh, center: bool = True, dtype=None, precision: str = "highest"
 ):
     """ONE-pass covariance over streamed host blocks, each block
-    row-sharded over the mesh data axis — the north-star deployment loop
-    (BASELINE config 5): stream from disk, shard each block over the
+    row-sharded over the mesh data axis — the streamed deployment loop
+    (no cell yet: ROADMAP.md Reach 5): stream from disk, shard each block over the
     chips, accumulate the replicated (d, d) Gram on device with one psum
     per block riding ICI. Host and per-device memory stay bounded by one
     block; the same shifted-accumulation algebra as the single-device

@@ -1,7 +1,7 @@
 """KMeans kernels — Lloyd iterations as MXU matmuls.
 
-Beyond-PCA capability (benchmark config 3: "KMeans k=100 on NYC-Taxi 20M
-rows — RAFT kmeans -> XLA"). The reference repo itself has no kmeans; the
+Beyond-PCA capability (RAFT kmeans -> XLA; the benchmark cell is
+kmeans_3000_k1000.device_rows, PERF.md). The reference repo itself has no kmeans; the
 RAPIDS family's implementation is RAFT's fused distance kernel + cuBLAS. The
 TPU formulation keeps everything on the MXU:
 
@@ -210,10 +210,12 @@ def lloyd(
     centers stay unit-normalized every iteration (input rows must already be
     unit-normalized), so the returned cost is the cosine-distance potential.
 
-    ``block_rows``: None = auto. The unblocked step is the fast path —
-    measured 373M vs 280M row-iters/s at 20M x 16, k=100 on v5e, because
-    the distance reduction fuses into the GEMM epilogue and a scan only
-    adds sequential dependencies. Blocking exists for MEMORY: once the
+    ``block_rows``: None = auto. The unblocked step is taken as the fast
+    path because the distance reduction fuses into the GEMM epilogue and a
+    scan only adds sequential dependencies: a choice that predates the
+    chip (the cell kmeans_3000_k1000.device_rows runs unblocked; blocked
+    against it is not measured: ROADMAP.md Design 13 / Design 14).
+    Blocking exists for MEMORY: once the
     (n, k) one-hot temporary approaches HBM capacity (~9 GB here), rows
     stream through a scan in blocks sized to ~1 GB of temporaries.
 
@@ -616,8 +618,8 @@ def random_init(x: jax.Array, mask: jax.Array, key: jax.Array, k: int,
     no mesh padding, no weightCol) swaps the exact top-k for the
     hardware ``approx_max_k``: the scores are iid noise, so which of
     them surface is a uniform random distinct sample either way, and
-    the approximate reduction skips the full sort network (measured
-    ~100 ms of pure seeding tax at 20M rows; exact on CPU). With a
+    the approximate reduction skips the full sort network (what that
+    saves is not measured on the chip; exact on CPU). With a
     REAL mask the exact top-k is required — the approximate per-tile
     reduction could let -inf (masked) scores survive when valid rows
     are few or concentrated."""

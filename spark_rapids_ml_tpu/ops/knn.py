@@ -38,9 +38,9 @@ def _block_sq_distances(q: jax.Array, xb: jax.Array, q_sq: jax.Array, prec) -> j
 
 
 def _auto_block_items(nq: int, n_items: int) -> int:
-    """Item-block size: measured throughput at config 7's shape is flat
-    beyond 65536 rows (the knee — 32.5k q/s at 64k vs 32.2k at 256k), so
-    cap there; under the cap a ~2 GiB f32 (nq, block) buffer budget
+    """Item-block size: capped at 65536 rows (a knee chosen before the
+    chip, not measured on it: ROADMAP.md Reach 9, Design 14); under the
+    cap a ~2 GiB f32 (nq, block) buffer budget
     shrinks blocks for large query batches (memory safety), floored at
     1024 so the scan stays coarse."""
     return min(n_items, 65536, max(1024, (1 << 29) // max(nq, 1)))
@@ -67,12 +67,13 @@ def knn_sq_euclidean(
     ``approx=True`` replaces the per-block exact ``top_k`` with the
     TPU-native ``lax.approx_min_k`` (the PartialReduce op the hardware
     has a fast path for; exact on CPU) while the cross-block candidate
-    merge stays exact. This is the TPU-first ANN finding
-    (benchmarks/config7_ann_search.py): a dense MXU scoring pass +
-    hardware approximate top-k beats the inverted-list gathers of
-    ``ops/ann.ivf_search`` at 1M×96 with ~0.995 recall, because TPU
-    gathers are scalarized while the distance GEMM rides the systolic
-    array. ``block_items=None`` picks the block from the query count
+    merge stays exact. The TPU-first ANN design: a dense MXU scoring
+    pass + hardware approximate top-k in place of the inverted-list
+    gathers of ``ops/ann.ivf_search``, because TPU gathers are scalarized
+    while the distance GEMM rides the systolic array. The crossover
+    between the two predates the chip and is not measured on it
+    (ROADMAP.md Reach 9 is its cell; Design 14).
+    ``block_items=None`` picks the block from the query count
     (:func:`_auto_block_items` — the estimator path reaches benchmark-
     grade blocks without a knob); pass an explicit value to pin it.
     """
@@ -223,8 +224,8 @@ def knn_host_streamed(
     by the SOURCE, not HBM (the regime the
     models/approximate_nearest_neighbors docstring used to hand to
     inverted lists on faith). Whether streaming beats a compressed
-    resident index (ivfpq) depends on source bandwidth;
-    benchmarks/config8_ann_beyond_hbm.py measures the crossover.
+    resident index (ivfpq) depends on source bandwidth; the crossover is
+    not measured on the chip (ROADMAP.md Reach 5, Design 14).
 
     Equal-size blocks reuse one compiled merge; a ragged final block
     compiles once more.

@@ -1,7 +1,7 @@
 """Linear model kernels — normal-equation sufficient statistics on the MXU.
 
-Beyond-PCA capability (benchmark config 4: "LinearRegression / Ridge on
-HIGGS 11M x 28 — normal-equation GEMM path"). The sufficient statistics
+Beyond-PCA capability (the normal-equation GEMM path; no benchmark cell
+yet: ROADMAP.md Reach 9). The sufficient statistics
 (X^T X, X^T y, column sums) are one fused jitted computation — the same
 masked/shardable shape as the covariance kernel, so the distributed story is
 identical: row-shard x/y over the mesh data axis and XLA inserts the psum.

@@ -66,8 +66,8 @@ def binary_auc_device(y: jax.Array, s: jax.Array, metric: str = "areaUnderROC"):
     evaluator (one curve point per distinct threshold, trapezoid
     through ties).
 
-    Two sort-attack ideas (timed on a CPU only; their chip speed is not
-    measured): (1) instead of ``argsort`` + label/score gathers, sort
+    Two sort-attack ideas (chosen before the chip and not measured on
+    it: ROADMAP.md Design 14; no evaluator cell, Reach 12): (1) instead of ``argsort`` + label/score gathers, sort
     the label ALONG WITH the score key (`lax.sort` with ``num_keys=1``)
     — the n-element random-access gathers disappear and the permutation
     is never materialized; (2) instead of ``nonzero``-packing the
@@ -83,8 +83,8 @@ def binary_auc_device(y: jax.Array, s: jax.Array, metric: str = "areaUnderROC"):
 
     ledger = costs.active()
     if ledger is not None:
-        # Evaluator programs join the cost-ledger gate (CI diffs their
-        # analyzed flops/bytes against benchmarks/cost_baseline.json).
+        # Evaluator programs are ledgered like the fit families
+        # (tests/test_costs.py holds the entry).
         import time
 
         lkey = costs.record_fallback(
@@ -105,7 +105,8 @@ def binary_auc_device(y: jax.Array, s: jax.Array, metric: str = "areaUnderROC"):
 def _binary_auc_jit(y: jax.Array, s: jax.Array, metric: str = "areaUnderROC"):
     n = s.shape[0]
     if jax.config.jax_enable_x64 and s.dtype == jnp.float32:
-        # Key-packing attack (5.4x on a CPU; not measured on a chip): fold
+        # Key-packing attack (predates the chip, not measured on it:
+        # ROADMAP.md Design 14): fold
         # the f32 score through the standard monotone bit transform,
         # append the label as bit 0 of a uint64, and run ONE one-operand
         # sort. Tie groups are exact — the full 32 key bits survive, and

@@ -284,9 +284,9 @@ def assign_stats_packed(
     argmin) at 1/P the tile count. Same contract as
     :func:`assign_stats_fused` (raw stats INCLUDING padding rows).
 
-    The tile-count win is a TPU systolic-array property and is NOT
-    measured: the only timing behind this kernel is a CPU GEMM-shape proxy
-    (benchmarks/config17_kmeans_packed.py), which is no chip speed.
+    The tile-count win is a TPU systolic-array property and is not
+    measured on the chip: the kernel and its auto route predate it
+    (ROADMAP.md Reach 10, Design 14).
 
     What the chip's compiler accepts (found compile-only for a described
     v5e, then run on the chip by ``chip_smoke.py``): the packed array's

@@ -3,9 +3,8 @@
 The synchronous UMAP epoch (ops.umap._make_epoch_fn) applies every edge's
 attractive gradient twice: once to the head (a DENSE (n, k, dim) sum — free)
 and once to the tail (``zeros.at[dst].add(g)`` — a true scatter over random
-indices). XLA lowers that scatter element-serialized, and it measured ~70%
-of the whole SGD wall at config 13 (10.9 ms/epoch of a
-15.6 ms epoch).
+indices). XLA lowers that scatter element-serialized; its share of the
+epoch is not measured on the chip (no UMAP cell: ROADMAP.md Reach 9).
 
 The edge list is STATIC per fit, so the randomness can be paid ONCE on the
 host instead of every epoch on the device: sort the E = n*k edges by tail

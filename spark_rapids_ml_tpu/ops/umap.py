@@ -170,15 +170,14 @@ def optimize_layout(
     train points stay put; ``move_other=False`` then skips the tail
     update.
 
-    TPU layout (r4, measured 97% of the UMAP fit wall before): the edge
+    TPU layout (r4): the edge
     list is EXACTLY (n heads x k neighbors), so every head-side access is
     STRUCTURED — the head "gather" is a broadcast of y and the head
     "scatter" is a dense (n, k, ...) sum over k — leaving only the
     genuinely random accesses on the slow scalarized path.
 
     Negative sampling (r5): ``neg_pool > 0`` (default) replaces the
-    E * neg_rate per-edge random gathers — measured 96% of the fit wall
-    in r4 (BASELINE config 13) — with ONE shared pool of ``neg_pool``
+    E * neg_rate per-edge random gathers with ONE shared pool of ``neg_pool``
     uniform draws per epoch. Repulsion of every head against the pool is
     dense algebra: squared distances via ``y @ pool.T`` (MXU GEMM) plus
     norm broadcasts, and because the per-sample coefficient (not the
@@ -235,12 +234,12 @@ def _make_epoch_fn(
         key, k_neg = jax.random.split(key)
         alpha = learning_rate * (1.0 - ep / n_epochs)
 
-        # Edge gathers stay in ROW form — measured on v5e (r5): splitting
-        # the (n, k, dim) gather into dim flat (n,) -> (n, k) lookups is
-        # 1.5x SLOWER (scalar gathers pay per element; the row gather
-        # amortizes index handling across the dim-wide row), the opposite
-        # of the forest per-class-gather lesson, whose tables are
-        # hundreds wide.
+        # Edge gathers stay in ROW form: splitting the (n, k, dim)
+        # gather into dim flat (n,) -> (n, k) lookups pays per element,
+        # where the row gather amortizes index handling across the
+        # dim-wide row (the opposite of the forest per-class-gather
+        # lesson, whose tables are hundreds wide). The choice predates
+        # the chip; not measured on it (no UMAP cell: ROADMAP.md Reach 9).
         yi = y[:, None, :]  # (n, 1, dim) — the head side is a broadcast
         ref_y = y if target is None else target
         yj = ref_y[dst]  # (n, k, dim)
@@ -451,8 +450,7 @@ def _sharded_layout_fn(
             key, k_neg = jax.random.split(key)
             alpha = learning_rate * (1.0 - ep / n_epochs)
             yh = lax.dynamic_slice_in_dim(y, row0, n_local)  # (n_local, dim)
-            # Row gather, as in the single-device epoch (r5 measured the
-            # component-split variant 1.5x SLOWER on v5e).
+            # Row gather, as in the single-device epoch (see there).
             yj = y[dst_b]  # (n_local, k, dim)
             diff = yh[:, None, :] - yj
             d2 = jnp.sum(diff * diff, axis=2)

@@ -1552,8 +1552,8 @@ if HAS_PYSPARK:  # pragma: no cover - no pyspark in the CI image
     class TpuApproximateNearestNeighbors(_TpuNeighborsBase):
         """ANN — the modern spark-rapids-ml ANN family. Algorithms pass
         through to the core model: ivfflat | ivfpq | brute |
-        brute_approx (the TPU-first hardware-top-k winner at
-        single-chip scales — benchmarks/config7_ann_search.py)."""
+        brute_approx (the TPU-first hardware top-k; its crossover
+        with the inverted lists is not measured on the chip)."""
 
         algorithm = Param(
             Params._dummy(), "algorithm",

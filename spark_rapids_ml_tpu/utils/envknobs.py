@@ -358,27 +358,6 @@ KNOBS: Dict[str, Knob] = _knob_table(
     Knob("TPUML_FLIGHT_DIR", "str", "ops-plane",
          "directory for flight-recorder dumps (default: the active "
          "TPUML_TELEMETRY_DIR, else tpuml-flight under the temp dir)"),
-    # benchmark shape overrides (benchmarks/ only)
-    Knob("TPUML_BENCH_ROWS", "int", "benchmarks",
-         "row-count override for serving benchmarks"),
-    Knob("TPUML_BENCH_COLS", "int", "benchmarks",
-         "feature-count override for serving benchmarks"),
-    Knob("TPUML_BENCH_K", "int", "benchmarks",
-         "output-dimension override for serving benchmarks"),
-    Knob("TPUML_BENCH_BLOCK", "int", "benchmarks",
-         "stream-block override for the serving benchmark"),
-    Knob("TPUML_BENCH_THREADS", "int", "benchmarks",
-         "client thread count for the server benchmark"),
-    Knob("TPUML_BENCH_REQUESTS", "int", "benchmarks",
-         "per-thread request count for the server benchmark"),
-    Knob("TPUML_BENCH_GANG_MEMBER", "choice", "benchmarks",
-         "1 marks a config20 process as a spawned gang member (internal "
-         "to the benchmark's self-spawn protocol)",
-         default="0", choices=("0", "1")),
-    Knob("TPUML_BENCH_GANG_CORES", "str", "benchmarks",
-         "comma-separated CPU core list a config20 gang member pins "
-         "itself to (holds per-member silicon constant across the "
-         "1->2-process sweep)"),
 )
 
 

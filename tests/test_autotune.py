@@ -315,11 +315,41 @@ class TestLadder:
         assert counter_value("compile.retrace") == 0
         assert tuner.peek_serving_bucket("lad.kern", 6, 3, bucket_rows(3)) == 3
         assert tuner.is_ladder_bucket(3)
-        # Bit-identical outputs across the ladder transition.
-        for out in cold + [grew, warm1, warm2]:
-            assert np.array_equal(out, cold[0])
+        # Bit-identical outputs WITHIN a program: the 8-row padded one
+        # (cold) and the exact 3-row one (grew and its cache hits).
+        assert np.array_equal(cold[1], cold[0])
+        assert np.array_equal(warm1, grew) and np.array_equal(warm2, grew)
+        # ACROSS the transition the two shapes are two XLA:CPU programs
+        # and may order the six-term float32 contraction differently
+        # (the last ulp moves). Padding does not reach the result: pad
+        # rows of any value leave the first three rows of the 8-row
+        # program bit for bit. Each sum is off by at most
+        # 6 * 2^-24 * sum|a_i b_i| (4e-7 of an O(1) sum), which is the
+        # atol; an entry that cancels has no tighter RELATIVE bound.
+        np.testing.assert_allclose(grew, cold[0], rtol=1e-6, atol=1e-6)
         # Cold sizes still round up through the pow-2 ladder.
         assert tuner.peek_serving_bucket("lad.kern", 6, 5, bucket_rows(5)) == 8
+
+    def test_ladder_cuts_padded_rows_in_the_ledger(self, tuner, rng):
+        """The count the retired closed-loop script took from the cost
+        ledger (rows x invocations per program): of ten 37-row requests
+        only those before the size proved hot (hot_min = 3) run the
+        64-row bucket; the rest run an exact 37-row program and pad
+        nothing. The committed rung is in the store, and on disk."""
+        import jax.numpy as jnp
+
+        w = jnp.asarray(rng.normal(size=(32, 8)).astype(np.float32))
+        probe = rng.normal(size=(37, 32)).astype(np.float32)
+        for _ in range(10):
+            serve_rows(_kernel, probe, (w,), name="pad.kern")
+        assert counter_value("autotune.ladder.grow") == 1
+        invocations = {
+            e.rows: e.invocations
+            for e in costs.active().entries() if e.family == "pad.kern"
+        }
+        assert invocations == {64: 2, 37: 8}
+        assert tuner.store.get("serving_ladder", "pad.kern|32")["value"] == [37]
+        assert os.path.exists(tuner.store.path)
 
     def test_ladder_decision_persists_and_reloads(self, tuner, tmp_path):
         for _ in range(3):

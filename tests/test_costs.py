@@ -160,6 +160,37 @@ class TestLedgerCapture:
         assert "where the FLOPs and bytes went" in str(rep)
         costs.configure(enable=False)
 
+    def test_umap_segment_and_device_auc_are_ledgered(self, ledger, rng, tmp_path, monkeypatch):
+        """The two families the retired cost-ledger scenario pinned and
+        no test did: a checkpointed UMAP layout records a `segment`
+        entry under `umap.layout.segment`, and the device AUC program
+        (both metrics) records under `metrics.binary_auc`; the whole
+        document validates."""
+        import jax.numpy as jnp
+
+        from spark_rapids_ml_tpu.manifold import UMAP
+        from spark_rapids_ml_tpu.ops.metrics import binary_auc_device
+
+        monkeypatch.setenv("TPUML_CHECKPOINT_EVERY", "5")
+        monkeypatch.setenv("TPUML_CHECKPOINT_DIR", str(tmp_path / "ck"))
+        monkeypatch.setenv("TPUML_CHECKPOINT_UMAP", "1")
+        xu = rng.normal(size=(128, 8)).astype(np.float32)
+        model = UMAP().setNNeighbors(5).setNEpochs(10).setSeed(1).fit(xu)
+        assert model.embedding.shape == (128, 2)
+        ys = (rng.uniform(size=512) < 0.5).astype(np.float32)
+        ss = (ys * 0.4 + rng.normal(size=512)).astype(np.float32)
+        for metric in ("areaUnderROC", "areaUnderPR"):
+            auc = float(binary_auc_device(jnp.asarray(ys), jnp.asarray(ss), metric=metric))
+            assert 0.0 < auc < 1.0
+        doc = costs.ledger_snapshot()
+        assert validate_ledger(doc) == []
+        by_family = {}
+        for e in doc["entries"]:
+            by_family.setdefault(e["family"], []).append(e)
+        assert {e["kind"] for e in by_family["umap.layout.segment"]} == {"segment"}
+        assert sum(e["invocations"] for e in by_family["umap.layout.segment"]) >= 2
+        assert len(by_family["metrics.binary_auc"]) == 2
+
     def test_fallback_entry_for_sharded_weights(self, ledger, rng):
         """Mesh-sharded weights route through the plain-jit fallback,
         which is ledgered from the LOWERING: cost analysis present,

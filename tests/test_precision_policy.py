@@ -80,6 +80,18 @@ def tuner(monkeypatch, tmp_path):
 
 
 @pytest.fixture
+def slower_candidates(monkeypatch):
+    """The probe's real results under FIXED walls, every candidate three
+    times the f32 reference: the gate's logic is under test, not the
+    load of a machine whose other workers share the clock."""
+    def fixed(a, b, mode, repeats=3):
+        out = np.asarray(prec._probe_gemm(a, b, mode))
+        return out, (1e-3 if mode == "f32" else 3e-3)
+
+    monkeypatch.setattr(prec, "_time_probe", fixed)
+
+
+@pytest.fixture
 def off(monkeypatch):
     monkeypatch.delenv("TPUML_AUTOTUNE", raising=False)
     monkeypatch.delenv("TPUML_PRECISION", raising=False)
@@ -451,11 +463,12 @@ class TestAutotunerGate:
     def test_off_tuner_never_probes(self, off):
         assert tune_precision("kmeans") is None
 
-    def test_cpu_probe_keeps_f32_and_memoizes(self, tuner, monkeypatch):
-        """On CPU the compensated mode is measurably SLOWER than native
-        f32, so the gate must keep the f32 incumbent — this is the
-        mechanism that makes default-mode CI runs bit-identical. The
-        decision memoizes: the second resolution never re-probes."""
+    def test_cpu_probe_keeps_f32_and_memoizes(self, tuner, slower_candidates, monkeypatch):
+        """Where the compensated mode probes SLOWER than native f32 (a
+        CPU: three real f32 GEMMs for one), the gate must keep the f32
+        incumbent — this is the mechanism that makes default-mode CI
+        runs bit-identical. The decision memoizes: the second resolution
+        never re-probes."""
         mode = tune_precision("kmeans", tuner=tuner)
         assert mode == "f32"
         decision = tuner.store.get("precision_mode", "kmeans")
@@ -466,6 +479,19 @@ class TestAutotunerGate:
 
         monkeypatch.setattr(prec, "_time_probe", boom)
         assert tune_precision("kmeans", tuner=tuner) == "f32"
+
+    def test_slower_candidates_keep_f32_in_every_family(self, tuner, slower_candidates):
+        """Every family through the gate against one live store (what
+        the retired precision sweep asserted with the tuner armed): each
+        keeps the f32 incumbent and records its candidates rejected as
+        regressions, `serving` both of its two."""
+        decisions = {fam: tune_precision(fam, tuner=tuner) for fam in FAMILIES}
+        assert decisions == dict.fromkeys(FAMILIES, "f32")
+        for fam in FAMILIES:
+            rejected = tuner.store.get("precision_mode", fam)["rejected"]
+            assert [r["reason"] for r in rejected] == ["regression"] * len(rejected)
+            want = ["bf16x3", "bf16"] if fam == "serving" else ["bf16x3"]
+            assert [r["value"] for r in rejected] == want
 
     def test_gate_rejects_seeded_parity_violating_mode(self, tuner):
         """A fast-but-wrong GEMM (plain bf16 math sold with a 1e-7
@@ -506,7 +532,7 @@ class TestAutotunerGate:
         assert entry["rejected"][0]["reason"] == "parity"
         assert counter_value("autotune.revert") == before + 1
 
-    def test_resolve_policy_consults_committed_decision(self, tuner):
+    def test_resolve_policy_consults_committed_decision(self, tuner, slower_candidates):
         """With the tuner armed and no explicit/env setting, resolution
         goes through the gate and lands on the committed mode."""
         assert resolve_policy("logistic") == "f32"

@@ -9,8 +9,11 @@ suppression, baseline round-trips) lives in tests/test_tpuml_lint.py."""
 
 import importlib
 import pkgutil
+import re
 import sys
 from pathlib import Path
+
+import pytest
 
 REPO = Path(__file__).resolve().parents[1]
 if str(REPO) not in sys.path:
@@ -88,3 +91,55 @@ def test_legacy_entry_point_still_works(tmp_path):
     )
     assert r.returncode == 1, r.stdout + r.stderr
     assert "missing-docstring" in r.stdout
+
+
+#: Directories whose files the documents cite by path. `benchmarks` is
+#: listed so that a citation of the retired stack (PR 32) fails: it is
+#: no directory of this tree.
+_DOC_PATH_PREFIXES = tuple(
+    f"{directory}/" for directory in (
+        "spark_rapids_ml_tpu", "tools", "tests", "perfbench", "benchmarks",
+        "docs", "native",
+    )
+)
+
+
+def _cited_paths(text):
+    """The back-quoted paths a document cites: a token under one of the
+    tree's directories, or a bare ``*.py`` name. A ``::name`` suffix and
+    a ``:line`` suffix are cut; commands (a space) and placeholders
+    (``<``, ``*``, ``…``) are not paths."""
+    text = re.sub(r"```.*?```", "", text, flags=re.S)  # fenced blocks
+    for m in re.finditer(r"`([^`\n]+)`", text):
+        token = m.group(1)
+        if " " in token or any(c in token for c in "<*…"):
+            continue
+        token = re.sub(r":[\d,:–-]+$", "", token.split("::")[0])
+        if token.startswith(_DOC_PATH_PREFIXES) or re.fullmatch(r"\w+\.py", token):
+            yield token
+
+
+@pytest.mark.parametrize(
+    "doc",
+    ["README.md", "docs/PARITY.md", "CONTRIBUTING.md",
+     ".claude/skills/verify/SKILL.md"],
+)
+def test_documents_cite_files_that_exist(doc):
+    """A document that names a file names one the tree has: the day a
+    script goes, every sentence that pointed at it fails here. A path
+    under a directory is looked up as written; a bare ``name.py`` is a
+    top-level file or the short name of a module somewhere under the
+    cited directories."""
+    short_names = {
+        f.name
+        for prefix in _DOC_PATH_PREFIXES
+        for f in (REPO / prefix).rglob("*.py")
+    }
+    missing = sorted({
+        token for token in _cited_paths((REPO / doc).read_text())
+        if not (
+            (REPO / token).exists()
+            or ("/" not in token and token in short_names)
+        )
+    })
+    assert not missing, f"{doc} cites files that do not exist: {missing}"

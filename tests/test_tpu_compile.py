@@ -194,8 +194,8 @@ class TestCovariance:
         assert _has_kernel(compiled)
 
     def test_fused_pca_fit_program_1m_x_1024(self, v5e):
-        """The whole device-resident fit (``bench.py``'s program) at the
-        headline shape: must compile and fit one chip's HBM beside its
+        """The whole device-resident fit (``_pca_fit_device``, what
+        ``PCA().fit(jax_array)`` runs) at 1M x 1024: must compile and fit one chip's HBM beside its
         4.1 GB input. ``eigenSolver="topk"`` keeps this to seconds — the
         default "auto" adds the full (d, d) eigensolver, a minute of
         compile that ``chip_smoke.py`` pays on the chip instead."""

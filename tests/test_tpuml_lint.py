@@ -383,6 +383,61 @@ class TestKnobRegistry:
         assert [f.rule for f in findings] == ["knob-undocumented"]
         assert "TPUML_ORPHAN_KNOB" in findings[0].message  # tpuml: noqa[knob-unregistered]
 
+    def _sweep_unread(self, repo_root):
+        findings, _ = tl.run_paths(
+            repo_root,
+            [repo_root / "spark_rapids_ml_tpu", repo_root / "tests"],
+            tl.CHECKERS, tl.REPO_CHECKERS,
+        )
+        return [f for f in findings if f.rule == "knob-unread"]
+
+    def test_unread_knob_flagged(self, mini_repo):
+        """A registered name that only the registry, a docstring and a
+        test hold is read by nothing: the finding the eight
+        benchmark-shape entries drew the day their scripts went (PR 32)."""
+        pkg = mini_repo / "spark_rapids_ml_tpu"
+        (pkg / "reader.py").write_text(textwrap.dedent('''
+            """Reads one knob; TPUML_ORPHAN_KNOB is prose here."""
+            from spark_rapids_ml_tpu.utils.envknobs import env_int
+
+            ROWS = env_int("TPUML_GOOD_KNOB", 1)
+        '''))
+        (mini_repo / "tests").mkdir()
+        (mini_repo / "tests" / "test_x.py").write_text(textwrap.dedent('''
+            """A test that sets a knob reads nothing."""
+            NAME = "TPUML_ORPHAN_KNOB"
+        '''))
+        hits = self._sweep_unread(mini_repo)
+        assert len(hits) == 1 and "TPUML_ORPHAN_KNOB" in hits[0].message  # tpuml: noqa[knob-unregistered]
+        assert hits[0].path == "spark_rapids_ml_tpu/utils/envknobs.py"
+
+    def test_literal_and_constructed_reads_are_clean(self, mini_repo):
+        """A literal read and a constructed one (the
+        `f"TPUML_PRECISION_{family}"` spelling of ops/precision.py) both
+        count; and a run that did not sweep the registry judges
+        nothing."""
+        from tools.tpuml_lint.engine import RepoContext
+        from tools.tpuml_lint.knobs import check_repo
+
+        pkg = mini_repo / "spark_rapids_ml_tpu"
+        (pkg / "reader.py").write_text(textwrap.dedent('''
+            """Reads both knobs."""
+            from spark_rapids_ml_tpu.utils.envknobs import env_int
+
+            ROWS = env_int("TPUML_GOOD_KNOB", 1)
+
+
+            def per_family(family):
+                """One knob per family."""
+                return env_int(f"TPUML_ORPHAN_{family.upper()}", 0)
+        '''))
+        assert self._sweep_unread(mini_repo) == []
+        one_file = tl.run_paths(
+            mini_repo, [pkg / "observability"], tl.CHECKERS, tl.REPO_CHECKERS
+        )[0]
+        assert "knob-unread" not in rules_of(one_file)
+        assert "knob-unread" not in rules_of(check_repo(RepoContext(mini_repo)))
+
 
 # --- family (d): observability drift -----------------------------------
 

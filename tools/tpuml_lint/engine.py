@@ -185,6 +185,12 @@ class RepoContext:
         self.parity_text: Optional[str] = (
             parity.read_text() if parity.is_file() else None
         )
+        # Gathered by the knob checker as the sweep goes (knob-unread):
+        # the knob names and constructed-name prefixes the swept files
+        # hold, and whether the sweep took in the registry itself.
+        self.knob_reads: Set[str] = set()
+        self.knob_read_prefixes: Set[str] = set()
+        self.swept_registry = False
 
     def _parse_knobs(self) -> Optional[Dict[str, int]]:
         """{knob name: declaration line} from the ``KNOBS`` table —

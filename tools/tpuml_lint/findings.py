@@ -83,6 +83,9 @@ RULES: Dict[str, Rule] = {
         Rule("knob-undocumented", "knobs", ERROR,
              "every registered knob must appear in docs/PARITY.md's knob "
              "tables — docs that can drift are docs that will"),
+        Rule("knob-unread", "knobs", ERROR,
+             "a registered knob that no swept file outside tests/ reads is "
+             "an option of nothing: it goes with the last code that read it"),
         # (d) observability drift
         Rule("event-unknown-type", "drift", ERROR,
              "emit() with a record type events.py::SCHEMA does not declare "

@@ -1,6 +1,6 @@
 """Checker family (c): the ``TPUML_*`` environment-knob registry.
 
-Three rules close the loop between code, registry, and docs:
+Four rules close the loop between code, registry, and docs:
 
   - ``knob-raw-environ``: reading a ``TPUML_*`` variable through
     ``os.environ`` / ``os.getenv`` instead of the ``utils/envknobs``
@@ -14,6 +14,13 @@ Three rules close the loop between code, registry, and docs:
     ``envknobs.KNOBS``.
   - ``knob-undocumented`` (repo-level): a registered knob missing from
     the knob tables in ``docs/PARITY.md``.
+  - ``knob-unread`` (repo-level): a registered knob whose name no swept
+    file outside ``tests/`` holds, as a literal or through a
+    constructed name (``f"TPUML_PRECISION_{family}"`` reads every
+    registered ``TPUML_PRECISION_*``). An option nothing reads is dead
+    weight in the table and in the docs. Judged only when the sweep
+    took in the registry itself: a run over one file says nothing of
+    what the package reads.
 
 ``TPUML_TEST_*`` names are harness inputs, not runtime knobs, and are
 exempt everywhere; ``utils/envknobs.py`` itself is exempt from the raw-
@@ -30,6 +37,8 @@ from tools.tpuml_lint.engine import ModuleContext, RepoContext
 from tools.tpuml_lint.findings import Finding
 
 _KNOB_NAME = re.compile(r"^TPUML_[A-Z0-9]+(?:_[A-Z0-9]+)*$")
+#: The constant head of a constructed name: f"TPUML_PRECISION_{family}".
+_KNOB_PREFIX = re.compile(r"^TPUML_(?:[A-Z0-9]+_)+$")
 
 
 def _is_test_knob(name: str) -> bool:
@@ -105,39 +114,67 @@ def check(module: ModuleContext, repo: RepoContext) -> List[Finding]:
                     "env_str/env_choice)",
                 ))
 
-    # --- unregistered literals ---
-    if repo.knobs is not None and not is_accessor_layer:
-        for node in ast.walk(module.tree):
-            if not (
-                isinstance(node, ast.Constant)
-                and isinstance(node.value, str)
-                and id(node) not in module.docstring_nodes
+    # --- knob names this file holds: reads gathered for knob-unread
+    # (the tests set knobs, they read none), unregistered literals ---
+    if is_accessor_layer:
+        repo.swept_registry = True
+        return findings
+    counts_as_read = not rel.startswith("tests/")
+    for node in ast.walk(module.tree):
+        if isinstance(node, ast.JoinedStr):
+            head = node.values[0] if len(node.values) > 1 else None
+            if (
+                counts_as_read
+                and isinstance(head, ast.Constant)
+                and isinstance(head.value, str)
+                and _KNOB_PREFIX.match(head.value)
             ):
-                continue
-            name = node.value
-            if not _KNOB_NAME.match(name):
-                continue
-            if _is_test_knob(name) or name in repo.knobs:
-                continue
-            findings.append(Finding(
-                rel, node.lineno, node.col_offset, "knob-unregistered",
-                f"{name} has no Knob entry in envknobs.KNOBS — register "
-                "it (and document it in docs/PARITY.md)",
-            ))
+                repo.knob_read_prefixes.add(head.value)
+            continue
+        if not (
+            isinstance(node, ast.Constant)
+            and isinstance(node.value, str)
+            and id(node) not in module.docstring_nodes
+        ):
+            continue
+        name = node.value
+        if not _KNOB_NAME.match(name):
+            continue
+        if counts_as_read:
+            repo.knob_reads.add(name)
+        if repo.knobs is None or _is_test_knob(name) or name in repo.knobs:
+            continue
+        findings.append(Finding(
+            rel, node.lineno, node.col_offset, "knob-unregistered",
+            f"{name} has no Knob entry in envknobs.KNOBS — register "
+            "it (and document it in docs/PARITY.md)",
+        ))
     return findings
 
 
 def check_repo(repo: RepoContext) -> List[Finding]:
-    """Repo-level docs cross-check: every registered knob must appear in
-    PARITY.md's knob tables."""
+    """Repo-level cross-checks of the registry: every registered knob
+    appears in PARITY.md's knob tables, and (after a sweep that took in
+    the registry) some swept file outside the tests reads it."""
     findings: List[Finding] = []
-    if repo.knobs is None or repo.parity_text is None:
+    if repo.knobs is None:
         return findings
     for name, line in sorted(repo.knobs.items()):
-        if name not in repo.parity_text:
+        if repo.parity_text is not None and name not in repo.parity_text:
             findings.append(Finding(
                 RepoContext.ENVKNOBS_REL, line, 0, "knob-undocumented",
                 f"registered knob {name} is missing from "
                 f"{RepoContext.PARITY_REL}'s knob tables",
+            ))
+        if (
+            repo.swept_registry
+            and name not in repo.knob_reads
+            and not any(name.startswith(p) for p in repo.knob_read_prefixes)
+        ):
+            findings.append(Finding(
+                RepoContext.ENVKNOBS_REL, line, 0, "knob-unread",
+                f"registered knob {name} is read by no swept file outside "
+                "tests/ — delete the entry (and its docs/PARITY.md row) "
+                "with the last code that read it",
             ))
     return findings
