@@ -30,6 +30,8 @@ from spark_rapids_ml_tpu.core.ingest import dense_partitions, place_block
 from spark_rapids_ml_tpu.ops.covariance import (
     centered_gram,
     centered_gram_packed,
+    comoment_add_block,
+    comoment_init,
     streaming_mean_and_covariance,
     welford_add_block,
     welford_init,
@@ -46,7 +48,12 @@ from spark_rapids_ml_tpu.ops.eigh import (
 from spark_rapids_ml_tpu.ops.linalg import resolve_precision, triu_to_full
 from spark_rapids_ml_tpu.parallel.distributed_cov import distributed_mean_and_covariance
 from spark_rapids_ml_tpu.parallel.mesh import shard_rows_from_partitions
-from spark_rapids_ml_tpu.utils.tracing import StageRange, TraceColor, TraceRange
+from spark_rapids_ml_tpu.utils.tracing import (
+    StageRange,
+    TraceColor,
+    TraceRange,
+    bump_counter,
+)
 
 
 from functools import partial as _partial
@@ -293,6 +300,10 @@ class RowMatrix:
     # --- column stats (Statistics.colStats analogue, :156) ---
 
     def column_means(self) -> jnp.ndarray:
+        """Column means in a pass of their own. Of the host routes only the
+        packed route's no-native fallback still calls it; the GEMM route
+        takes its means in the pass that makes the Gram
+        (:meth:`_covariance_gemm`)."""
         if self._device_x is not None:
             with TraceRange("mean center", TraceColor.ORANGE):
                 return jnp.mean(self._device_x, axis=0)
@@ -314,11 +325,12 @@ class RowMatrix:
         """One host partition on the device in the compute dtype: the
         ``convert`` stage (the host conversion ``jnp.asarray(part,
         dtype=...)`` makes inside itself, taken out of it) and the
-        ``place`` stage round ``put``, the placement call of the pass.
-        The GEMM and pallas routes hold their partitions in the compute
-        dtype already (``__init__``), so ``convert`` hands the partition
-        on as it is; only the packed route's no-native fallback, whose
-        partitions are float64, still narrows here without x64."""
+        ``place`` stage round ``put``, the route's placement call. The
+        GEMM and pallas routes hold their partitions in the compute dtype
+        already (``__init__``), so ``convert`` hands the partition on as
+        it is, and they come here once a partition; only the packed
+        route's no-native fallback, whose partitions are float64, still
+        narrows here without x64, in each of its two passes."""
         with StageRange("convert"):
             host = np.asarray(part, dtype=self.dtype)
         return place_block(host, put)
@@ -349,12 +361,7 @@ class RowMatrix:
                 return self._covariance_packed()
             if self.precision == "dd":
                 return self._covariance_dd()
-            mean = (
-                self.column_means()
-                if self.mean_centering
-                else jnp.zeros(self.num_cols, dtype=self.dtype)
-            )
-            return self._covariance_gemm(mean)
+            return self._covariance_gemm()
 
     def _covariance_device(self) -> jnp.ndarray:
         """Covariance of a device-resident array — one fused XLA program,
@@ -393,34 +400,54 @@ class RowMatrix:
 
         return device_array_rows_on_mesh(x, self.mesh)
 
-    def _covariance_gemm(self, mean: jnp.ndarray) -> jnp.ndarray:
-        """Per-partition fused centered Gram + host partial sum (:168-201)."""
+    def _covariance_gemm(self) -> jnp.ndarray:
+        """One pass over the host partitions, each placed once (:168-201
+        needs the finished means first and makes two). Centred: every
+        partition goes into a (count, means, Gram) state on the device by
+        ``comoment_add_block``, its Gram centred on its own means.
+        Uncentred: the partitions' raw Grams, added up."""
         device = self._device()
-        acc = None
         use_pallas = self.backend == "pallas"
-        if use_pallas:
-            from spark_rapids_ml_tpu.ops.pallas.covariance import (
-                centered_gram_pallas,
+        # The interpreter covers non-TPU platforms (CI's CPU mesh).
+        interpret = use_pallas and jax.default_backend() != "tpu"
+        if use_pallas and not interpret and np.dtype(self.dtype) == np.float64:
+            # Mosaic has no f64 MXU dot — fail clearly instead of at
+            # kernel compile (reachable only with x64 forced on TPU).
+            raise ValueError(
+                "backend='pallas' compiles f32 kernels; disable x64 or "
+                "pass dtype=jnp.float32 (or use backend='xla')"
             )
-
-            # The interpreter covers non-TPU platforms (CI's CPU mesh).
-            interpret = jax.default_backend() != "tpu"
-            if not interpret and np.dtype(self.dtype) == np.float64:
-                # Mosaic has no f64 MXU dot — fail clearly instead of at
-                # kernel compile (reachable only with x64 forced on TPU).
-                raise ValueError(
-                    "backend='pallas' compiles f32 kernels; disable x64 or "
-                    "pass dtype=jnp.float32 (or use backend='xla')"
-                )
         put = _partial(jax.device_put, device=device)
-        for part in self.partitions:
-            blk = self._place_partition(part, put)
+        if self.mean_centering:
+            bump_counter("rowmatrix.cov.one_pass")
             with StageRange("solve", TraceColor.GREEN):
-                if use_pallas:
-                    gram = centered_gram_pallas(blk, mean, interpret=interpret)
-                else:
-                    gram = centered_gram(blk, mean, precision=self.precision)
-                acc = gram if acc is None else acc + gram
+                state = comoment_init(self.num_cols, dtype=self.dtype)
+            for part in self.partitions:
+                blk = self._place_partition(part, put)
+                with StageRange("solve", TraceColor.GREEN):
+                    state = comoment_add_block(
+                        state,
+                        blk,
+                        precision=self.precision,
+                        backend=self.backend,
+                        interpret=interpret,
+                    )
+            acc = state[3]
+        else:
+            if use_pallas:
+                from spark_rapids_ml_tpu.ops.pallas.covariance import (
+                    centered_gram_pallas,
+                )
+            acc = None
+            mean = jnp.zeros(self.num_cols, dtype=self.dtype)
+            for part in self.partitions:
+                blk = self._place_partition(part, put)
+                with StageRange("solve", TraceColor.GREEN):
+                    if use_pallas:
+                        gram = centered_gram_pallas(blk, mean, interpret=interpret)
+                    else:
+                        gram = centered_gram(blk, mean, precision=self.precision)
+                    acc = gram if acc is None else acc + gram
         with StageRange("solve", TraceColor.GREEN):
             return acc / (self.num_rows - 1)
 

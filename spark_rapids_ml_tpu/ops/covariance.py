@@ -272,3 +272,86 @@ def welford_merge(a: tuple, b: tuple) -> tuple:
     mean = mean_a + delta * (count_b / safe)
     m2 = m2_a + m2_b + delta**2 * (count_a * count_b / safe)
     return (count, mean, m2)
+
+
+def comoment_init(d: int, dtype=jnp.float64) -> tuple:
+    """(count, mean (d,), mean_lo (d,), M (d, d)) accumulator: the
+    ``welford_*`` three with a MATRIX second moment, ``M = sum (x - m)^T
+    (x - m)`` over the rows seen, ``m = mean + mean_lo`` their column
+    means. Column means and the centred Gram of host partitions in ONE
+    pass, each partition placed once (``linalg/row_matrix.py``'s GEMM
+    route); ``M / (count - 1)`` is the covariance.
+
+    ``mean_lo`` is what rounding took from ``mean`` (a few units in its
+    last place at most). A pairwise merge feeds the means' error into
+    ``M`` at first order, ``|column mean| / spread`` roundings where the
+    two-pass form (Gram centred on the finished mean) has them squared;
+    carried in two pieces the means lose nothing, and float32 columns a
+    thousand spreads off zero read as they do in two passes."""
+    return (
+        jnp.zeros((), dtype=dtype),
+        jnp.zeros((d,), dtype=dtype),
+        jnp.zeros((d,), dtype=dtype),
+        jnp.zeros((d, d), dtype=dtype),
+    )
+
+
+@jax.jit
+def comoment_merge(a: tuple, b: tuple) -> tuple:
+    """Chan's pairwise merge of two co-moment states. Exact algebra, no
+    approximation of the two-pass form: ``sum_b (X_b - m)^T (X_b - m) =
+    sum_b [(X_b - m_b)^T (X_b - m_b) + n_b (m_b - m)(m_b - m)^T]``."""
+    count_a, mean_a, lo_a, m_a = a
+    count_b, mean_b, lo_b, m_b = b
+    count = count_a + count_b
+    weight = count_b / jnp.maximum(count, 1)
+    # Means that lie close together against their size (where their
+    # rounding matters) differ by an exact float; the trailing pieces ride
+    # along, and what the new leading piece rounds off joins them.
+    delta_hi, delta_lo = mean_b - mean_a, lo_b - lo_a
+    delta = delta_hi + delta_lo
+    shift = delta_hi * weight
+    mean = mean_a + shift
+    lo = lo_a + delta_lo * weight + (shift - (mean - mean_a))
+    m = m_a + m_b + jnp.outer(delta, delta) * (count_a * weight)
+    return (count, mean, lo, m)
+
+
+@partial(
+    jax.jit,
+    static_argnames=("precision", "backend", "interpret"),
+    donate_argnums=(0,),
+)
+def comoment_add_block(
+    state: tuple,
+    x: jax.Array,
+    precision: str = "highest",
+    backend: str = "xla",
+    interpret: bool = False,
+) -> tuple:
+    """One partition into the state, one program: the block's own column
+    means, its Gram centred on THEM (the kernel the two-pass route ran on
+    the global mean: :func:`centered_gram`, or the Pallas kernel under
+    ``backend="pallas"``), then :func:`comoment_merge`. Centring on the
+    block's mean, not on the first block's as :func:`shifted_block_scan`
+    does, leaves no ``n * delta delta^T`` to cancel at the end when
+    partitions are sorted or clustered. ``state`` is donated: the (d, d)
+    accumulator is updated in place."""
+    n_b = x.shape[0]
+    if n_b == 0:  # static shape: an empty partition contributes nothing
+        return state
+    # The division INSIDE the sum, so that the means leave a reduction as
+    # one rounded array: as ``sum / n_b`` the compiler recomputes the
+    # division in each consumer's fusion, where a fused multiply-add
+    # (XLA:CPU) skips its rounding and the uses disagree by it.
+    mean_b = jnp.sum(x * (1.0 / n_b), axis=0)
+    # what that rounding left: the rows' mean is mean_b + lo_b
+    lo_b = jnp.sum((x - mean_b) * (1.0 / n_b), axis=0)
+    if backend == "pallas":
+        from spark_rapids_ml_tpu.ops.pallas.covariance import centered_gram_pallas
+
+        m_b = centered_gram_pallas(x, mean_b, interpret=interpret)
+    else:
+        m_b = centered_gram(x, mean_b, precision=precision)
+    m_b = m_b - jnp.outer(lo_b, lo_b) * n_b
+    return comoment_merge(state, (jnp.asarray(n_b, state[0].dtype), mean_b, lo_b, m_b))

@@ -47,6 +47,13 @@ def rng():
     return np.random.default_rng(42)
 
 
+@pytest.fixture
+def chip_dtypes():
+    """The chip's configuration: x64 off, so the compute dtype is float32."""
+    with jax.enable_x64(False):
+        yield
+
+
 # The 3x5 synthetic dataset from the reference suite (PCASuite.scala:42-46):
 # one all-zero sparse row, one sparse row, one dense row.
 REFERENCE_DATA = [

@@ -1,6 +1,8 @@
 """Unit tests for the ops layer — the XLA replacements for the reference's
 JNI kernels (rapidsml_jni.cu), each checked against a numpy oracle."""
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -19,6 +21,9 @@ from spark_rapids_ml_tpu.ops.covariance import (
     centered_gram,
     centered_gram_blocked,
     centered_gram_packed,
+    comoment_add_block,
+    comoment_init,
+    comoment_merge,
     welford_add_block,
     welford_init,
     welford_merge,
@@ -56,6 +61,115 @@ class TestPacked:
     def test_triu_to_full_rejects_bad_length(self):
         with pytest.raises(ValueError):
             triu_to_full(np.zeros(7))
+
+
+def comoment_of(blocks, dtype=jnp.float64, **kwargs):
+    state = comoment_init(blocks[0].shape[1], dtype=dtype)
+    for blk in blocks:
+        state = comoment_add_block(state, jnp.asarray(blk, dtype=dtype), **kwargs)
+    return state
+
+
+def rel_gap(got, want) -> float:
+    """Widest entry gap as a share of the oracle's largest entry."""
+    want = np.asarray(want, dtype=np.float64)
+    return float(np.abs(np.asarray(got, dtype=np.float64) - want).max() / np.abs(want).max())
+
+
+class TestComoment:
+    """The matrix-moment ``welford_*``: (count, mean, mean_lo, M) against a
+    float64 numpy oracle, ``M`` the Gram centred on the rows' means."""
+
+    def test_unequal_blocks_match_the_two_pass_oracle(self, rng):
+        x = rng.normal(size=(500, 8)) * 3 + 7
+        count, mean, lo, m = comoment_of(np.array_split(x, [3, 50, 51, 333]))
+        assert int(count) == 500
+        np.testing.assert_allclose(mean + lo, x.mean(axis=0), atol=1e-12)
+        np.testing.assert_allclose(m / (500 - 1), np.cov(x, rowvar=False), atol=1e-11)
+
+    def test_an_empty_block_returns_the_state_unchanged(self, rng):
+        x = rng.normal(size=(60, 5))
+        with_empty = comoment_of([x[:20], x[:0], x[20:]])
+        without = comoment_of([x[:20], x[20:]])
+        for got, want in zip(with_empty, without):
+            assert np.array_equal(np.asarray(got), np.asarray(want))
+        first = comoment_add_block(comoment_init(5), jnp.asarray(x[:0]))
+        assert int(first[0]) == 0 and not np.asarray(first[3]).any()
+
+    def test_a_one_row_block_has_no_gram_of_its_own(self, rng):
+        x = rng.normal(size=(41, 6)) + 100.0
+        one = comoment_of([x[:1]])
+        assert int(one[0]) == 1 and not np.asarray(one[3]).any()
+        np.testing.assert_allclose(one[1] + one[2], x[0], atol=1e-12)
+        rows = comoment_of([x[i : i + 1] for i in range(41)])  # Welford's own form
+        np.testing.assert_allclose(rows[3] / 40, np.cov(x, rowvar=False), atol=1e-11)
+
+    def test_merge_is_associative_and_agrees_with_add_block(self, rng):
+        x = rng.normal(size=(120, 4)) * 2 - 5
+        a, b, c = (comoment_of([blk]) for blk in (x[:30], x[30:31], x[31:]))
+        left = comoment_merge(comoment_merge(a, b), c)
+        right = comoment_merge(a, comoment_merge(b, c))
+        scan = comoment_of([x[:30], x[30:31], x[31:]])
+        for state in (left, right, scan):
+            assert int(state[0]) == 120
+            np.testing.assert_allclose(state[1] + state[2], x.mean(axis=0), atol=1e-12)
+            np.testing.assert_allclose(state[3] / 119, np.cov(x, rowvar=False), atol=1e-11)
+        empty = comoment_init(4)
+        for got, want in zip(comoment_merge(empty, a), a):
+            np.testing.assert_allclose(got, want, atol=0)
+        assert int(comoment_merge(empty, empty)[0]) == 0
+
+    def test_the_pallas_kernel_takes_the_block_mean_the_same_way(self, rng):
+        x = rng.normal(size=(300, 16)) + 3
+        blocks = np.array_split(x, [100, 230])
+        xla = comoment_of(blocks)
+        pallas = comoment_of(blocks, backend="pallas", interpret=True)
+        np.testing.assert_allclose(pallas[3], xla[3], rtol=1e-12, atol=1e-10)
+
+    # What the route that made two passes (means by ``welford_*``, then
+    # ``centered_gram`` on the finished means) reads against the float64
+    # covariance of SHUFFLED rows whose columns lie 1e3 off zero: under
+    # 1e-14 in float64, 2e-7 to 5e-7 in float32 (one pass with the means
+    # in ONE piece reads 2e-6 to 2e-5 there). One pass is held to it
+    # on sorted partitions, where a merge has the most to add: block means
+    # ten spreads apart (the between-block term carries the covariance) and
+    # half a spread apart (the means' rounding is as large as it gets
+    # against what it is multiplied with).
+    PRESENT_ROUTE_TOL = {"float64": 1e-12, "float32": 1e-6}
+
+    @staticmethod
+    def partitions(rng, order: str, parts: int = 40, rows: int = 500, cols: int = 24):
+        x = rng.standard_normal((parts * rows, cols)) + 1e3 * rng.standard_normal(cols)
+        if order != "shuffled":
+            step = {"sorted_far": 10.0, "sorted_near": 0.5}[order]
+            x += np.repeat(np.arange(parts) * step, rows)[:, None] * rng.standard_normal(cols)
+        x = x.astype(np.float32)  # the same values at either compute dtype
+        return np.split(x, parts), np.cov(x.astype(np.float64), rowvar=False)
+
+    @pytest.mark.parametrize("order", ["shuffled", "sorted_far", "sorted_near"])
+    @pytest.mark.parametrize("dtype", ["float64", "float32"])
+    def test_sorted_partitions_read_as_the_two_pass_route_does(self, rng, dtype, order):
+        blocks, want = self.partitions(rng, order)
+        n = sum(blk.shape[0] for blk in blocks)
+        with jax.enable_x64(dtype == "float64"):
+            state = comoment_of(blocks, dtype=jnp.dtype(dtype))
+            assert state[3].dtype == jnp.dtype(dtype)
+            one_pass = np.asarray(state[3], dtype=np.float64) / (n - 1)
+            mean = np.asarray(state[1], np.float64) + np.asarray(state[2], np.float64)
+            placed = [jnp.asarray(blk, dtype=jnp.dtype(dtype)) for blk in blocks]
+            means = welford_init(blocks[0].shape[1], dtype=jnp.dtype(dtype))
+            for blk in placed:
+                means = welford_add_block(means, blk)
+            two_pass = sum(
+                np.asarray(centered_gram(blk, means[1]), dtype=np.float64) for blk in placed
+            ) / (n - 1)
+        tol = self.PRESENT_ROUTE_TOL[dtype]
+        assert rel_gap(two_pass, want) < tol  # the tolerance IS the old route's
+        assert rel_gap(one_pass, want) < tol
+        exact = np.concatenate(blocks).astype(np.float64).mean(axis=0)
+        # carried in two pieces the means are good to a rounding of the
+        # SPREAD, not of the 1e3 they lie off zero
+        assert np.abs(mean - exact).max() < (1e-10 if dtype == "float64" else 1e-5)
 
 
 class TestEigh:

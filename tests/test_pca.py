@@ -424,3 +424,57 @@ class TestTopkEigenSolver:
             m.explainedVariance, m_ref.explainedVariance, atol=1e-9
         )
 
+
+
+class TestHostPartitionsOnePass:
+    """Host partitions on the GEMM route: column means and the centred Gram
+    come from ONE pass (each partition placed once, its Gram centred on its
+    own means, Chan's merge between them). ``pc`` and ``explainedVariance``
+    against ``numpy.cov`` + ``numpy.linalg.eigh`` in float64, for both
+    covariance kernels, with the rows shuffled and with the partitions
+    sorted (block means many spreads apart, columns 1e3 off zero), under
+    the tests' x64 and in the chip's float32."""
+
+    K = 3
+
+    @staticmethod
+    def partitions(rng, order: str, parts: int = 8, rows: int = 256, cols: int = 12):
+        basis = np.linalg.qr(rng.standard_normal((cols, cols)))[0]
+        scales = np.array([6.0, 4.0, 2.5] + [1.0] * (cols - 3))
+        x = (rng.standard_normal((parts * rows, cols)) * scales) @ basis.T
+        x += 1e3 * rng.standard_normal(cols)
+        if order == "sorted":  # each partition ten spreads further along one axis
+            x += np.repeat(np.arange(parts) * 10.0, rows)[:, None] * basis[:, 3]
+        return np.split(x.astype(np.float32), parts)
+
+    @staticmethod
+    def oracle(parts, k: int):
+        w, v = np.linalg.eigh(np.cov(np.concatenate(parts).astype(np.float64), rowvar=False))
+        w, v = w[::-1], v[:, ::-1]
+        return v[:, :k], w[:k] / w.sum()
+
+    @pytest.mark.parametrize("order", ["shuffled", "sorted"])
+    @pytest.mark.parametrize("backend", ["xla", "pallas"])
+    @pytest.mark.parametrize("x64", [True, False], ids=["x64", "chip_dtypes"])
+    def test_matches_the_float64_oracle(self, rng, request, x64, backend, order):
+        if not x64:
+            request.getfixturevalue("chip_dtypes")
+        parts = self.partitions(rng, order)
+        model = PCA().setK(self.K).setCovarianceBackend(backend).fit(parts)
+        want_pc, want_ev = self.oracle(parts, self.K)
+        pc = np.asarray(model.pc, dtype=np.float64)
+        pc = pc * np.sign(np.sum(pc * want_pc, axis=0))  # sign-aligned
+        # float32: 1e-7 to 7e-7 and 3e-6 to 9e-5 on three seeds, as on the
+        # route that made two passes (the components' gap is the subspace
+        # iteration's stopping residual, not the covariance)
+        ev_tol, pc_tol = (1e-9, 1e-7) if x64 else (2e-6, 3e-4)
+        np.testing.assert_allclose(model.explainedVariance, want_ev, rtol=ev_tol)
+        np.testing.assert_allclose(pc, want_pc, atol=pc_tol)
+
+    def test_sorted_and_shuffled_partitions_of_one_matrix_fit_one_model(self, rng, chip_dtypes):
+        parts = self.partitions(rng, "sorted")
+        x = np.concatenate(parts)
+        shuffled = np.split(x[rng.permutation(x.shape[0])], len(parts))
+        a, b = (PCA().setK(self.K).fit(p) for p in (parts, shuffled))
+        np.testing.assert_allclose(a.explainedVariance, b.explainedVariance, rtol=2e-6)
+        np.testing.assert_allclose(np.abs(a.pc), np.abs(b.pc), atol=3e-4)

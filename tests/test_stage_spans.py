@@ -6,9 +6,10 @@ host time for the benchmark's ``host_*`` metrics.
 What is held here: a stage is a TraceRange plus its two counters and
 nothing else; stages of one thread never nest (conftest's
 ``stages_never_nest`` fixture fires on a planted nesting); a host-partition
-PCA fit opens every stage, places its rows twice, and its stages add up to
-no more than its wall; inside a profiler session the stages are in the
-trace's host plane; a device-array fit opens none of the host stages.
+PCA fit opens every stage, places its rows ONCE (means and Gram in one
+pass, counter ``rowmatrix.cov.one_pass``), and its stages add up to no more
+than its wall; inside a profiler session the stages are in the trace's host
+plane; a device-array fit opens none of the host stages.
 """
 
 import glob
@@ -117,7 +118,7 @@ class TestStagesNeverNest:
 
 
 class TestHostPartitionFit:
-    def test_pca_fit_opens_every_stage_and_places_its_rows_twice(self):
+    def test_pca_fit_opens_every_stage_and_places_its_rows_once(self):
         parts = f32_partitions(11)
         before = stage_counters()
         t0 = time.perf_counter()
@@ -128,12 +129,12 @@ class TestHostPartitionFit:
         for stage in STAGES:
             assert got[f"fit.stage.{stage}.calls"] > 0, stage
             assert got[f"fit.stage.{stage}.ns"] >= 0, stage
-        # one conversion and one placement a partition in each of the two
-        # passes (the means, then the Gram), in the compute dtype
-        assert got["fit.stage.convert.calls"] == got["fit.stage.place.calls"] == 2 * len(parts)
+        # one conversion and one placement a partition, in the compute
+        # dtype: the means are taken in the pass that makes the Gram
+        assert got["fit.stage.convert.calls"] == got["fit.stage.place.calls"] == len(parts)
         rows, cols = sum(p.shape[0] for p in parts), parts[0].shape[1]
         itemsize = np.dtype(jnp.zeros(0).dtype).itemsize  # float64 under the tests' x64
-        assert got["fit.stage.place.bytes"] == 2 * rows * cols * itemsize
+        assert got["fit.stage.place.bytes"] == rows * cols * itemsize
         # RowMatrix densifies in the compute dtype, float64 under the tests'
         # x64: every float32 partition is written anew
         assert got["fit.stage.densify.calls"] == 1
@@ -154,9 +155,8 @@ class TestHostPartitionFit:
             return None
 
         cov = find(tree, "compute cov")
-        means = find(cov["children"], "mean center")
-        assert {c["name"] for c in means["children"]} == {"convert", "place", "solve"}
-        assert {"convert", "place", "solve"} <= {c["name"] for c in cov["children"]}
+        assert {c["name"] for c in cov["children"]} == {"convert", "place", "solve"}
+        assert find(tree, "mean center") is None  # no pass of its own for the means
         assert [c["name"] for c in find(tree, "auto eigh")["children"]] == ["solve"]
         assert find(tree, "admit") is not None and find(tree, "densify") is not None
         assert find(tree, "gemm") is None  # the span the three stages replaced
@@ -165,12 +165,13 @@ class TestHostPartitionFit:
         parts = f32_partitions(17)
         report = PCA().setK(2).fit(parts).fit_report()
         text = str(report)
-        # the means pass: a conversion and a placement a partition, and a
-        # dispatch a partition after the one that makes the accumulator
+        # the one pass: a conversion and a placement a partition, and a
+        # dispatch a partition between the one that makes the accumulator
+        # and the one that scales it
         assert f"convert x{len(parts)}" in text and f"place x{len(parts)}" in text
-        assert f"solve x{len(parts) + 1}" in text
-        assert text.count("convert") == 2  # one line a pass, not one a partition
-        assert report.stage_totals()["convert"]["calls"] == 2 * len(parts)
+        assert f"solve x{len(parts) + 2}" in text
+        assert text.count("convert") == 1  # one line for the pass, not one a partition
+        assert report.stage_totals()["convert"]["calls"] == len(parts)
 
     def test_a_float64_partition_already_dense_is_not_written(self):
         rng = np.random.default_rng(13)
@@ -207,13 +208,6 @@ def row_matrices(monkeypatch):
     return made
 
 
-@pytest.fixture
-def chip_dtypes():
-    """The chip's configuration: x64 off, so the compute dtype is float32."""
-    with jax.enable_x64(False):
-        yield
-
-
 @pytest.mark.usefixtures("chip_dtypes")
 class TestHostPartitionFitWithoutX64:
     def test_float32_partitions_are_handed_on_as_they_are(self, row_matrices):
@@ -227,8 +221,8 @@ class TestHostPartitionFitWithoutX64:
         assert got["fit.stage.densify.calls"] == 1
         assert got["fit.stage.densify.bytes"] == 0
         # the stages stay open round a conversion that has nothing to do
-        assert got["fit.stage.convert.calls"] == got["fit.stage.place.calls"] == 2 * len(parts)
-        assert got["fit.stage.place.bytes"] == 2 * sum(p.size for p in parts) * 4
+        assert got["fit.stage.convert.calls"] == got["fit.stage.place.calls"] == len(parts)
+        assert got["fit.stage.place.bytes"] == sum(p.size for p in parts) * 4
 
     def test_a_float64_source_at_highest_is_narrowed_once(self, row_matrices):
         parts = [p.astype(np.float64) for p in f32_partitions(22)]
@@ -239,7 +233,7 @@ class TestHostPartitionFitWithoutX64:
         assert {p.dtype for p in mat.partitions} == {np.dtype(np.float32)}
         entries = sum(p.size for p in parts)
         assert got["fit.stage.densify.bytes"] == entries * 4
-        assert got["fit.stage.place.bytes"] == 2 * entries * 4
+        assert got["fit.stage.place.bytes"] == entries * 4
 
     @pytest.mark.parametrize(
         "route",
@@ -261,6 +255,41 @@ class TestHostPartitionFitWithoutX64:
         assert np.array_equal(
             np.asarray(narrow.explainedVariance), np.asarray(wide.explainedVariance)
         )
+
+
+class TestOnePassCounter:
+    """``rowmatrix.cov.one_pass``: one per covariance that takes its means
+    and its Gram from a single pass over host partitions, and none on the
+    routes that never made two."""
+
+    @staticmethod
+    def moved_by(fit) -> int:
+        before = tracing.counter_value("rowmatrix.cov.one_pass")
+        model = fit()
+        np.asarray(model.pc)
+        return tracing.counter_value("rowmatrix.cov.one_pass") - before
+
+    @pytest.mark.parametrize("backend", ["xla", "pallas"])
+    def test_moves_by_one_a_host_partition_fit(self, backend):
+        parts = f32_partitions(31)
+        pca = PCA().setK(2).setCovarianceBackend(backend)
+        assert self.moved_by(lambda: pca.fit(parts)) == 1
+        assert self.moved_by(lambda: pca.fit(parts)) == 1  # every fit, not every compile
+
+    @pytest.mark.parametrize(
+        "fit",
+        [
+            lambda parts: PCA().setK(2).fit(jnp.asarray(np.concatenate(parts))),
+            lambda parts: PCA().setK(2).setMeanCentering(False).fit(parts),
+            lambda parts: PCA().setK(2).setPrecision("dd").fit(parts),
+            lambda parts: PCA().setK(2).setUseGemm(False).fit(parts),
+            lambda parts: PCA().setK(2).fit(iter(parts)),
+        ],
+        ids=["device_array", "uncentred", "dd", "packed", "streaming"],
+    )
+    def test_does_not_move_on_the_other_routes(self, fit):
+        parts = f32_partitions(32)
+        assert self.moved_by(lambda: fit(parts)) == 0
 
 
 class TestDeviceArrayFit:
@@ -290,7 +319,7 @@ class TestProfilerSession:
         finally:
             jax.profiler.stop_trace()
         (path,) = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
-        names = set(STAGES) | {"enclosing fit", "compute cov", "mean center"}
+        names = set(STAGES) | {"enclosing fit", "compute cov"}
         events = {}
         for plane in ProfileData.from_file(path).planes:
             if plane.name.startswith("/host:"):
@@ -305,11 +334,9 @@ class TestProfilerSession:
             assert events.get(stage), f"no {stage} event in the host plane"
             for start, end in events[stage]:
                 assert fit[0] <= start <= end <= fit[1], stage
-        assert len(events["convert"]) == len(events["place"]) == 2 * len(parts)
-        # and under the reference's parents: every conversion and placement
-        # lies inside the covariance span, half of them inside the means pass
+        assert len(events["convert"]) == len(events["place"]) == len(parts)
+        # and under the reference's parent: every conversion and placement
+        # lies inside the covariance span
         (cov,) = events["compute cov"]
-        (means,) = events["mean center"]
         for stage in ("convert", "place"):
             assert all(cov[0] <= s and e <= cov[1] for s, e in events[stage])
-            assert sum(means[0] <= s and e <= means[1] for s, e in events[stage]) == len(parts)

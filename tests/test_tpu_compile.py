@@ -193,6 +193,27 @@ class TestCovariance:
         ).compile()
         assert _has_kernel(compiled)
 
+    @pytest.mark.parametrize("backend,d", [("xla", 3000), ("pallas", 1024)])
+    def test_one_pass_step_of_a_host_partition(self, v5e, backend, d):
+        """``comoment_add_block`` as ``RowMatrix._covariance_gemm`` calls it
+        on a 10,000-row partition (``pca_3000.host_parts``'s, and the
+        kernel's width): the block's means, its Gram centred on them and
+        the merge are one program that updates the donated (d, d)
+        accumulator in place and writes nothing else of that size."""
+        from spark_rapids_ml_tpu.ops.covariance import comoment_add_block
+
+        state = (_f32((), v5e), _f32((d,), v5e), _f32((d,), v5e), _f32((d, d), v5e))
+        compiled = comoment_add_block.lower(
+            state, _f32((10_000, d), v5e), precision="highest",
+            backend=backend, interpret=False,
+        ).compile()
+        assert _has_kernel(compiled) == (backend == "pallas")
+        stats = compiled.memory_analysis()
+        assert stats.alias_size_in_bytes >= d * d * 4, stats
+        # the XLA route fuses the merge into the Gram's own fusion; the
+        # kernel hands its (padded) Gram over once
+        assert stats.temp_size_in_bytes <= (0 if backend == "xla" else 3 * d * d * 4), stats
+
     def test_fused_pca_fit_program_1m_x_1024(self, v5e):
         """The whole device-resident fit (``_pca_fit_device``, what
         ``PCA().fit(jax_array)`` runs) at 1M x 1024: must compile and fit one chip's HBM beside its
