@@ -343,6 +343,38 @@ def _price_fit_input(
     )
 
 
+def batch_within_budget(
+    family: str, resident_bytes: int, per_item_bytes: int, items: int, item: str = "item"
+) -> int:
+    """How many of ``items`` equal work items (a forest's trees) may be in
+    flight at once: as many as fit the fit budget beside ``resident_bytes``,
+    priced from shapes by the caller. All of them with the gate off; a
+    structured :class:`FitMemoryError` — before anything is compiled — when
+    not even one fits."""
+    budget = fit_mem_budget()
+    if budget <= 0:
+        return items
+    fit = (budget - int(resident_bytes)) // max(1, int(per_item_bytes))
+    if fit >= 1:
+        bump_counter("fit.admission.admitted")
+        return int(min(items, fit))
+    needed = int(resident_bytes) + int(per_item_bytes)
+    bump_counter("fit.admission.rejected")
+    emit(
+        "fit_admission", action="reject", family=family,
+        needed_bytes=needed, budget_bytes=budget, can_stream=False,
+    )
+    raise FitMemoryError(
+        family,
+        f"the resident arrays and the working set of one {item} exceed the budget",
+        needed_bytes=needed, budget_bytes=budget,
+        hint=(
+            f"raise {FIT_MEM_BUDGET_ENV} (or set it to 0 to disable the gate), "
+            f"or shrink the input or what one {item} holds"
+        ),
+    )
+
+
 # --- OOM recovery -------------------------------------------------------
 
 

@@ -9,13 +9,15 @@ ships only PCA — SURVEY.md §2; the modern RAPIDS Spark-ML line accelerates
 random forests via cuML), so the test oracle is scikit-learn / handcrafted
 separable data rather than a reference file.
 
-All trees grow simultaneously, level by level, with histogram GEMMs on the
-MXU — see :mod:`spark_rapids_ml_tpu.ops.trees` for the kernel design.
+The trees of a batch grow together, level by level: a level counts only each
+node's chosen features, so neither memory nor work grows with the number of
+nodes — see :mod:`spark_rapids_ml_tpu.ops.trees` for the builder.
 """
 
 from __future__ import annotations
 
 import math
+from functools import partial
 from typing import Any, Optional
 
 import jax
@@ -41,17 +43,18 @@ from spark_rapids_ml_tpu.core.persistence import (
 from spark_rapids_ml_tpu.models.linear_regression import _extract_xy
 from spark_rapids_ml_tpu.ops.trees import (
     Forest,
-    bin_features,
+    builder_bytes,
     feature_importances,
-    fit_forest_fused,
     forest_predict_proba,
     forest_predict_reg,
+    grow_forest,
     grow_forest_sharded,
-    quantize_features,
+    quantize_and_bin,
     sample_weights,
 )
 from spark_rapids_ml_tpu.core.serving import note_device_cache, serve_rows
-from spark_rapids_ml_tpu.utils.tracing import TraceColor, TraceRange
+from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS
+from spark_rapids_ml_tpu.utils.tracing import StageRange, TraceColor, TraceRange, bump_counter
 
 
 def _proba_kernel(x, forest, *, depth: int):
@@ -236,7 +239,14 @@ class _RandomForestParams(Params):
 
     def setMaxDepth(self, v: int):
         if not 0 <= v <= 14:
-            raise ValueError(f"maxDepth must be in [0, 14], got {v}")
+            # the heap-indexed Forest holds 2^(maxDepth+1) - 1 node slots a
+            # tree and the builder's widest level 2^(maxDepth-1) selected
+            # histograms of (K, maxBins, stats): both double with a level
+            raise ValueError(
+                f"maxDepth must be in [0, 14], got {v}: a tree is a static heap of "
+                "2^(maxDepth+1) - 1 node slots, and the widest level's selected "
+                "histograms (2^(maxDepth-1) x features a node x maxBins) are held at once"
+            )
         return self._chain(self.maxDepth, v)
 
     def setMaxBins(self, v: int):
@@ -277,6 +287,47 @@ class _RandomForestParams(Params):
 
     def setWeightCol(self, v: str):
         return self._chain(self.weightCol, v)
+
+
+class _ForestArrays:
+    """The fitted forest as heap-indexed host arrays (node ``g`` of a tree
+    has children ``2g + 1`` and ``2g + 2``): the model's public result, read
+    once from the device and kept."""
+
+    _forest: Optional[Forest]
+
+    def _host_forest(self) -> Forest:
+        if not isinstance(self._forest.feature, np.ndarray):
+            self._forest = Forest(*jax.device_get(tuple(self._forest)))
+        return self._forest
+
+    @property
+    def nodeFeature(self) -> np.ndarray:
+        """(numTrees, nodes) split feature, -1 at a leaf."""
+        return self._host_forest().feature
+
+    @property
+    def nodeThreshold(self) -> np.ndarray:
+        """(numTrees, nodes): a row goes LEFT on ``x[feature] <= threshold``."""
+        return self._host_forest().threshold
+
+    @property
+    def nodeIsLeaf(self) -> np.ndarray:
+        return self._host_forest().is_leaf
+
+    @property
+    def nodeValue(self) -> np.ndarray:
+        """(numTrees, nodes, S): class distribution, or [mean]."""
+        return self._host_forest().leaf_value
+
+    @property
+    def nodeWeight(self) -> np.ndarray:
+        """(numTrees, nodes) bootstrap-weighted rows that reached the node."""
+        return self._host_forest().node_weight
+
+    @property
+    def nodeGain(self) -> np.ndarray:
+        return self._host_forest().node_gain
 
 
 @jax.jit
@@ -327,19 +378,22 @@ def _hist_exact_in_bf16(row_stats, sample_w) -> bool:
 def _fit_forest(params: _RandomForestParams, x: np.ndarray, row_stats: np.ndarray,
                 impurity: str, classification: bool, mesh=None,
                 stats_integral: bool = False) -> Forest:
-    """Shared fit: quantize, sample, grow. Returns the Forest arrays.
+    """Shared fit: bin, sample, grow. Returns the Forest arrays.
 
-    Single-device fits run the WHOLE pipeline (quantile edges + binning +
-    growth) as one XLA program (:func:`fit_forest_fused` —
-    the prep used to cost more than the growth); only the sample-weight
-    draw stays outside it, because the bf16-exactness predicate must read
-    it back to pick the (static) histogram precision before compiling.
+    Two kinds of program, neither read back inside the fit: ``rf bin``
+    (quantile edges + packed bin ids, once) and ``rf grow`` (one batch of
+    trees, all levels: :func:`ops.trees.grow_forest`). How many trees a batch
+    holds follows from the shapes and the device's free memory
+    (``membudget.batch_within_budget`` on :func:`ops.trees.builder_bytes`);
+    what cannot fit even one tree raises ``FitMemoryError`` before any
+    compile. A tree is a function of (rows, params, seed, its index) alone,
+    so the batches change no node.
 
-    With a mesh, rows are data-sharded and the per-level histograms merge
-    over ICI (:func:`grow_forest_sharded`); quantization and weight sampling
-    stay replicated (edges/weights are tiny and seed-deterministic)."""
+    With a mesh, rows are data-sharded and the per-level SELECTED histograms
+    merge over ICI (:func:`grow_forest_sharded`); binning and weight sampling
+    stay replicated (seed-deterministic)."""
     from spark_rapids_ml_tpu.core.ingest import place_array
-    from spark_rapids_ml_tpu.core.membudget import fit_memory_guard
+    from spark_rapids_ml_tpu.core.membudget import batch_within_budget, fit_memory_guard
 
     n, d = x.shape
     # Budgeted admission (core/membudget.py): forest growth has no
@@ -357,45 +411,69 @@ def _fit_forest(params: _RandomForestParams, x: np.ndarray, row_stats: np.ndarra
             else np.asarray(row_stats).size * 4
         ),
     )
+    n_trees, max_depth = params.getNumTrees(), params.getMaxDepth()
     n_bins = min(params.getMaxBins(), max(2, n))
     m = resolve_feature_subset(
-        params.getFeatureSubsetStrategy(), d, params.getNumTrees(), classification
+        params.getFeatureSubsetStrategy(), d, n_trees, classification
     )
+    n_stats = row_stats.shape[1]
+    shards = 1 if mesh is None else int(mesh.shape[DATA_AXIS])
+    resident, per_tree, prepare = builder_bytes(
+        -(-n // shards), d, n_bins, m, n_stats, max_depth,
+        rows_resident=is_device_array(x),
+    )
+    batch_within_budget("random_forest", resident, prepare, 1, "binning pass")
+    batch = batch_within_budget("random_forest", resident, per_tree, n_trees, "tree")
+    n_batches = -(-n_trees // batch)
+    batch = -(-n_trees // n_batches)  # as even as they come
+
     key = jax.random.key(params.getSeed())
     k_sample, k_feat = jax.random.split(key)
-
+    sampling = (params.getSubsamplingRate(), params.getBootstrap())
     # Guarded placement: the whole-dataset uploads go through the
     # ingest.device_put chokepoint (fault point, OOM retry + cache
     # reclaim) instead of bare jnp.asarray calls.
     xj = place_array(x, dtype=jnp.float32)
-    w = sample_weights(
-        k_sample, params.getNumTrees(), n, params.getSubsamplingRate(),
-        params.getBootstrap(),
-    )
+    rs = place_array(row_stats, dtype=jnp.float32)
     # stats_integral: the caller GUARANTEES exact-integer stats (a plain
     # one-hot, no weightCol) — with the 256-clamped bootstrap weights the
     # bf16 exactness is then a static fact and the device-readback
     # predicate (one host round trip per fit) is skipped entirely.
     exact = classification and (
-        stats_integral or _hist_exact_in_bf16(row_stats, w)
+        stats_integral
+        or _hist_exact_in_bf16(
+            row_stats, sample_weights(k_sample, np.arange(n_trees), n, *sampling)
+        )
     )
     kwargs = dict(
-        max_depth=params.getMaxDepth(),
+        max_depth=max_depth,
         n_bins=n_bins,
+        n_features=d,
         impurity=impurity,
         feat_subset=m,
         min_instances=params.getMinInstancesPerNode(),
         min_info_gain=params.getMinInfoGain(),
         exact_counts=exact,
     )
-    rs = place_array(row_stats, dtype=jnp.float32)
-    if mesh is not None:
-        edges = quantize_features(xj, n_bins)
-        xb = bin_features(xj, edges)
-        return grow_forest_sharded(
-            mesh, xb, rs, w, edges.astype(jnp.float32), k_feat, **kwargs
-        )
-    return fit_forest_fused(xj, rs, w, k_feat, **kwargs)
+    # The stage times the dispatch: no program of the fit is waited for.
+    with StageRange("solve"):
+        with TraceRange("rf bin", TraceColor.BLUE):
+            edges, xb = quantize_and_bin(xj, n_bins)
+        bump_counter("forest.bins.bytes", int(xb.size) * 4)
+        grow = grow_forest if mesh is None else partial(grow_forest_sharded, mesh)
+        grown = []
+        for first in range(0, n_trees, batch):
+            ids = np.arange(first, min(first + batch, n_trees), dtype=np.int32)
+            with TraceRange("rf grow", TraceColor.RED):
+                w = sample_weights(k_sample, ids, n, *sampling)
+                grown.append(grow(xb, rs, w, edges, k_feat, ids, **kwargs))
+        forest = grown[0] if len(grown) == 1 else Forest(*map(jnp.concatenate, zip(*grown)))
+    # what the fit dispatched, from its shapes (nothing is read back for them)
+    bump_counter("forest.grow.tree_batches", len(grown))
+    bump_counter("forest.grow.tree_levels", n_trees * max_depth)
+    bump_counter("forest.grow.selected_elems", n_trees * max_depth * n * m)
+    bump_counter("forest.grow.hist_cells", n_trees * (2**max_depth - 1) * m * n_bins * n_stats)
+    return forest
 
 
 class RandomForestClassifier(_RandomForestParams, Estimator, MLReadable):
@@ -505,7 +583,7 @@ class RandomForestClassifier(_RandomForestParams, Estimator, MLReadable):
         return self._copyValues(model)
 
 
-class RandomForestClassificationModel(_RandomForestParams, Model):
+class RandomForestClassificationModel(_ForestArrays, _RandomForestParams, Model):
     probabilityCol = RandomForestClassifier.probabilityCol
     rawPredictionCol = RandomForestClassifier.rawPredictionCol
 
@@ -689,7 +767,7 @@ class RandomForestRegressor(_RandomForestParams, Estimator, MLReadable):
         return self._copyValues(model)
 
 
-class RandomForestRegressionModel(_RandomForestParams, Model):
+class RandomForestRegressionModel(_ForestArrays, _RandomForestParams, Model):
     def __init__(
         self,
         uid: Optional[str] = None,

@@ -331,7 +331,84 @@ class TestNumClassesHint:
 
         from spark_rapids_ml_tpu.ops.trees import sample_weights
 
-        w = np.asarray(sample_weights(jax.random.key(1), 4, 50_000, 1.0, True))
+        w = np.asarray(sample_weights(jax.random.key(1), np.arange(4), 50_000, 1.0, True))
         assert np.array_equal(w, np.rint(w))
         assert w.max() <= 256.0
         assert w.mean() == pytest.approx(1.0, abs=0.05)
+        # a tree's draw is its own: a batch draws what the whole forest would
+        one = np.asarray(sample_weights(jax.random.key(1), np.array([2]), 50_000, 1.0, True))
+        np.testing.assert_array_equal(one[0], w[2])
+
+
+
+class TestForestAdmission:
+    def _xy(self, rng, n=300, d=6):
+        x = rng.normal(size=(n, d))
+        return x, (x[:, 0] > 0).astype(float)
+
+    def test_over_budget_raises_before_any_program(self, rng, monkeypatch):
+        from spark_rapids_ml_tpu.core.membudget import FitMemoryError
+        from spark_rapids_ml_tpu.models import random_forest
+
+        def never(*a, **k):
+            raise AssertionError("a program was built for a fit that cannot fit")
+
+        monkeypatch.setattr(random_forest, "quantize_and_bin", never)
+        monkeypatch.setattr(random_forest, "grow_forest", never)
+        monkeypatch.setenv("TPUML_FIT_MEM_BUDGET", "200000")
+        import jax.numpy as jnp
+
+        x, y = self._xy(rng)
+        with pytest.raises(FitMemoryError, match="one tree"):
+            RandomForestClassifier().setNumTrees(3).setMaxDepth(6).fit(
+                (jnp.asarray(x, jnp.float32), jnp.asarray(y))
+            )
+
+    def test_price_of_the_cell(self):
+        """At the benchmark's shapes: bins 0.77 GB, a tree in flight about
+        0.9 GB, so a free chip holds all 13; the dense form's depth 13 at
+        3000 columns (12.6 GB of histogram a tree) is refused."""
+        from spark_rapids_ml_tpu.ops.trees import builder_bytes
+
+        resident, per_tree, prepare = builder_bytes(250_000, 3000, 128, 55, 2, 13)
+        assert 0.7e9 < resident < 0.8e9
+        assert 0.5e9 < per_tree < 1.5e9
+        assert 5.9e9 < prepare < 6.1e9
+        assert builder_bytes(250_000, 3000, 128, 3000, 2, 13)[1] > 20e9
+        host = builder_bytes(250_000, 3000, 128, 55, 2, 13, rows_resident=False)
+        assert host[0] - resident == 250_000 * 3000 * 4
+
+    def test_batches_follow_from_the_budget_and_change_nothing(self, rng, monkeypatch):
+        from spark_rapids_ml_tpu.ops.trees import builder_bytes
+        from spark_rapids_ml_tpu.utils import tracing
+
+        x, y = self._xy(rng)
+        est = lambda: RandomForestClassifier().setNumTrees(5).setMaxDepth(4).setSeed(2)  # noqa: E731
+        whole = est().fit((x, y))
+        resident, per_tree, prepare = builder_bytes(300, 6, 32, 3, 2, 4, rows_resident=False)
+        # room for two trees beside the rows and the bins
+        monkeypatch.setenv("TPUML_FIT_MEM_BUDGET", str(resident + max(prepare, 2 * per_tree) + 4 * 300 * 8 * 4))
+        before = tracing.counters("forest.")
+        batched = est().fit((x, y))
+        moved = {k: v - before.get(k, 0) for k, v in tracing.counters("forest.").items()}
+        assert moved["forest.grow.tree_batches"] == 3  # 2 + 2 + 1
+        for name in whole._forest._fields:
+            np.testing.assert_array_equal(
+                np.asarray(getattr(batched._forest, name)), np.asarray(getattr(whole._forest, name))
+            )
+
+    def test_counters_equal_their_closed_forms(self, rng):
+        from spark_rapids_ml_tpu.utils import tracing
+
+        x, y = self._xy(rng, n=500, d=10)
+        before = tracing.counters("forest.")
+        RandomForestClassifier().setNumTrees(4).setMaxDepth(3).setMaxBins(16).setSeed(1).fit((x, y))
+        moved = {k: v - before.get(k, 0) for k, v in tracing.counters("forest.").items()}
+        k = 4  # ceil(sqrt(10))
+        assert moved == {
+            "forest.bins.bytes": 500 * 3 * 4,  # ten uint8 ids in three words a row
+            "forest.grow.tree_batches": 1,
+            "forest.grow.tree_levels": 4 * 3,
+            "forest.grow.selected_elems": 4 * 3 * 500 * k,
+            "forest.grow.hist_cells": 4 * (2**3 - 1) * k * 16 * 2,
+        }
