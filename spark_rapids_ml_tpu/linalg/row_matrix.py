@@ -26,7 +26,7 @@ from spark_rapids_ml_tpu.core.data import (
     is_streaming_source,
     iter_stream_blocks,
 )
-from spark_rapids_ml_tpu.core.ingest import dense_partitions, place_block
+from spark_rapids_ml_tpu.core.ingest import PlacementWindow, dense_partitions
 from spark_rapids_ml_tpu.ops.covariance import (
     centered_gram,
     centered_gram_packed,
@@ -315,17 +315,20 @@ class RowMatrix:
         with TraceRange("mean center", TraceColor.ORANGE):
             with StageRange("solve"):
                 state = welford_init(self.num_cols, dtype=self.dtype)
+            window = PlacementWindow()
             for part in self.partitions:
-                blk = self._place_partition(part, jnp.asarray)
+                blk = self._place_partition(part, jnp.asarray, window)
                 with StageRange("solve"):
                     state = welford_add_block(state, blk)
             return state[1]
 
-    def _place_partition(self, part: np.ndarray, put):
+    def _place_partition(self, part: np.ndarray, put, window: PlacementWindow):
         """One host partition on the device in the compute dtype: the
         ``convert`` stage (the host conversion ``jnp.asarray(part,
         dtype=...)`` makes inside itself, taken out of it) and the
-        ``place`` stage round ``put``, the route's placement call. The
+        ``place`` stage round ``put``, the route's placement call, made
+        through the pass's ``window`` (one a pass: it bounds the placements
+        in flight, ``core/ingest.py::PlacementWindow``). The
         GEMM and pallas routes hold their partitions in the compute dtype
         already (``__init__``), so ``convert`` hands the partition on as
         it is, and they come here once a partition; only the packed
@@ -333,7 +336,7 @@ class RowMatrix:
         narrows here without x64, in each of its two passes."""
         with StageRange("convert"):
             host = np.asarray(part, dtype=self.dtype)
-        return place_block(host, put)
+        return window.place(host, put)
 
     # --- covariance (computeCovariance, :149-257) ---
 
@@ -418,12 +421,13 @@ class RowMatrix:
                 "pass dtype=jnp.float32 (or use backend='xla')"
             )
         put = _partial(jax.device_put, device=device)
+        window = PlacementWindow()
         if self.mean_centering:
             bump_counter("rowmatrix.cov.one_pass")
             with StageRange("solve", TraceColor.GREEN):
                 state = comoment_init(self.num_cols, dtype=self.dtype)
             for part in self.partitions:
-                blk = self._place_partition(part, put)
+                blk = self._place_partition(part, put, window)
                 with StageRange("solve", TraceColor.GREEN):
                     state = comoment_add_block(
                         state,
@@ -441,7 +445,7 @@ class RowMatrix:
             acc = None
             mean = jnp.zeros(self.num_cols, dtype=self.dtype)
             for part in self.partitions:
-                blk = self._place_partition(part, put)
+                blk = self._place_partition(part, put, window)
                 with StageRange("solve", TraceColor.GREEN):
                     if use_pallas:
                         gram = centered_gram_pallas(blk, mean, interpret=interpret)
@@ -508,8 +512,9 @@ class RowMatrix:
             else jnp.zeros(n_cols, dtype=self.dtype)
         )
         acc = None
+        window = PlacementWindow()
         for part in self.partitions:
-            blk = self._place_partition(part, jnp.asarray)
+            blk = self._place_partition(part, jnp.asarray, window)
             with StageRange("solve"):
                 packed = centered_gram_packed(blk, mean)
                 acc = packed if acc is None else acc + packed

@@ -278,9 +278,13 @@ class StageRange(TraceRange):
     Stages are leaves: one thread never opens a stage inside a stage, so
     their ``ns`` can be added up and held against a fit's wall time (the
     tests' ``stages_never_nest`` fixture asserts it on every fit they run).
-    A stage synchronises nothing: round an asynchronous call it measures
-    what the host spent in the call, and the host blocked on the device
-    shows in the stage that makes the blocking read.
+    A stage synchronises nothing of its own: round an asynchronous call it
+    measures what the host spent in the call, and the host blocked on the
+    device shows in the stage that makes the blocking read. ``place`` is
+    such a stage since the placement window (``core/ingest.py::
+    PlacementWindow``): in a pass over host partitions it holds the
+    placement call AND the wait for room before it (the read of the oldest
+    placement in flight; ``ingest.place.wait_ns`` is that part alone).
     """
 
     __slots__ = ()

@@ -9,20 +9,25 @@ nothing else; stages of one thread never nest (conftest's
 PCA fit opens every stage, places its rows ONCE (means and Gram in one
 pass, counter ``rowmatrix.cov.one_pass``), and its stages add up to no more
 than its wall; inside a profiler session the stages are in the trace's host
-plane; a device-array fit opens none of the host stages.
+plane; a device-array fit opens none of the host stages; every pass over
+host partitions keeps a bounded number of placements in flight
+(core/ingest.py::PlacementWindow: it waits for the oldest, inside ``place``,
+counters ``ingest.place.waits`` / ``.wait_ns``) and fits the same model.
 """
 
 import glob
 import os
 import threading
 import time
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from spark_rapids_ml_tpu.core.ingest import dense_partitions
+from spark_rapids_ml_tpu.core import ingest
+from spark_rapids_ml_tpu.core.ingest import PlacementWindow, dense_partitions
 from spark_rapids_ml_tpu.models.kmeans import KMeans
 from spark_rapids_ml_tpu.models.pca import PCA
 from spark_rapids_ml_tpu.utils import tracing
@@ -290,6 +295,129 @@ class TestOnePassCounter:
     def test_does_not_move_on_the_other_routes(self, fit):
         parts = f32_partitions(32)
         assert self.moved_by(lambda: fit(parts)) == 0
+
+
+class Placed:
+    """What a fake ``put`` returns: it knows whether it has been waited for."""
+
+    def __init__(self, made: list):
+        self.ready = False
+        self.waited_with = None  # placements in flight when this one was waited for
+        self._made = made
+        made.append(self)
+
+    def block_until_ready(self):
+        self.waited_with = sum(not p.ready for p in self._made)
+        self.ready = True
+        return self
+
+
+class TestPlacementWindow:
+    """``core/ingest.py::PlacementWindow``: one a pass over host partitions;
+    before a placement that would be one too many it waits for the OLDEST
+    in flight, inside the ``place`` stage."""
+
+    @staticmethod
+    def room_for(monkeypatch, blocks: int, block_nbytes: int) -> None:
+        monkeypatch.setattr(ingest, "PLACEMENT_WINDOW_BYTES", blocks * block_nbytes)
+
+    @staticmethod
+    def moved(fit) -> dict:
+        names = ("ingest.place.waits", "ingest.place.wait_ns", "fit.stage.place.calls")
+        before = {n: tracing.counter_value(n) for n in names}
+        model = fit()
+        if hasattr(model, "pc"):
+            np.asarray(model.pc)
+        return {n: tracing.counter_value(n) - before[n] for n in names}
+
+    @pytest.mark.parametrize("parts", [2, 3, 7])
+    @pytest.mark.parametrize(
+        "route, passes",
+        [
+            (lambda pca: pca, 1),
+            (lambda pca: pca.setMeanCentering(False), 1),
+            (lambda pca: pca.setUseGemm(False), 2),
+        ],
+        ids=["centred", "uncentred", "packed_fallback"],
+    )
+    def test_a_pass_waits_once_a_partition_beyond_the_window(self, monkeypatch, route, passes, parts):
+        from spark_rapids_ml_tpu import native
+
+        monkeypatch.setattr(native, "available", lambda: False)  # the packed route's jitted fallback
+        host = f32_partitions(41, parts=parts)
+        self.room_for(monkeypatch, 3, host[0].size * 8)  # float64 on the device under the tests' x64
+        got = self.moved(lambda: route(PCA().setK(2)).fit(host))
+        assert got["ingest.place.waits"] == passes * max(0, parts - 3)
+        assert got["fit.stage.place.calls"] == passes * parts  # the wait opens no stage of its own
+        assert (got["ingest.place.wait_ns"] > 0) == (parts > 3)
+
+    @pytest.mark.parametrize(
+        "fit",
+        [
+            lambda x: PCA().setK(2).fit(jnp.asarray(x)),
+            lambda x: KMeans().setK(3).setMaxIter(2).fit(x.astype(np.float32)),
+        ],
+        ids=["pca_device_rows", "kmeans_host_rows_through_prepare_rows"],
+    )
+    def test_a_fit_that_places_once_or_never_does_not_wait(self, monkeypatch, fit):
+        monkeypatch.setattr(ingest, "PLACEMENT_WINDOW_BYTES", 1)  # by route, not by size
+        x = np.random.default_rng(42).standard_normal((60, 4))
+        got = self.moved(lambda: fit(x))
+        assert got["ingest.place.waits"] == 0 and got["ingest.place.wait_ns"] == 0
+
+    @pytest.mark.parametrize("room", [2, 3, 5])
+    def test_never_more_than_the_window_in_flight_and_the_oldest_goes_first(self, monkeypatch, room):
+        block = np.zeros((10, 3), np.float32)
+        self.room_for(monkeypatch, room, block.nbytes)
+        made, window = [], PlacementWindow()
+        before = stage_counters()
+        for i in range(9):
+            placed = window.place(block, lambda b: Placed(made))
+            assert placed is made[i]
+            assert sum(not p.ready for p in made) <= room
+            assert [p.ready for p in made] == [j <= i - room for j in range(i + 1)]
+        # every wait found the window full, and was for one placement only
+        assert [p.waited_with for p in made] == [room] * (9 - room) + [None] * room
+        got = delta(before)
+        assert got["fit.stage.place.calls"] == 9
+        assert got["fit.stage.place.bytes"] == 9 * block.nbytes
+
+    @pytest.mark.parametrize("nbytes", [1, 2, 1000])
+    def test_a_block_larger_than_the_constant_still_overlaps_with_one_more(self, nbytes):
+        big = SimpleNamespace(nbytes=nbytes * ingest.PLACEMENT_WINDOW_BYTES + 1)
+        made, window = [], PlacementWindow()
+        for i in range(5):
+            window.place(big, lambda b: Placed(made))
+            assert sum(not p.ready for p in made) == min(i + 1, 2)  # 2, never 1 or 0
+
+    def test_an_empty_partition_is_placed_like_any_other(self):
+        host = f32_partitions(44, parts=2) + [np.zeros((0, 6), np.float32)] + f32_partitions(45, parts=2)
+        got = self.moved(lambda: PCA().setK(2).fit(host))
+        assert got["fit.stage.place.calls"] == 5 and got["ingest.place.waits"] == 0
+
+    def test_windows_of_two_passes_share_nothing(self, monkeypatch):
+        block = np.zeros((10, 3), np.float32)
+        self.room_for(monkeypatch, 2, block.nbytes)
+        made = []
+        for _ in range(2):
+            window = PlacementWindow()
+            for _ in range(2):
+                window.place(block, lambda b: Placed(made))
+        assert not any(p.ready for p in made)
+
+    def test_the_windowed_fit_is_the_unbounded_loops_model_to_the_bit(self, monkeypatch):
+        host = f32_partitions(43, parts=9, rows=40, cols=12)
+        self.room_for(monkeypatch, 2, host[0].size * 8)
+        before = tracing.counter_value("ingest.place.waits")
+        windowed = PCA().setK(3).fit(host)
+        assert tracing.counter_value("ingest.place.waits") - before == 7
+        self.room_for(monkeypatch, 1_000, host[0].size * 8)
+        unbounded = PCA().setK(3).fit(host)
+        assert tracing.counter_value("ingest.place.waits") - before == 7
+        assert np.array_equal(np.asarray(windowed.pc), np.asarray(unbounded.pc))
+        assert np.array_equal(
+            np.asarray(windowed.explainedVariance), np.asarray(unbounded.explainedVariance)
+        )
 
 
 class TestDeviceArrayFit:
