@@ -32,6 +32,8 @@ from spark_rapids_ml_tpu.ops.covariance import (
     centered_gram_packed,
     comoment_add_block,
     comoment_init,
+    comoment_resident,
+    count_resident_blocks,
     streaming_mean_and_covariance,
     welford_add_block,
     welford_init,
@@ -61,25 +63,40 @@ from functools import partial as _partial
 
 @_partial(
     jax.jit,
-    static_argnames=("k", "center", "precision", "eigen_solver", "eigen_iters"),
+    static_argnames=("k", "center", "precision", "eigen_solver", "eigen_iters", "blocked"),
 )
-def _pca_fit_device(x, k, center, precision, eigen_solver, eigen_iters):
+def _pca_fit_device(x, k, center, precision, eigen_solver, eigen_iters, blocked=True):
     """The whole PCA fit as ONE XLA program on a device-resident array:
-    column means + fused centered covariance GEMM + eigensolve + explained
-    variance — nothing leaves the device, nothing re-traces across calls
-    (module-level jit keyed on shape + the static config). Its rate is
-    not measured on the chip (no cell: ROADMAP.md Reach 1); the
+    column means and the centred Gram summed in blocks of rows
+    (``ops/covariance.py::comoment_resident``), the eigensolve and the
+    explained variance — nothing leaves the device, nothing re-traces
+    across calls (module-level jit keyed on shape + the static config).
+    Measured in ``pca_3000.device_rows`` (PERF.md section 5); the
     reference's equivalent spans four JNI calls with host copies between
     each (RapidsRowMatrix.scala:149-257, rapidsml_jni.cu:159-356).
+
+    ``blocked=False`` is the mesh fit's form until the four-chip cell
+    (ROADMAP.md Reach 1): rows sharded over the data axis are summed in one
+    contraction a chip, XLA inserting the ``psum`` (a scan over a sharded
+    axis would gather every block).
     """
     n, d = x.shape
-    mean = jnp.mean(x, axis=0) if center else jnp.zeros((d,), dtype=x.dtype)
-    cov = centered_gram(x, mean, precision=precision) / (n - 1)
+    if blocked:
+        _, mean, _, gram = comoment_resident(x, precision=precision, center=center)
+    else:
+        mean = jnp.mean(x, axis=0) if center else jnp.zeros((d,), dtype=x.dtype)
+        gram = centered_gram(x, mean, precision=precision)
+    cov = gram / (n - 1)
+    # What rounding can leave of constant columns: their means are not exact
+    # (a block's mean is a rounded sum), so their Gram is nought only to
+    # epsilon squared of the means' size, of either sign.
+    floor = jnp.finfo(x.dtype).eps ** 2 * jnp.sum(mean * mean)
 
     def ratio(w, total):
         # Zero-variance input (constant rows) must yield zeros, not NaN —
-        # the same `total > 0` guard every host path applies.
-        return jnp.where(total > 0, w / jnp.where(total > 0, total, 1), w)
+        # the `total > 0` guard every host path applies, at rounding's floor.
+        some = total > floor
+        return jnp.where(some, w / jnp.where(some, total, 1), 0.0)
 
     if eigen_solver == "auto" and k < d:
         w, v, _ = eigh_auto(cov, k, max_iters=auto_max_iters(eigen_iters))
@@ -375,6 +392,12 @@ class RowMatrix:
         if n < 2:
             raise ValueError(f"need at least 2 rows, got {n}")
         with TraceRange("compute cov", TraceColor.RED):
+            if self.backend != "pallas" and self.mesh is None:
+                count_resident_blocks(n)
+                state = comoment_resident(
+                    x, precision=self.precision, center=self.mean_centering
+                )
+                return state[3] / (n - 1)
             mean = (
                 jnp.mean(x, axis=0)
                 if self.mean_centering
@@ -387,6 +410,8 @@ class RowMatrix:
 
                 interpret = jax.default_backend() != "tpu"
                 return centered_gram_pallas(x, mean, interpret=interpret) / (n - 1)
+            # rows sharded over a mesh: one contraction a chip and XLA's
+            # psum, until the four-chip cell (ROADMAP.md Reach 1)
             return centered_gram(x, mean, precision=self.precision) / (n - 1)
 
     def _device_array_on_mesh(self):
@@ -688,6 +713,9 @@ class RowMatrix:
                 raise ValueError(f"need at least 2 rows, got {n}")
             if not 1 <= k <= n_cols:
                 raise ValueError(f"k must be in [1, {n_cols}], got {k}")
+            blocked = self.mesh is None
+            if blocked:
+                count_resident_blocks(n)
             with TraceRange("fused device fit", TraceColor.RED), StageRange("solve"):
                 u, explained = _pca_fit_device(
                     self._device_array_on_mesh(),
@@ -696,6 +724,7 @@ class RowMatrix:
                     precision=self.precision,
                     eigen_solver=self.eigen_solver,
                     eigen_iters=self.eigen_iters,
+                    blocked=blocked,
                 )
             return u, explained  # device arrays — the caller decides on host
         shape_known = (

@@ -4,8 +4,15 @@ Param surface mirrors ``org.apache.spark.ml.regression.LinearRegression``:
 ``featuresCol``, ``labelCol``, ``predictionCol``, ``fitIntercept``,
 ``regParam``, ``elasticNetParam`` (0 -> Ridge via the exact normal-equation
 solve; > 0 -> Lasso/elastic net via FISTA on the same sufficient
-statistics — solver="normal" rejects it, as in Spark), ``standardization``,
-``solver`` ("normal" | "auto"). Beyond-the-reference capability.
+statistics — solver="normal" rejects it, as in Spark), ``maxIter`` and
+``tol`` (the proximal loop's; the exact solve has no iteration),
+``standardization``, ``solver`` ("normal" | "auto"). Beyond-the-reference
+capability.
+
+Objective, as Spark states it: minimise ``1/(2n) ||y - X b - b0||^2 +
+regParam (alpha sum_j w1_j |b_j| + (1 - alpha)/2 sum_j w2_j b_j^2)``,
+``w1 = sigma_j``, ``w2 = sigma_j^2`` under ``standardization`` (1
+otherwise), the intercept ``b0 = mean(y) - mean(x)^T b`` unpenalised.
 """
 
 from __future__ import annotations
@@ -25,7 +32,7 @@ from spark_rapids_ml_tpu.core.data import (
 from spark_rapids_ml_tpu.core.estimator import Estimator, Model
 from spark_rapids_ml_tpu.core.ingest import matrix_like, prepare_labels, prepare_rows
 from spark_rapids_ml_tpu.core.lazy_state import LazyHostState
-from spark_rapids_ml_tpu.core.params import Param, Params, toBoolean, toFloat, toString
+from spark_rapids_ml_tpu.core.params import Param, Params, toBoolean, toFloat, toInt, toString
 from spark_rapids_ml_tpu.core.persistence import (
     MLReadable,
     get_and_set_params,
@@ -34,10 +41,15 @@ from spark_rapids_ml_tpu.core.persistence import (
     save_data,
     save_metadata,
 )
+from spark_rapids_ml_tpu.ops.covariance import count_resident_blocks
 from spark_rapids_ml_tpu.ops.linear import (
+    FISTA_POWER_ITERS,
+    Moments,
+    moments_from_raw,
     normal_eq_stats,
     normal_eq_stats_streaming,
     predict_linear,
+    raw_moments,
     regression_metrics,
     solve_elastic_net,
     solve_elastic_net_resumable,
@@ -45,7 +57,12 @@ from spark_rapids_ml_tpu.ops.linear import (
     solve_normal_host,
 )
 from spark_rapids_ml_tpu.core.serving import note_device_cache, serve_rows
-from spark_rapids_ml_tpu.utils.tracing import TraceColor, TraceRange
+from spark_rapids_ml_tpu.utils.tracing import (
+    StageRange,
+    TraceColor,
+    TraceRange,
+    bump_counter,
+)
 
 
 def _predict_kernel(x, coef, intercept, *, precision: str = "highest"):
@@ -66,6 +83,10 @@ class _LinearRegressionParams(Params):
     fitIntercept = Param("_", "fitIntercept", "whether to fit an intercept", toBoolean)
     regParam = Param("_", "regParam", "L2 regularization strength", toFloat)
     elasticNetParam = Param("_", "elasticNetParam", "L1/L2 mixing (0 = pure L2)", toFloat)
+    maxIter = Param("_", "maxIter", "maximum proximal (FISTA) iterations", toInt)
+    tol = Param(
+        "_", "tol", "convergence tolerance of the proximal iterations", toFloat
+    )
     standardization = Param(
         "_", "standardization", "penalize standardized coefficients", toBoolean
     )
@@ -87,6 +108,8 @@ class _LinearRegressionParams(Params):
             fitIntercept=True,
             regParam=0.0,
             elasticNetParam=0.0,
+            maxIter=100,
+            tol=1e-6,
             standardization=True,
             solver="auto",
             precision="auto",
@@ -109,6 +132,12 @@ class _LinearRegressionParams(Params):
 
     def getElasticNetParam(self) -> float:
         return self.getOrDefault(self.elasticNetParam)
+
+    def getMaxIter(self) -> int:
+        return self.getOrDefault(self.maxIter)
+
+    def getTol(self) -> float:
+        return self.getOrDefault(self.tol)
 
     def getStandardization(self) -> bool:
         return self.getOrDefault(self.standardization)
@@ -168,6 +197,24 @@ class LinearRegression(_LinearRegressionParams, Estimator, MLReadable):
         if not 0.0 <= value <= 1.0:
             raise ValueError(f"elasticNetParam must be in [0, 1], got {value}")
         self.set(self.elasticNetParam, value)
+        return self
+
+    def setMaxIter(self, value: int) -> "LinearRegression":
+        """Proximal (FISTA) iterations at most; the exact normal-equation
+        solve (``elasticNetParam`` 0 or ``regParam`` 0) has none."""
+        if value < 0:
+            raise ValueError(f"maxIter must be >= 0, got {value}")
+        self.set(self.maxIter, value)
+        return self
+
+    def setTol(self, value: float) -> "LinearRegression":
+        """The proximal loop stops once the widest change of a coefficient
+        is at most ``tol`` times the widest coefficient (1 at least). A
+        ``tol`` under the compute dtype's epsilon is read as "run
+        ``maxIter`` iterations" (``ops/linear.py::_fista_loop``)."""
+        if value < 0:
+            raise ValueError(f"tol must be >= 0, got {value}")
+        self.set(self.tol, value)
         return self
 
     def setStandardization(self, value: bool) -> "LinearRegression":
@@ -336,11 +383,10 @@ class LinearRegression(_LinearRegressionParams, Estimator, MLReadable):
                 stats = normal_eq_stats_streaming(
                     streaming, dtype=dtype, precision=prec
                 )
-                coef, intercept = self._solve_from_stats(stats, stats[0].shape[0])
-            model = LinearRegressionModel(
-                self.uid, np.asarray(coef, dtype=np.float64), float(intercept)
-            )
-            return self._copyValues(model)
+                solved = self._solve_from_stats(
+                    moments_from_raw(*stats), stats[0].shape[0]
+                )
+            return self._copyValues(LinearRegressionModel(self.uid, *solved))
 
         x_in, y_in = _extract_xy(dataset, self.getFeaturesCol(), self.getLabelCol())
         w_host = extract_weights(dataset, self.getWeightCol())
@@ -391,37 +437,57 @@ class LinearRegression(_LinearRegressionParams, Estimator, MLReadable):
             ys = prepare_labels(
                 y_in, int(xs.shape[0]), n_true=n, mesh=self.mesh, dtype=xs.dtype
             )
-            # Uniform unmasked case: skip the x*mask pass (bytes-bound at
-            # small d — the multiply would double the HBM traffic).
-            if w_host is None and self.mesh is None:
-                mask = None
-            stats = normal_eq_stats(xs, ys, mask, precision=prec)
-            # Gang deploy mode: the solve below reads the O(d²) statistics
-            # on the host — replicate them so every member solves the
-            # identical whole-dataset normal equations (no-op otherwise).
-            from spark_rapids_ml_tpu.parallel.distributed import (
-                replicate_for_host,
-            )
+            # The stage times the dispatches (the blocked sum, then the
+            # solve): nothing here waits for the device.
+            with StageRange("solve"):
+                with TraceRange("linreg moments", TraceColor.GREEN):
+                    if self.mesh is None:
+                        # every real row at weight 1: no per-row weight
+                        if w_host is None:
+                            mask = None
+                        count_resident_blocks(int(xs.shape[0]))
+                        moments = normal_eq_stats(xs, ys, mask, precision=prec)
+                    else:
+                        # rows sharded over a mesh: one contraction a chip
+                        # and XLA's psum, until the four-chip cell
+                        # (ROADMAP.md Reach 1)
+                        moments = moments_from_raw(
+                            *raw_moments(xs, ys, mask, precision=prec)
+                        )
+                # Gang deploy mode: the solve below reads the O(d²)
+                # statistics on the host — replicate them so every member
+                # solves the identical whole-dataset normal equations
+                # (no-op otherwise).
+                from spark_rapids_ml_tpu.parallel.distributed import (
+                    replicate_for_host,
+                )
 
-            stats = replicate_for_host(self.mesh, *stats)
-            coef, intercept = self._solve_from_stats(stats, d)
+                moments = Moments(*replicate_for_host(self.mesh, *moments))
+                solved = self._solve_from_stats(moments, d)
 
         # Solve outputs stay device-resident; the model's host float64
         # views convert lazily (the PCAModel contract).
-        model = LinearRegressionModel(self.uid, coef, intercept)
-        return self._copyValues(model)
+        return self._copyValues(LinearRegressionModel(self.uid, *solved))
 
-    def _solve_from_stats(self, stats, d: int):
+    def _solve_from_stats(self, moments, d: int) -> tuple:
         """Dispatch the solver on the accumulated sufficient statistics —
         the one home of the exact-vs-proximal routing (shared by the
-        in-memory, mesh, and streaming fit paths)."""
-        xtx, xty, x_sum, y_sum, yty, count = stats
+        in-memory, mesh, and streaming fit paths). Returns the model's
+        arguments after its uid: (coefficients, intercept) from the exact
+        solve, and (numIter, finalObjective, finalGradient) after them
+        from the proximal one."""
+        moments = moments.narrowed(d)
         init_coef = self._initial_coef
         if init_coef is not None and init_coef.shape[0] != d:
             raise ValueError(
                 f"initial model has {init_coef.shape[0]} coefficients, "
                 f"data has {d} features"
             )
+        solver_args = dict(
+            reg_param=self.getRegParam(),
+            fit_intercept=self.getFitIntercept(),
+            standardization=self.getStandardization(),
+        )
         if not self._uses_fista():
             if init_coef is not None:
                 raise ValueError(
@@ -431,54 +497,30 @@ class LinearRegression(_LinearRegressionParams, Estimator, MLReadable):
                 )
             # Zero effective penalty: the exact (Cholesky) solve, not a
             # fixed-step proximal approximation of the same objective.
-            return solve_normal(
-                xtx[:d, :d],
-                xty[:d],
-                x_sum[:d],
-                y_sum,
-                count,
-                reg_param=self.getRegParam(),
-                fit_intercept=self.getFitIntercept(),
-                standardization=self.getStandardization(),
-            )
+            return solve_normal(moments, **solver_args)
         # L1/elastic net: FISTA on the same sufficient statistics — one
-        # data GEMM pass, then O(d^2) proximal iterations (Spark reaches
-        # this case via OWL-QN over the data). With the TPUML_CHECKPOINT_*
-        # knobs set the proximal loop runs segmented with async snapshots
-        # and resumes mid-solve (robustness/checkpoint.py); the iterative
-        # loop — not the one-GEMM stats pass — is what preemption loses.
-        ckpt = self._fit_checkpointer(
-            "linreg.fista", data=(xtx[:d, :d], xty[:d], x_sum[:d], y_sum, count)
-        )
-        if ckpt is not None:
-            coef, intercept, _ = solve_elastic_net_resumable(
-                xtx[:d, :d],
-                xty[:d],
-                x_sum[:d],
-                y_sum,
-                count,
-                reg_param=self.getRegParam(),
-                elastic_net_param=self.getElasticNetParam(),
-                checkpointer=ckpt,
-                fit_intercept=self.getFitIntercept(),
-                standardization=self.getStandardization(),
-                init_coef=init_coef,
-                mesh=self.mesh,
-            )
-            return coef, intercept
-        coef, intercept, _ = solve_elastic_net(
-            xtx[:d, :d],
-            xty[:d],
-            x_sum[:d],
-            y_sum,
-            count,
-            reg_param=self.getRegParam(),
+        # blocked pass over the data, then O(d^2) proximal iterations
+        # (Spark reaches this case via OWL-QN over the data). With the
+        # TPUML_CHECKPOINT_* knobs set the proximal loop runs segmented
+        # with async snapshots and resumes mid-solve
+        # (robustness/checkpoint.py); the iterative loop — not the one
+        # stats pass — is what preemption loses.
+        solver_args.update(
             elastic_net_param=self.getElasticNetParam(),
-            fit_intercept=self.getFitIntercept(),
-            standardization=self.getStandardization(),
+            max_iter=self.getMaxIter(),
+            tol=self.getTol(),
             init_coef=init_coef,
         )
-        return coef, intercept
+        ckpt = self._fit_checkpointer("linreg.fista", data=tuple(moments))
+        bump_counter("linreg.fista.power_iters", FISTA_POWER_ITERS)
+        with TraceRange("linreg prox", TraceColor.PURPLE):
+            if ckpt is not None:
+                return tuple(
+                    solve_elastic_net_resumable(
+                        moments, checkpointer=ckpt, mesh=self.mesh, **solver_args
+                    )
+                )
+            return tuple(solve_elastic_net(moments, **solver_args))
 
 
 def _streaming_blocks(dataset):
@@ -587,7 +629,10 @@ class LinearRegressionModel(_LinearRegressionParams, Model, LazyHostState):
     resident fit; host float64 views convert lazily and pickling
     materializes host state (core/lazy_state.LazyHostState)."""
 
-    _lazy_host_fields = {"_coef_raw": ("_coef_np", np.float64)}
+    _lazy_host_fields = {
+        "_coef_raw": ("_coef_np", np.float64),
+        "_grad_raw": ("_grad_np", np.float64),
+    }
     _pickle_clear = ("_coef_dev",)
 
     def __init__(
@@ -595,31 +640,81 @@ class LinearRegressionModel(_LinearRegressionParams, Model, LazyHostState):
         uid: Optional[str] = None,
         coefficients: Optional[np.ndarray] = None,
         intercept: float = 0.0,
+        numIter: Optional[int] = None,
+        finalObjective: Optional[float] = None,
+        finalGradient: Optional[np.ndarray] = None,
     ):
         super().__init__(uid)
         self._coef_raw = coefficients
         self._coef_np: Optional[np.ndarray] = None
         self._coef_dev = None
-        self._intercept_raw = intercept
+        # (intercept, numIter, finalObjective): host numbers, or a
+        # device-resident fit's scalars until the host first reads one
+        self._scalars_raw = (intercept, numIter, finalObjective)
+        self._grad_raw = finalGradient
+        self._grad_np: Optional[np.ndarray] = None
 
     def __getstate__(self):
-        state = super().__getstate__()
-        state["_intercept_raw"] = self.intercept
-        return state
+        self._scalars()  # device scalars never pickle
+        return super().__getstate__()
 
     @property
     def coefficients(self) -> Optional[np.ndarray]:
         return self._lazy_host_view("_coef_raw")
 
+    def _scalars(self) -> tuple:
+        """(intercept, numIter, finalObjective) on the host. A
+        device-resident fit's scalars cross together on the first read of
+        any of them (the fit itself never waits for them); that read is
+        also where a proximal fit's ``linreg.fista.iters`` counter moves,
+        here and in the fit's report."""
+        raw = self._scalars_raw
+        if any(is_device_array(v) for v in raw):
+            intercept, n_iter, objective = jax.device_get(raw)
+            self._scalars_raw = (
+                float(intercept),
+                None if n_iter is None else int(n_iter),
+                None if objective is None else float(objective),
+            )
+            if is_device_array(raw[1]):  # this process's proximal fit, first read
+                bump_counter("linreg.fista.iters", int(n_iter))
+                if self._fit_report is not None:
+                    self._fit_report.counters["linreg.fista.iters"] = int(n_iter)
+        return self._scalars_raw
+
     @property
     def intercept(self) -> float:
-        if not isinstance(self._intercept_raw, float):
-            self._intercept_raw = float(self._intercept_raw)
-        return self._intercept_raw
+        return float(self._scalars()[0])
+
+    @property
+    def numIter(self) -> Optional[int]:
+        """Proximal (FISTA) iterations the fit ran: ``maxIter`` exactly
+        under a ``tol`` below the dtype's epsilon. None after the exact
+        normal-equation solve and for a model no fit of this process made."""
+        return self._scalars()[1]
+
+    @property
+    def finalObjective(self) -> Optional[float]:
+        """Spark's objective (module docstring) at the returned
+        coefficients and intercept, from the fit's moments: the last entry
+        of Spark's ``objectiveHistory``. None off the proximal path."""
+        return self._scalars()[2]
+
+    @property
+    def finalGradient(self) -> Optional[np.ndarray]:
+        """(d,) gradient of the objective's smooth part (the least-squares
+        term and the L2 penalty) with respect to the returned coefficients,
+        ``(Xc^T Xc b - Xc^T yc) / n + regParam (1 - alpha) w2 b`` from the
+        fit's moments: what the next proximal step would start from, so a
+        check of the moments' arithmetic against an independent gradient at
+        the same point. None off the proximal path."""
+        return self._lazy_host_view("_grad_raw")
 
     def copy(self, extra=None) -> "LinearRegressionModel":
         """Model.copy preserves fitted state (Spark's Model.copy contract)."""
-        that = LinearRegressionModel(self.uid, self._coef_raw, self._intercept_raw)
+        that = LinearRegressionModel(
+            self.uid, self._coef_raw, *self._scalars_raw, self._grad_raw
+        )
         return self._copyValues(that, extra)
 
     def predict(self, x) -> np.ndarray:
@@ -658,7 +753,7 @@ class LinearRegressionModel(_LinearRegressionParams, Model, LazyHostState):
                 if is_device_array(self._coef_raw)
                 else jnp.asarray(self.coefficients)
             )
-            self._coef_dev = (coef, jnp.asarray(self._intercept_raw))
+            self._coef_dev = (coef, jnp.asarray(self._scalars_raw[0]))
             note_device_cache(self)
         return self._coef_dev
 

@@ -27,59 +27,35 @@ from spark_rapids_ml_tpu.ops.precision import make_dot
 
 @partial(jax.jit, static_argnames=("precision",))
 def centered_gram(x: jax.Array, mean: jax.Array, precision: str = "highest") -> jax.Array:
-    """(x − mean)ᵀ(x − mean) — the per-partition covariance partial.
+    """(x - mean)^T (x - mean) of ONE block of rows: the kernel inside the
+    co-moment step (:func:`comoment_add_block` for a host partition,
+    :func:`comoment_resident` for a block of resident rows), which calls it
+    on the block's OWN means.
 
-    This is the distributed unit of work: each data shard computes its local
-    centered Gram against the *global* mean (broadcast, like
-    RapidsRowMatrix.scala:162), and partials are summed by a collective.
+    One float32 contraction over all the rows of a large matrix reads low
+    on the diagonal on the chip's matrix unit (PERF.md section 6, PR 24:
+    -2.35e-5 at 500,000 rows, +1.1e-7 in blocks of 10,000), so no fit hands
+    this more than :data:`GRAM_BLOCK_ROWS` resident rows at once; a host
+    partition is as tall as its caller made it.
     """
     b = x - mean
     return make_dot(precision)(b.T, b)
 
 
-@partial(jax.jit, static_argnames=("precision",))
 def mean_and_covariance(x: jax.Array, precision: str = "highest"):
-    """Single-device fused path: returns (column means, covariance).
+    """Single-device path: returns (column means, covariance) of resident
+    rows, summed in blocks (:func:`comoment_resident`).
 
     Covariance normalized by (n − 1), matching the spr/treeAggregate path
     (RapidsRowMatrix.scala:240-246) — the statistically correct sample
     covariance.
     """
-    n = x.shape[0]
-    mean = jnp.mean(x, axis=0)
-    cov = centered_gram(x, mean, precision=precision) / (n - 1)
-    return mean, cov
+    count, mean, mean_lo, m = comoment_resident(jnp.asarray(x), precision=precision)
+    return mean + mean_lo, m / (count - 1)
 
 
 def covariance(x: jax.Array, precision: str = "highest") -> jax.Array:
     return mean_and_covariance(x, precision=precision)[1]
-
-
-@partial(jax.jit, static_argnames=("block_rows", "precision"))
-def centered_gram_blocked(
-    x: jax.Array, mean: jax.Array, block_rows: int = 4096, precision: str = "highest"
-) -> jax.Array:
-    """Streaming centered Gram over row blocks via lax.scan.
-
-    For row counts whose (n, d) activation would not fit HBM alongside the
-    result, accumulate BᵀB block-by-block. Padding rows are filled with
-    ``mean`` so their centered contribution is exactly zero — no masking
-    needed inside the scan body, keeping the MXU matmul dense and static.
-    """
-    n, d = x.shape
-    nb = -(-n // block_rows)
-    pad = nb * block_rows - n
-    x = jnp.concatenate([x, jnp.broadcast_to(mean, (pad, d))], axis=0) if pad else x
-    blocks = x.reshape(nb, block_rows, d)
-    dot = make_dot(precision)
-
-    def body(acc, blk):
-        b = blk - mean
-        return acc + dot(b.T, b), None
-
-    acc0 = jnp.zeros((d, d), dtype=x.dtype)
-    acc, _ = jax.lax.scan(body, acc0, blocks)
-    return acc
 
 
 @jax.jit
@@ -304,7 +280,8 @@ def comoment_merge(a: tuple, b: tuple) -> tuple:
     count_a, mean_a, lo_a, m_a = a
     count_b, mean_b, lo_b, m_b = b
     count = count_a + count_b
-    weight = count_b / jnp.maximum(count, 1)
+    # counts are weight sums under ``weightCol``: any positive total divides
+    weight = count_b / jnp.where(count > 0, count, 1)
     # Means that lie close together against their size (where their
     # rounding matters) differ by an exact float; the trailing pieces ride
     # along, and what the new leading piece rounds off joins them.
@@ -315,6 +292,46 @@ def comoment_merge(a: tuple, b: tuple) -> tuple:
     lo = lo_a + delta_lo * weight + (shift - (mean - mean_a))
     m = m_a + m_b + jnp.outer(delta, delta) * (count_a * weight)
     return (count, mean, lo, m)
+
+
+def _block_comoments(x, weights, precision, backend="xla", interpret=False, center=True):
+    """The co-moment state ``(count, mean, mean_lo, M)`` of ONE block of
+    rows alone: its own column means in two pieces and its Gram centred on
+    them (:func:`centered_gram`, or the Pallas kernel under
+    ``backend="pallas"``). ``weights`` (n_b,) or None: a per-row weight of
+    the block (``weightCol``; nought for a padding row), so counts are
+    weight sums and means and Gram weighted ones. ``center=False``: the
+    block's raw second moment about zero, the means left at nought."""
+    n_b, d = x.shape
+    if not center:
+        zero = jnp.zeros((d,), dtype=x.dtype)
+        rows = x if weights is None else x * weights[:, None]
+        count = n_b if weights is None else jnp.sum(weights)
+        return count, zero, zero, make_dot(precision)(rows.T, x)
+    if weights is None:
+        count = n_b
+        # The division INSIDE the sum, so that the means leave a reduction
+        # as one rounded array: as ``sum / n_b`` the compiler recomputes the
+        # division in each consumer's fusion, where a fused multiply-add
+        # (XLA:CPU) skips its rounding and the uses disagree by it.
+        mean_b = jnp.sum(x * (1.0 / n_b), axis=0)
+        # what that rounding left: the rows' mean is mean_b + lo_b
+        lo_b = jnp.sum((x - mean_b) * (1.0 / n_b), axis=0)
+        if backend == "pallas":
+            from spark_rapids_ml_tpu.ops.pallas.covariance import centered_gram_pallas
+
+            m_b = centered_gram_pallas(x, mean_b, interpret=interpret)
+        else:
+            m_b = centered_gram(x, mean_b, precision=precision)
+    else:
+        count = jnp.sum(weights)
+        # a block of padding alone weighs nothing and leaves the state as it is
+        share = (weights * jnp.where(count > 0, 1.0 / count, 0.0))[:, None]
+        mean_b = jnp.sum(x * share, axis=0)
+        lo_b = jnp.sum((x - mean_b) * share, axis=0)
+        b = x - mean_b
+        m_b = make_dot(precision)((b * weights[:, None]).T, b)
+    return count, mean_b, lo_b, m_b - jnp.outer(lo_b, lo_b) * count
 
 
 @partial(
@@ -330,28 +347,82 @@ def comoment_add_block(
     interpret: bool = False,
 ) -> tuple:
     """One partition into the state, one program: the block's own column
-    means, its Gram centred on THEM (the kernel the two-pass route ran on
-    the global mean: :func:`centered_gram`, or the Pallas kernel under
-    ``backend="pallas"``), then :func:`comoment_merge`. Centring on the
-    block's mean, not on the first block's as :func:`shifted_block_scan`
-    does, leaves no ``n * delta delta^T`` to cancel at the end when
-    partitions are sorted or clustered. ``state`` is donated: the (d, d)
-    accumulator is updated in place."""
+    means, its Gram centred on THEM, then :func:`comoment_merge`. Centring
+    on the block's mean, not on the first block's as
+    :func:`shifted_block_scan` does, leaves no ``n * delta delta^T`` to
+    cancel at the end when partitions are sorted or clustered. ``state`` is
+    donated: the (d, d) accumulator is updated in place."""
     n_b = x.shape[0]
     if n_b == 0:  # static shape: an empty partition contributes nothing
         return state
-    # The division INSIDE the sum, so that the means leave a reduction as
-    # one rounded array: as ``sum / n_b`` the compiler recomputes the
-    # division in each consumer's fusion, where a fused multiply-add
-    # (XLA:CPU) skips its rounding and the uses disagree by it.
-    mean_b = jnp.sum(x * (1.0 / n_b), axis=0)
-    # what that rounding left: the rows' mean is mean_b + lo_b
-    lo_b = jnp.sum((x - mean_b) * (1.0 / n_b), axis=0)
-    if backend == "pallas":
-        from spark_rapids_ml_tpu.ops.pallas.covariance import centered_gram_pallas
+    count, mean_b, lo_b, m_b = _block_comoments(x, None, precision, backend, interpret)
+    return comoment_merge(state, (jnp.asarray(count, state[0].dtype), mean_b, lo_b, m_b))
 
-        m_b = centered_gram_pallas(x, mean_b, interpret=interpret)
-    else:
-        m_b = centered_gram(x, mean_b, precision=precision)
-    m_b = m_b - jnp.outer(lo_b, lo_b) * n_b
-    return comoment_merge(state, (jnp.asarray(n_b, state[0].dtype), mean_b, lo_b, m_b))
+
+#: Rows of a resident matrix summed in one float32 contraction. The size
+#: with chip readings (PERF.md sections 5 and 6: a step of 10,000 x 3000
+#: rows is 6.84 ms and reads +1.1e-7 on the Gram's diagonal where one
+#: contraction over 500,000 rows reads -2.35e-5).
+GRAM_BLOCK_ROWS = 10_000
+
+
+def count_resident_blocks(n: int) -> None:
+    """``gram.blocks`` / ``gram.rows``: what one :func:`comoment_resident`
+    over ``n`` rows adds up, from the shape, at dispatch (never read back)."""
+    from spark_rapids_ml_tpu.utils.tracing import bump_counter
+
+    bump_counter("gram.blocks", -(-n // GRAM_BLOCK_ROWS))
+    bump_counter("gram.rows", n)
+
+
+@partial(jax.jit, static_argnames=("precision", "center"))
+def comoment_resident(
+    x: jax.Array,
+    y: jax.Array | None = None,
+    weights: jax.Array | None = None,
+    precision: str = "highest",
+    center: bool = True,
+) -> tuple:
+    """The co-moment state ``(count, mean, mean_lo, M)`` of rows that are
+    RESIDENT on one device, one program: a ``lax.scan`` over blocks of
+    :data:`GRAM_BLOCK_ROWS` rows whose body is the step
+    :func:`comoment_add_block` applies to a host partition (the block's own
+    means, its Gram centred on them at ``precision``, Chan's merge), the
+    last, short block a static remainder after the scan. Every fit on
+    resident rows sums through here: the fused PCA fit and ``RowMatrix``'s
+    resident route (``linalg/row_matrix.py``), and linear regression's
+    sufficient statistics (``ops/linear.py::normal_eq_stats``).
+
+    ``y`` (n,): a label column riding along as column ``d`` of each block,
+    so the state is that of ``[x | y]``: ``M[:d, d]`` is ``Xc^T yc``,
+    ``M[d, d]`` is ``yc^T yc`` and ``mean[d]`` the labels' mean, by the
+    same step and the same merge. ``weights`` (n,): per-row weights (see
+    :func:`_block_comoments`). ``center=False``: the raw second moment
+    about zero, in blocks, the means left at nought.
+
+    Blocks are sliced in place (a reshape to (blocks, rows, d) would copy
+    all the rows)."""
+    n, d = x.shape
+    width = d if y is None else d + 1
+    state = comoment_init(width, dtype=x.dtype)
+    if n == 0:
+        return state
+    step = min(n, GRAM_BLOCK_ROWS)
+
+    def add(state, lo, rows):
+        def take(a):
+            return jax.lax.dynamic_slice_in_dim(a, lo, rows, axis=0)
+
+        blk = take(x)
+        if y is not None:
+            blk = jnp.concatenate([blk, take(y).astype(x.dtype)[:, None]], axis=1)
+        w = None if weights is None else take(weights).astype(x.dtype)
+        count, *rest = _block_comoments(blk, w, precision, center=center)
+        return comoment_merge(state, (jnp.asarray(count, x.dtype), *rest))
+
+    state, _ = jax.lax.scan(
+        lambda s, i: (add(s, i * step, step), None), state, jnp.arange(n // step)
+    )
+    if n % step:
+        state = add(state, n - n % step, n % step)
+    return state

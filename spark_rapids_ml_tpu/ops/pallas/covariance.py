@@ -1,8 +1,8 @@
 """Pallas TPU kernel: fused center + covariance accumulation.
 
 The hot op of PCA fit (SURVEY.md §3.1 hot loops 1+2: per-row centering +
-C = BᵀB). The XLA scan version (ops.covariance.centered_gram_blocked) writes
-each centered block back to HBM before the matmul reads it; this kernel keeps
+C = BᵀB). The XLA scan version (ops.covariance.comoment_resident) copies
+each block of rows out of the matrix before the matmul reads it; this kernel keeps
 the centered tile AND the (d, d) accumulator in VMEM — the only HBM traffic
 is the single streaming read of X. Grid steps run sequentially on a TPU
 core, so the revisited accumulator block is race-free.
@@ -75,7 +75,7 @@ def centered_gram_pallas(
     if max_block < 8:
         raise ValueError(
             f"d={d} needs a ({dp_}, {dp_}) VMEM accumulator that exceeds the "
-            "~16 MB VMEM budget; use ops.covariance.centered_gram_blocked"
+            "~16 MB VMEM budget; use backend='xla' (ops.covariance.comoment_resident)"
         )
     # Sublane alignment applies to the user-passed tile size too, not just
     # the VMEM clamp — Mosaic rejects non-final block tiles that are not a

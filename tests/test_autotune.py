@@ -569,8 +569,8 @@ class TestDoubleBuffer:
         import jax.numpy as jnp
 
         from spark_rapids_ml_tpu.ops.linear import (
-            normal_eq_stats,
             normal_eq_stats_streaming,
+            raw_moments,
         )
 
         blocks = [
@@ -586,7 +586,7 @@ class TestDoubleBuffer:
             xj = jnp.asarray(np.ascontiguousarray(xb), dtype=np.float64)
             yj = jnp.asarray(np.ascontiguousarray(yb), dtype=np.float64)
             mask = jnp.ones(xj.shape[0], dtype=xj.dtype)
-            stats = normal_eq_stats(xj, yj, mask, precision="highest")
+            stats = raw_moments(xj, yj, mask, precision="highest")
             acc = stats if acc is None else tuple(
                 a + s for a, s in zip(acc, stats)
             )

@@ -30,9 +30,9 @@ class TestLinearElasticNet:
         x, y, _ = _sparse_problem(rng)
         reg = 0.1
         stats = normal_eq_stats(jnp.asarray(x), jnp.asarray(y), jnp.ones(len(y)))
-        coef, intercept, n_iter = solve_elastic_net(
-            *stats[:4], stats[5], reg_param=reg, elastic_net_param=1.0,
-            standardization=False,
+        coef, intercept, n_iter, _, _ = solve_elastic_net(
+            stats, reg_param=reg, elastic_net_param=1.0,
+            standardization=False, max_iter=2000, tol=1e-9,
         )
         skl = linear_model.Lasso(alpha=reg, max_iter=50_000, tol=1e-10).fit(x, y)
         np.testing.assert_allclose(np.asarray(coef), skl.coef_, atol=1e-4)
@@ -43,9 +43,9 @@ class TestLinearElasticNet:
         x, y, _ = _sparse_problem(rng, n=400, d=10)
         reg, l1_ratio = 0.2, 0.5
         stats = normal_eq_stats(jnp.asarray(x), jnp.asarray(y), jnp.ones(len(y)))
-        coef, intercept, _ = solve_elastic_net(
-            *stats[:4], stats[5], reg_param=reg, elastic_net_param=l1_ratio,
-            standardization=False,
+        coef, intercept, *_ = solve_elastic_net(
+            stats, reg_param=reg, elastic_net_param=l1_ratio,
+            standardization=False, max_iter=2000, tol=1e-9,
         )
         skl = linear_model.ElasticNet(
             alpha=reg, l1_ratio=l1_ratio, max_iter=50_000, tol=1e-10
@@ -57,10 +57,10 @@ class TestLinearElasticNet:
 
         x, y, _ = _sparse_problem(rng)
         stats = normal_eq_stats(jnp.asarray(x), jnp.asarray(y), jnp.ones(len(y)))
-        c_enet, i_enet, _ = solve_elastic_net(
-            *stats[:4], stats[5], reg_param=0.3, elastic_net_param=0.0,
+        c_enet, i_enet, *_ = solve_elastic_net(
+            stats, reg_param=0.3, elastic_net_param=0.0, max_iter=2000, tol=1e-9,
         )
-        c_ridge, i_ridge = solve_normal(*stats[:4], stats[5], reg_param=0.3)
+        c_ridge, i_ridge = solve_normal(stats, reg_param=0.3)
         np.testing.assert_allclose(np.asarray(c_enet), np.asarray(c_ridge), atol=1e-5)
         assert abs(float(i_enet) - float(i_ridge)) < 1e-5
 

@@ -19,7 +19,7 @@ from spark_rapids_ml_tpu.ops import (
 )
 from spark_rapids_ml_tpu.ops.covariance import (
     centered_gram,
-    centered_gram_blocked,
+    comoment_resident,
     centered_gram_packed,
     comoment_add_block,
     comoment_init,
@@ -225,22 +225,50 @@ class TestCovariance:
         x = rng.normal(size=(40, 6))
         np.testing.assert_allclose(covariance(x), np.cov(x, rowvar=False), atol=1e-10)
 
-    def test_blocked_matches_dense(self, rng):
-        x = rng.normal(size=(1000, 16))
-        mean = x.mean(axis=0)
-        dense = centered_gram(x, mean)
-        blocked = centered_gram_blocked(x, mean, block_rows=128)
-        np.testing.assert_allclose(blocked, dense, atol=1e-8)
-
-    def test_blocked_padding_is_exact_zero_contribution(self, rng):
-        """n not a multiple of block_rows: mean-padding adds nothing."""
-        x = rng.normal(size=(130, 4))
-        mean = x.mean(axis=0)
-        np.testing.assert_allclose(
-            centered_gram_blocked(x, mean, block_rows=64),
-            centered_gram(x, mean),
-            atol=1e-10,
+    @pytest.mark.parametrize("with_label", [False, True], ids=["rows", "rows+label"])
+    @pytest.mark.parametrize(
+        "n", [20_000, 23_457, 640], ids=["two-blocks", "remainder", "one-short-block"]
+    )
+    def test_resident_blocks_match_float64_two_pass(self, rng, n, with_label):
+        """The blocked co-moment sum over resident rows (blocks of 10,000,
+        the short last block a static remainder) against a float64 two-pass
+        covariance, on float32 columns a thousand spreads off zero (the
+        case ``comoment_init``'s docstring names), with and without the
+        label riding along as one more column."""
+        d = 5
+        x = (rng.normal(size=(n, d)) * np.arange(1, d + 1) + 1000.0).astype(np.float32)
+        y = (x @ rng.normal(size=d) + rng.normal(size=n)).astype(np.float32)
+        cols = np.column_stack([x, y]) if with_label else x
+        count, mean, mean_lo, m = comoment_resident(
+            jnp.asarray(x), jnp.asarray(y) if with_label else None
         )
+        exact = cols.astype(np.float64)
+        centred = exact - exact.mean(axis=0)
+        want = centred.T @ centred
+        scale = np.sqrt(np.outer(np.diag(want), np.diag(want)))
+        assert float(count) == n
+        assert np.asarray(m).dtype == np.float32
+        assert np.max(np.abs(np.asarray(m, np.float64) - want) / scale) < 1e-6
+        got_mean = np.asarray(mean, np.float64) + np.asarray(mean_lo, np.float64)
+        np.testing.assert_allclose(got_mean, exact.mean(axis=0), rtol=1e-7)
+
+    def test_resident_blocks_weighted_and_uncentred(self, rng):
+        """Per-row weights (``weightCol``; nought for padding) enter the
+        step as the block's weights; ``center=False`` sums the raw second
+        moment in the same blocks."""
+        n, d = 12_345, 4
+        x = rng.normal(size=(n, d)) + 3.0
+        w = np.where(np.arange(n) < n - 345, rng.uniform(0.1, 2.0, size=n), 0.0)
+        count, mean, mean_lo, m = comoment_resident(jnp.asarray(x), weights=jnp.asarray(w))
+        mu = (w[:, None] * x).sum(axis=0) / w.sum()
+        np.testing.assert_allclose(float(count), w.sum(), rtol=1e-12)
+        np.testing.assert_allclose(np.asarray(mean + mean_lo), mu, rtol=1e-12)
+        np.testing.assert_allclose(
+            np.asarray(m), ((x - mu) * w[:, None]).T @ (x - mu), rtol=1e-10
+        )
+        raw = comoment_resident(jnp.asarray(x), center=False)
+        np.testing.assert_allclose(np.asarray(raw[3]), x.T @ x, rtol=1e-12)
+        assert not np.any(np.asarray(raw[1]))
 
     def test_packed_matches_dense(self, rng):
         x = rng.normal(size=(30, 5))

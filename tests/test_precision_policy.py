@@ -223,8 +223,8 @@ class TestDotParity:
         y = jnp.asarray(rng.normal(size=(300,)).astype(np.float32))
         ref = normal_eq_stats(x, y, None, precision="f32")
         got = normal_eq_stats(x, y, None, precision=mode)
-        assert _rel_err(got[0], ref[0]) <= REL_TOL[mode]  # xtx
-        assert _rel_err(got[1], ref[1]) <= REL_TOL[mode]  # xty
+        assert _rel_err(got.a, ref.a) <= REL_TOL[mode]  # Xc^T Xc
+        assert _rel_err(got.b, ref.b) <= REL_TOL[mode]  # Xc^T yc
         coef = jnp.asarray(rng.normal(size=(16,)).astype(np.float32))
         pref = predict_linear(x, coef, 0.5, precision="f32")
         assert _rel_err(predict_linear(x, coef, 0.5, precision=mode), pref) <= REL_TOL[mode]

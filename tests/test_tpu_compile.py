@@ -229,3 +229,19 @@ class TestCovariance:
         mem = compiled.memory_analysis()
         assert mem.argument_size_in_bytes == 1_000_000 * 1024 * 4
         assert mem.temp_size_in_bytes < (4 << 30)  # no second copy of X
+
+    def test_blocked_moments_with_labels_at_the_linreg_cell_size(self, v5e):
+        """``normal_eq_stats`` at ``linreg_3000.device_rows``'s shape: the
+        blocked sum of ``[X | y]`` beside its 6.0 GB of rows holds a block
+        and the (d + 1, d + 1) accumulator, never a second copy of X (a
+        reshape to blocks, or a concatenation of the label column outside
+        the scan, would be one)."""
+        from spark_rapids_ml_tpu.ops.linear import normal_eq_stats
+
+        n, d = 500_000, 3000
+        compiled = normal_eq_stats.lower(
+            _f32((n, d), v5e), _f32((n,), v5e), None, precision="highest"
+        ).compile()
+        mem = compiled.memory_analysis()
+        assert mem.argument_size_in_bytes >= n * d * 4
+        assert mem.temp_size_in_bytes < (1 << 30)
