@@ -391,19 +391,18 @@ def _get_program(
         donate_argnums=(0,) if donate else (),
     )
     compile_t0 = time.perf_counter()
-    with TraceRange("serving compile", TraceColor.YELLOW):
-        with warnings.catch_warnings(record=True) as caught:
-            # A donated scratch whose bytes no output can alias is a
-            # no-op, not an error — drop jax's warning, keep a counter.
-            warnings.simplefilter("always")
-            exe = jitted.lower(x_spec, *arg_specs, **static).compile()
-        for w in caught:
-            if "donated buffers" in str(w.message):
-                bump_counter("serving.donate.unusable")
-            else:  # pragma: no cover - foreign warnings pass through
-                warnings.warn_explicit(
-                    w.message, w.category, w.filename, w.lineno
-                )
+    with warnings.catch_warnings(record=True) as caught:
+        # A donated scratch whose bytes no output can alias is a
+        # no-op, not an error — drop jax's warning, keep a counter.
+        warnings.simplefilter("always")
+        exe = jitted.lower(x_spec, *arg_specs, **static).compile()
+    for w in caught:
+        if "donated buffers" in str(w.message):
+            bump_counter("serving.donate.unusable")
+        else:  # pragma: no cover - foreign warnings pass through
+            warnings.warn_explicit(
+                w.message, w.category, w.filename, w.lineno
+            )
     lkey = None
     if ledger is not None:
         # Classify the compile (retrace watchdog) + capture XLA's cost

@@ -9,10 +9,13 @@ and the finished :class:`RunReport` hangs off the model
 
   - the **stage-timing tree** rebuilt from the run's spans (TraceRange
     now records span id / parent / depth / ok / exception type — the
-    ingest, H2D, compile, solver-segment and collective ranges nest the
-    way the code did);
+    ingest, H2D, solver-segment and collective ranges nest the way the
+    code did, and each program the call compiled or loaded from the
+    persistent cache is a ``program <name>`` span under the range that
+    asked for it);
   - aggregate **stage totals** (seconds and call counts per range name);
-  - the **counter deltas** the call produced (compile counts, checkpoint
+  - the **counter deltas** the call produced (programs traced, lowered,
+    compiled or loaded from the persistent cache, checkpoint
     writes/restores, retry attempts, serving cache traffic);
   - **device memory stats** (``jax.local_devices()[i].memory_stats()``
     where the backend provides them), also published as
@@ -36,7 +39,7 @@ from spark_rapids_ml_tpu.utils.lockcheck import make_lock
 
 #: Counter prefixes a report folds into its summary.
 _REPORT_PREFIXES = ("serving.", "checkpoint.", "retry.", "gang.", "ingest.",
-                    "persistence.", "degrade.")
+                    "persistence.", "degrade.", "program.")
 
 
 def device_memory_stats() -> Dict[str, Dict[str, int]]:
@@ -143,10 +146,12 @@ class RunReport:
         return stage_totals(self.spans)
 
     def compile_count(self) -> int:
-        """Compiles attributed to this run: compile-named spans plus the
-        serving-layer compile counter delta (whichever layer saw them)."""
-        from_spans = sum(1 for s in self.spans if "compile" in s["name"])
-        return max(from_spans, int(self.counters.get("serving.compile", 0)))
+        """Executables this run obtained: XLA compiles plus persistent-cache
+        loads (``program.compile.calls`` + ``program.cache_load.calls``, the
+        program-load listener of ``utils/tracing.py``), whichever layer
+        asked for them."""
+        return int(self.counters.get("program.compile.calls", 0)
+                   + self.counters.get("program.cache_load.calls", 0))
 
     def checkpoint_activity(self) -> Dict[str, float]:
         return {

@@ -24,11 +24,20 @@ everything a fit or serving path calls to be seen is here:
     benchmark's ``host_*_ms`` metrics read.
   - ``bump_counter`` / ``counter_value`` / ``counters`` /
     ``clear_counters`` — the typed registry's counters as a flat dict.
+  - Program load — one ``jax.monitoring`` listener, registered when this
+    module is imported, that counts what jax reports of every program it
+    traces, lowers, compiles or loads from the persistent cache:
+    ``program.<phase>.ns`` / ``.calls`` for :data:`PROGRAM_PHASES`, the
+    per-program table :func:`program_loads`, and a ``program <name>`` span
+    record for each compile and each cache load (the benchmark's
+    ``setup_trace_s`` / ``setup_compile_s`` / ``setup_cache_load_s`` /
+    ``setup_programs`` read the counters).
 """
 
 from __future__ import annotations
 
 import itertools
+import logging
 import os
 import threading
 import time
@@ -153,6 +162,18 @@ def _stack() -> list:
     return s
 
 
+def _parent_span(stack: list) -> Optional[str]:
+    """The parent of a span opening now on this thread: its innermost open
+    span. At a thread/process entry point, the ambient trace's hand-off span
+    (set by trace_scope or the env carrier), so a dispatcher thread's or gang
+    member's root spans attach to the submitting span in the merged trace
+    tree."""
+    if stack:
+        return stack[-1]
+    tc = _current_trace()
+    return tc.span_id if tc is not None else None
+
+
 def current_span_id() -> Optional[str]:
     """This thread's innermost open span id — the parent a cross-thread
     or cross-process child should adopt (events.current_trace_context)."""
@@ -192,15 +213,7 @@ class TraceRange:
 
     def __enter__(self) -> "TraceRange":
         stack = _stack()
-        if stack:
-            self.parent_id = stack[-1]
-        else:
-            # Thread/process entry point: parent to the ambient trace's
-            # hand-off span (set by trace_scope or the env carrier), so a
-            # dispatcher thread's or gang member's root spans attach to
-            # the submitting span in the merged trace tree.
-            tc = _current_trace()
-            self.parent_id = tc.span_id if tc is not None else None
+        self.parent_id = _parent_span(stack)
         self.depth = len(stack)
         self.span_id = _new_span_id()
         stack.append(self.span_id)
@@ -234,26 +247,32 @@ class TraceRange:
                         break
                 if not mirror:
                     del _open_stacks[ident]
-        # Everything below is inert unless a run scope or event sink is
-        # active — the production disabled path allocates one dict at most
-        # when a report is actually being recorded.
-        ctx = _current_run()
-        if ctx is not None or _log_enabled():
-            record = {
-                "name": self.name,
-                "start": self._start,
-                "end": end,
-                "dur": end - self._start,
-                "ok": self.ok,
-                "exc": self.exc_type,
-                "depth": self.depth,
-                "parent": self.parent_id,
-                "span": self.span_id,
-                "thread": threading.get_ident(),
-            }
-            if ctx is not None:
-                ctx.add_span(record)
-            _emit("span", **record)
+        _publish_span(self.name, self._start, end, self.ok, self.exc_type,
+                      self.depth, self.parent_id, self.span_id)
+
+
+def _publish_span(name, start, end, ok, exc, depth, parent, span_id) -> None:
+    """Hand a finished span to the ambient run context (``model.fit_report()``
+    stage trees) and the event log. Inert unless a run scope or event sink is
+    active — the production disabled path allocates one dict at most when a
+    report is actually being recorded."""
+    ctx = _current_run()
+    if ctx is not None or _log_enabled():
+        record = {
+            "name": name,
+            "start": start,
+            "end": end,
+            "dur": end - start,
+            "ok": ok,
+            "exc": exc,
+            "depth": depth,
+            "parent": parent,
+            "span": span_id,
+            "thread": threading.get_ident(),
+        }
+        if ctx is not None:
+            ctx.add_span(record)
+        _emit("span", **record)
 
 
 # Alias matching the reference class name (NvtxRange.java:37).
@@ -305,3 +324,165 @@ class StageRange(TraceRange):
         ``fit.stage.<stage>.bytes`` (called after the ``with`` block, so
         the count is not in the stage's own time)."""
         bump_counter(f"fit.stage.{self.name}.bytes", int(nbytes))
+
+
+# --- program load: what jax traces, lowers, compiles or loads ---
+
+#: The phases of loading a program, each counted in ``program.<phase>.ns``
+#: and ``.calls``: ``trace`` (Python to jaxpr), ``lower`` (jaxpr to
+#: StableHLO), ``compile`` (an XLA compile that did not come from the
+#: persistent cache, its failed lookup and its cache write included) and
+#: ``cache_load`` (an executable read, deserialised and loaded from the
+#: persistent cache). A cache load's backend event less its read stays in
+#: ``program.compile.ns`` (the cache key's computation) without a call.
+#: Each ``ns`` is self time: a trace nested in a trace or in a lowering
+#: counts once, in the inner event (:func:`_union_ns`).
+PROGRAM_PHASES = ("trace", "lower", "compile", "cache_load")
+_PROGRAM_COUNTERS = {
+    p: (f"program.{p}.ns", f"program.{p}.calls") for p in PROGRAM_PHASES
+}
+
+#: jax's own events, each naming its program (jax/_src/dispatch.py); the
+#: cache's two name none (jax/_src/compiler.py::compile_or_get_cached,
+#: which runs inside the backend event of the program it loads).
+_SPAN_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_READ = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+#: Programs :func:`program_loads` names; the rest add to ``"other"``.
+MAX_PROGRAMS = 256
+#: Closed intervals a thread keeps for the union rule.
+_MAX_KEPT_INTERVALS = 4096
+
+_loads_lock = make_lock("tracing.program_loads")
+_program_loads: dict = {}  # guarded-by: _loads_lock
+_listening = False  # guarded-by: _loads_lock
+#: ``perf_counter() - time.time()`` at registration: jax stamps its events
+#: with ``time.time()``, span records are on the ``perf_counter`` clock.
+_wall_to_perf = 0.0
+
+
+class _ThreadLoads(threading.local):
+    """One thread's listener state: the intervals of its events already
+    counted, and the persistent-cache read of the backend event still open
+    (jax traces, lowers and compiles a program on the thread that asked for
+    it)."""
+
+    def __init__(self):
+        self.closed: Deque[Tuple[float, float]] = deque(maxlen=_MAX_KEPT_INTERVALS)
+        self.hit = False
+        self.read_s = 0.0
+
+
+_thread_loads = _ThreadLoads()
+
+
+def program_loads() -> dict:
+    """``{program: {trace_ns, lower_ns, compile_ns, cache_load_ns, compiles,
+    cache_loads}}`` since the listener was registered: which programs a
+    process paid for, and whether it compiled them or loaded them from the
+    persistent cache. A program goes by the name jax gives its function
+    (``jit(f)`` counts as ``f``); past :data:`MAX_PROGRAMS` names, the
+    rest add to ``"other"``."""
+    with _loads_lock:
+        return {name: dict(row) for name, row in _program_loads.items()}
+
+
+def _union_ns(closed: Deque[Tuple[float, float]], start: float, end: float) -> int:
+    """The nanoseconds of ``[start, end]`` that no interval closed before it
+    on this thread has counted: an event's self time. jax traces an inner
+    jitted function inside its caller's trace, and traces some functions
+    inside a lowering; one thread's events nest or are disjoint, and an
+    inner one closes first, so those inside ``[start, end]`` are the last
+    ones kept. The four phases' ``ns`` therefore add up to the union of a
+    thread's events, never more."""
+    inner = 0.0
+    while closed and closed[-1][0] >= start:
+        s, e = closed.pop()
+        inner += e - s
+    closed.append((start, end))  # the oldest drops off past the deque's length
+    return max(0, int((end - start - inner) * 1e9))
+
+
+def _count_phase(phase: str, start: float, end: float, fun_name: str) -> None:
+    name = fun_name[4:-1] if fun_name.startswith("jit(") and fun_name.endswith(")") else fun_name
+    state = _thread_loads
+    ns = _union_ns(state.closed, start, end)
+    if phase != "compile":
+        bump_counter(_PROGRAM_COUNTERS[phase][0], ns)
+        bump_counter(_PROGRAM_COUNTERS[phase][1])
+        added = {f"{phase}_ns": ns}
+    else:
+        hit, read_ns = state.hit, int(state.read_s * 1e9)
+        state.hit, state.read_s = False, 0.0
+        compile_ns = max(0, ns - read_ns)
+        bump_counter(_PROGRAM_COUNTERS["compile"][0], compile_ns)
+        if hit:
+            bump_counter(_PROGRAM_COUNTERS["cache_load"][0], read_ns)
+            bump_counter(_PROGRAM_COUNTERS["cache_load"][1])
+            added = {"compile_ns": compile_ns, "cache_load_ns": read_ns, "cache_loads": 1}
+        else:
+            bump_counter(_PROGRAM_COUNTERS["compile"][1])
+            added = {"compile_ns": compile_ns, "compiles": 1}
+        stack = _stack()
+        _publish_span(f"program {name}", start + _wall_to_perf, end + _wall_to_perf,
+                      True, None, len(stack), _parent_span(stack), _new_span_id())
+    with _loads_lock:
+        row = _program_loads.get(name)
+        if row is None:
+            if len(_program_loads) - ("other" in _program_loads) >= MAX_PROGRAMS:
+                name = "other"
+            row = _program_loads.setdefault(name, dict.fromkeys(
+                ("trace_ns", "lower_ns", "compile_ns", "cache_load_ns",
+                 "compiles", "cache_loads"), 0))
+        for key, amount in added.items():
+            row[key] += amount
+
+
+def _on_time_span(event: str, start: float, end: float, **kwargs) -> None:
+    phase = _SPAN_PHASES.get(event)
+    if phase is None:
+        return
+    try:
+        _count_phase(phase, start, end, str(kwargs.get("fun_name", "?")))
+    except Exception:  # a listener must never fail the compile it watches
+        logging.getLogger(__name__).exception("program-load listener failed on %s", event)
+
+
+def _on_event(event: str, **_) -> None:
+    if event == _CACHE_HIT:
+        _thread_loads.hit = True
+
+
+def _on_duration(event: str, seconds: float, **_) -> None:
+    if event == _CACHE_READ:
+        _thread_loads.hit = True
+        _thread_loads.read_s += seconds
+
+
+def register_program_listener() -> bool:
+    """Listen to jax's trace, lowering, compile and persistent-cache events,
+    once a process (this module calls it when imported, so CPU processes
+    and tests count too). Returns whether this call registered it. The
+    eight counters exist from here on, at 0 until something loads: a
+    reader tells "nothing loaded" from "not counted"."""
+    global _listening, _wall_to_perf
+    with _loads_lock:
+        if _listening:
+            return False
+        _listening = True
+        _wall_to_perf = time.perf_counter() - time.time()
+    for ns, calls in _PROGRAM_COUNTERS.values():
+        bump_counter(ns, 0)
+        bump_counter(calls, 0)
+    jax.monitoring.register_event_time_span_listener(_on_time_span)
+    jax.monitoring.register_event_duration_secs_listener(_on_duration)
+    jax.monitoring.register_event_listener(_on_event)
+    return True
+
+
+register_program_listener()
