@@ -51,6 +51,7 @@ from spark_rapids_ml_tpu.ops.trees import (
     grow_forest_sharded,
     quantize_and_bin,
     sample_weights,
+    select_passes,
 )
 from spark_rapids_ml_tpu.core.serving import note_device_cache, serve_rows
 from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS
@@ -462,17 +463,20 @@ def _fit_forest(params: _RandomForestParams, x: np.ndarray, row_stats: np.ndarra
         bump_counter("forest.bins.bytes", int(xb.size) * 4)
         grow = grow_forest if mesh is None else partial(grow_forest_sharded, mesh)
         grown = []
+        passes = 0
         for first in range(0, n_trees, batch):
             ids = np.arange(first, min(first + batch, n_trees), dtype=np.int32)
             with TraceRange("rf grow", TraceColor.RED):
                 w = sample_weights(k_sample, ids, n, *sampling)
                 grown.append(grow(xb, rs, w, edges, k_feat, ids, **kwargs))
+            passes += select_passes(-(-n // shards), n_bins, max_depth, len(ids))
         forest = grown[0] if len(grown) == 1 else Forest(*map(jnp.concatenate, zip(*grown)))
     # what the fit dispatched, from its shapes (nothing is read back for them)
     bump_counter("forest.grow.tree_batches", len(grown))
     bump_counter("forest.grow.tree_levels", n_trees * max_depth)
     bump_counter("forest.grow.selected_elems", n_trees * max_depth * n * m)
     bump_counter("forest.grow.hist_cells", n_trees * (2**max_depth - 1) * m * n_bins * n_stats)
+    bump_counter("forest.grow.select_passes", passes)
     return forest
 
 

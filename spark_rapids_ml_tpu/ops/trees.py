@@ -12,9 +12,10 @@ and no more in order of magnitude:
   - bin ids are packed four to an int32 word (two past 256 bins), so a row
     is one contiguous run that a row gather moves whole;
   - per level the rows are sorted by node and cut into tiles of one node's
-    rows; a tile's K features are SELECTED by one exact bf16 pass against the
-    node's (K, d) one-hot and COUNTED by one pass of the weights against the
-    one-hot of the selected bins: the histogram is
+    rows; a tile's K features are SELECTED from the packed words one byte
+    plane at a time, by exact bf16 passes against the plane's columns of the
+    node's one-hot (no row is unpacked to d digits), and COUNTED by one pass
+    of the weights against the one-hot of the selected bins: the histogram is
     ``(trees in flight, nodes, S, K, B)``, never ``nodes * d * B``;
   - the split search, routing (out of the K already selected) and one sort a
     level finish it; no element gather or scatter per row (the chip does 83 M
@@ -284,8 +285,9 @@ def _leaf_prediction(stats: jax.Array, kind: str) -> jax.Array:
 #   2. per step of ``tiles_per_step`` tiles: the tiles' rows of the packed
 #      bin matrix are gathered whole (a row is contiguous: a slice gather, not
 #      the scalar loop of an element gather), the node's K chosen features are
-#      SELECTED by one bf16 pass against the node's (K, d) one-hot (bin ids
-#      are under 256 a digit, so the pass is exact), and the tile's
+#      SELECTED byte plane by byte plane (``_select_bins``: a bf16 pass a
+#      plane and digit against the plane's columns of the node's one-hot; bin
+#      ids are under 256 a digit, so every pass is exact), and the tile's
 #      ``(S, K, B)`` histogram is ``weights^T @ onehot(bins)`` on the MXU,
 #      added to its node's.
 #   3. the split search (``_search_nodes``) over ``(m_pad, S, K, B)`` cells.
@@ -321,19 +323,32 @@ def packed_width(d: int, n_bins: int) -> int:
     return -(-d // bins_per_word(n_bins))
 
 
-def _unpack_digits(words: jax.Array, n_bins: int) -> list:
-    """Packed words (..., W) -> the bin ids' base-256 digits, low first, each
-    (..., per_word * W) int32 in feature order (word w holds features
-    w, W + w, ...; a feature past d reads 0 and is never selected)."""
+def _select_bins(words: jax.Array, feats: jax.Array, n_bins: int, operand_dtype) -> jax.Array:
+    """The bin ids ``(C, K, R)`` int32 of features ``feats (C, K)`` in the
+    packed rows ``words (C, R, W)``, one byte plane at a time.
+
+    Word ``w`` holds features ``w, W + w, 2W + w, ...`` (:func:`bin_features`),
+    so digit ``q`` of byte plane ``p`` is digit ``q`` of features
+    ``p W ... p W + W - 1``: each (plane, digit) is one pass against that
+    plane's columns of the node's one-hot. Digits are under 256 and exactly
+    one plane holds a feature, so every pass and their float32 sum are exact;
+    no row is unpacked to ``per_word * W`` digits (a feature past d is never
+    in ``feats``).
+    """
     per_word = bins_per_word(n_bins)
     n_digits = 4 // per_word
-    return [
-        jnp.concatenate(
-            [(words >> (8 * (p * n_digits + q))) & 0xFF for p in range(per_word)],
-            axis=-1,
-        )
-        for q in range(n_digits)
-    ]
+    width = words.shape[-1]
+    iota_w = jnp.arange(width, dtype=jnp.int32)
+    picked = [0.0] * n_digits
+    for p in range(per_word):
+        onehot = (feats[:, :, None] == p * width + iota_w).astype(operand_dtype)  # (C, K, W)
+        for q in range(n_digits):
+            digit = (words >> (8 * (p * n_digits + q))) & 0xFF  # (C, R, W)
+            picked[q] = picked[q] + jnp.einsum(
+                "crw,ckw->ckr", digit.astype(jnp.float32).astype(operand_dtype), onehot,
+                preferred_element_type=jnp.float32,
+            )
+    return sum(picked[q].astype(jnp.int32) * 256**q for q in range(n_digits))
 
 
 def level_groups(max_depth: int, min_width: int = MIN_LEVEL_WIDTH) -> list:
@@ -361,6 +376,19 @@ def step_tiles_a_tree(trees: int) -> int:
 def level_tiles(n: int, m_pad: int, tile_rows: int, tiles_per_step: int) -> int:
     """Tiles of a level program: enough for any tree, a whole number of steps."""
     return _round_up(-(-n // tile_rows) + m_pad, tiles_per_step)
+
+
+def select_passes(n: int, n_bins: int, max_depth: int, trees: int) -> int:
+    """Selection products one batch of ``trees`` issues, from shapes alone:
+    planes x digits (:func:`_select_bins`) a step, vmapped over the batch's
+    trees, times the steps of every level (``grow_forest``'s defaults)."""
+    per_word = bins_per_word(n_bins)
+    tiles = step_tiles_a_tree(trees)
+    steps = sum(
+        (end - first) * level_tiles(n, m_pad, TILE_ROWS, tiles) // tiles
+        for first, end, m_pad in level_groups(max_depth)
+    )
+    return per_word * (4 // per_word) * steps
 
 
 def builder_bytes(
@@ -500,10 +528,8 @@ def _grow_tree(
     n = xb.shape[0]
     n_stats = row_stats.shape[1]
     k = min(feat_subset, n_features)
-    d_packed = xb.shape[1] * bins_per_word(n_bins)
     n_nodes = 2 ** (max_depth + 1) - 1
     rows_per_tile = jnp.arange(tile_rows, dtype=jnp.int32)
-    iota_d = jnp.arange(d_packed, dtype=jnp.int32)
     iota_b = jnp.arange(n_bins, dtype=jnp.int32)
     iota_k = jnp.arange(k, dtype=jnp.int32)
     keep_dtype = jnp.uint8 if n_bins <= 256 else jnp.int32
@@ -548,14 +574,7 @@ def _grow_tree(
             ws_c = lax.dynamic_slice_in_dim(ws_tiles, at, tiles_per_step, axis=1)
             tn_c = lax.dynamic_slice_in_dim(tn, at, tiles_per_step)
             node_c = lax.dynamic_slice_in_dim(tile_node, at, tiles_per_step)
-            onehot_f = (feats[tn_c][:, :, None] == iota_d).astype(operand_dtype)  # (C, K, d)
-            sel = 0
-            for q, digit in enumerate(_unpack_digits(xb[rid_c], n_bins)):  # (C, R, d)
-                picked = jnp.einsum(
-                    "crd,ckd->ckr", digit.astype(jnp.float32).astype(operand_dtype), onehot_f,
-                    preferred_element_type=jnp.float32,
-                )
-                sel = sel + picked.astype(jnp.int32) * (256**q)
+            sel = _select_bins(xb[rid_c], feats[tn_c], n_bins, operand_dtype)  # (C, K, R)
             onehot_b = (sel[..., None] == iota_b).astype(hist_dtype)  # (C, K, R, B)
             tile_hist = jnp.einsum(
                 "scr,ckrb->cskb", ws_c.astype(hist_dtype), onehot_b, precision=hist_prec,

@@ -242,12 +242,15 @@ class TestLevelBuilder:
           sds((1,), jnp.int32))
 
         largest = [0]
+        unpacked = []  # rows of d digits: the selection reads the packed words by plane
 
         def walk(jp):
             for eqn in jp.eqns:
                 for v in eqn.outvars:
                     size = int(np.prod(v.aval.shape)) if hasattr(v.aval, "shape") else 0
                     largest[0] = max(largest[0], size)
+                    if eqn.primitive.name == "concatenate" and v.aval.shape[-1] == d:
+                        unpacked.append(v.aval.shape)
                 for sub in jax.core.jaxprs_in_params(eqn.params):
                     walk(sub)
 
@@ -256,6 +259,7 @@ class TestLevelBuilder:
         selected_hist = m_top * 2 * k * n_bins  # 57.7 M
         assert selected_hist <= largest[0] <= 2 * selected_hist
         assert largest[0] < m_top * d * n_bins / 10  # the dense form's 1.57e9
+        assert unpacked == []
 
     def test_level_groups_and_tiles_follow_from_shapes(self):
         from spark_rapids_ml_tpu.ops import trees
@@ -285,3 +289,76 @@ class TestLevelBuilder:
             trees.node_feature_subsets(tree_key, np.array([g], np.int32), d, k)[0])
         ws = w[0][:, None].astype(np.float64) * stats.astype(np.float64)
         _check_tree_recursively(forest, 0, bins, ws, subsets_of, edges, 3, "gini", n_bins)
+
+    @pytest.mark.parametrize("operand", ["float32", "bfloat16"])
+    @pytest.mark.parametrize(
+        "n_bins, d",
+        [(128, 64), (256, 10), (300, 37), (1000, 9)],  # 10, 37, 9: padding features
+    )
+    def test_plane_selection_reads_the_bin_ids_of_the_subset(self, n_bins, d, operand):
+        """``_select_bins`` on random packed words and random per-node subsets
+        returns exactly what the host reads (``unpack_bins``, then a take of
+        the subset); the padding features' bytes, set to all ones, are never
+        read."""
+        import jax.numpy as jnp
+
+        from spark_rapids_ml_tpu.ops import trees
+
+        rng = np.random.default_rng(n_bins + d)
+        n, c, r, k = 96, 5, 16, min(6, d)
+        ids = rng.integers(0, n_bins, size=(n, d))
+        ids[0] = n_bins - 1  # the top digit's high bit: a negative word
+        edges = np.broadcast_to(np.arange(n_bins - 1) + 0.5, (d, n_bins - 1))
+        words = np.array(trees.bin_features(
+            jnp.asarray(ids, jnp.float32), jnp.asarray(edges, jnp.float32), n_bins))
+        per_word, width = trees.bins_per_word(n_bins), words.shape[1]
+        bits = 32 // per_word
+        raw = words.view(np.uint32)
+        for f in range(d, per_word * width):
+            raw[:, f % width] |= np.uint32(((1 << bits) - 1) << (f // width * bits))
+        oracle = trees.unpack_bins(words, d, n_bins)
+        np.testing.assert_array_equal(oracle, ids)
+
+        rid = rng.integers(0, n, size=(c, r))
+        feats = np.stack([np.sort(rng.choice(d, size=k, replace=False)) for _ in range(c)])
+        sel = np.asarray(trees._select_bins(
+            jnp.asarray(words[rid]), jnp.asarray(feats, jnp.int32), n_bins, jnp.dtype(operand)))
+        want = np.stack([oracle[rid[i]][:, feats[i]].T for i in range(c)])  # (C, K, R)
+        assert sel.dtype == np.int32
+        np.testing.assert_array_equal(sel, want)
+
+    @pytest.mark.parametrize("impurity, n_bins", [("gini", 300), ("variance", 32)])
+    def test_every_node_holds_the_rows_its_thresholds_route_there(self, impurity, n_bins):
+        """A classifier past 256 bins (two digits a bin id) and a regressor:
+        every node reached reports the weighted stats of exactly the rows that
+        the RAW thresholds (``x <= threshold`` goes left) route there."""
+        d, k, depth = 7, 3, 5
+        x, stats, edges, words, bins = _builder_case(impurity, n=600, d=d, n_bins=n_bins, seed=21)
+        if n_bins > 256:
+            assert bins.max() > 256
+        forest, w, _ = _grow(words, stats, edges, tree_ids=[0, 1, 2], depth=depth,
+                             impurity=impurity, n_bins=n_bins, d=d, k=k, tile_rows=16,
+                             tiles_per_step=4)
+        for t in range(3):
+            ws = w[t][:, None].astype(np.float64) * stats.astype(np.float64)
+            splits, todo = 0, [(0, np.arange(x.shape[0]))]
+            while todo:
+                g, rows = todo.pop()
+                total = ws[rows].sum(axis=0)
+                # weights are integers: a node's weight is exact on both paths
+                weight = total[0] if impurity == "variance" else total.sum()
+                assert forest.node_weight[t, g] == weight
+                if impurity == "variance":
+                    if total[0] > 0:
+                        np.testing.assert_allclose(forest.leaf_value[t, g, 0], total[1] / total[0],
+                                                   rtol=1e-4, atol=1e-4)
+                else:
+                    np.testing.assert_array_equal(
+                        np.rint(forest.leaf_value[t, g] * forest.node_weight[t, g]), total)
+                if forest.is_leaf[t, g]:
+                    continue
+                f, thr = forest.feature[t, g], forest.threshold[t, g]
+                left = x[rows, f] <= thr
+                splits += 1
+                todo += [(2 * g + 1, rows[left]), (2 * g + 2, rows[~left])]
+            assert splits >= 3

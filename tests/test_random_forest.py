@@ -411,4 +411,21 @@ class TestForestAdmission:
             "forest.grow.tree_levels": 4 * 3,
             "forest.grow.selected_elems": 4 * 3 * 500 * k,
             "forest.grow.hist_cells": 4 * (2**3 - 1) * k * 16 * 2,
+            # four byte planes a step; one step of 8 tiles a level (ceil(500 / 128) + 4)
+            "forest.grow.select_passes": 4 * 3,
         }
+
+    @pytest.mark.parametrize("n_bins, planes, digits", [(128, 4, 1), (300, 2, 2)])
+    def test_select_passes_are_counted_at_dispatch(self, rng, n_bins, planes, digits):
+        from spark_rapids_ml_tpu.utils import tracing
+
+        x, y = self._xy(rng, n=2000, d=9)
+        # three trees: 10 tiles a step; a level of width 4 has ceil(2000 / 128) + 4 = 20
+        # tiles: two steps a level, three levels
+        steps = 2 * 3
+        for _ in range(2):  # a second fit of the same shape counts as much again
+            before = tracing.counters("forest.").get("forest.grow.select_passes", 0)
+            est = RandomForestClassifier().setNumTrees(3).setMaxDepth(3).setMaxBins(n_bins)
+            est.setSeed(1).fit((x, y))
+            moved = tracing.counters("forest.")["forest.grow.select_passes"] - before
+            assert moved == planes * digits * steps
