@@ -30,10 +30,11 @@ from spark_rapids_ml_tpu.core.ingest import PlacementWindow, dense_partitions
 from spark_rapids_ml_tpu.ops.covariance import (
     centered_gram,
     centered_gram_packed,
-    centered_gram_sharded,
     comoment_add_block,
     comoment_init,
     comoment_resident,
+    comoment_sharded,
+    count_sharded_blocks,
     count_resident_blocks,
     streaming_mean_and_covariance,
     welford_add_block,
@@ -50,7 +51,7 @@ from spark_rapids_ml_tpu.ops.eigh import (
 )
 from spark_rapids_ml_tpu.ops.linalg import resolve_precision, triu_to_full
 from spark_rapids_ml_tpu.parallel.distributed_cov import distributed_mean_and_covariance
-from spark_rapids_ml_tpu.parallel.mesh import shard_rows_from_partitions
+from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS, shard_rows_from_partitions
 from spark_rapids_ml_tpu.utils.tracing import (
     StageRange,
     TraceColor,
@@ -64,9 +65,9 @@ from functools import partial as _partial
 
 @_partial(
     jax.jit,
-    static_argnames=("k", "center", "precision", "eigen_solver", "eigen_iters", "blocked"),
+    static_argnames=("k", "center", "precision", "eigen_solver", "eigen_iters", "mesh"),
 )
-def _pca_fit_device(x, k, center, precision, eigen_solver, eigen_iters, blocked=True):
+def _pca_fit_device(x, k, center, precision, eigen_solver, eigen_iters, mesh=None):
     """The whole PCA fit as ONE XLA program on a device-resident array:
     column means and the centred Gram summed in blocks of rows
     (``ops/covariance.py::comoment_resident``), the eigensolve and the
@@ -76,17 +77,16 @@ def _pca_fit_device(x, k, center, precision, eigen_solver, eigen_iters, blocked=
     reference's equivalent spans four JNI calls with host copies between
     each (RapidsRowMatrix.scala:149-257, rapidsml_jni.cu:159-356).
 
-    ``blocked=False`` is the mesh fit's form until the four-chip cell
-    (ROADMAP.md Reach 1): rows sharded over the data axis are summed in one
-    contraction a chip, XLA inserting the ``psum`` (a scan over a sharded
-    axis would gather every block).
+    With ``mesh`` (rows sharded over its data axis) each chip sums its own
+    rows in those blocks and one merge across chips all-reduces the
+    co-moments once (``ops/covariance.py::comoment_sharded``; measured in
+    ``pca_3000.mesh4``); the eigensolve runs on the replicated result.
     """
     n, d = x.shape
-    if blocked:
+    if mesh is None:
         _, mean, _, gram = comoment_resident(x, precision=precision, center=center)
     else:
-        mean = jnp.mean(x, axis=0) if center else jnp.zeros((d,), dtype=x.dtype)
-        gram = centered_gram_sharded(x, mean, precision=precision)
+        _, mean, _, gram = comoment_sharded(x, mesh, precision=precision, center=center)
     cov = gram / (n - 1)
     # What rounding can leave of constant columns: their means are not exact
     # (a block's mean is a rounded sum), so their Gram is nought only to
@@ -393,27 +393,34 @@ class RowMatrix:
         if n < 2:
             raise ValueError(f"need at least 2 rows, got {n}")
         with TraceRange("compute cov", TraceColor.RED):
-            if self.backend != "pallas" and self.mesh is None:
-                count_resident_blocks(n)
-                state = comoment_resident(
-                    x, precision=self.precision, center=self.mean_centering
-                )
-                return state[3] / (n - 1)
-            mean = (
-                jnp.mean(x, axis=0)
-                if self.mean_centering
-                else jnp.zeros((self.num_cols,), dtype=x.dtype)
-            )
             if self.backend == "pallas":
                 from spark_rapids_ml_tpu.ops.pallas.covariance import (
                     centered_gram_pallas,
                 )
 
+                mean = (
+                    jnp.mean(x, axis=0)
+                    if self.mean_centering
+                    else jnp.zeros((self.num_cols,), dtype=x.dtype)
+                )
                 interpret = jax.default_backend() != "tpu"
                 return centered_gram_pallas(x, mean, interpret=interpret) / (n - 1)
-            # rows sharded over a mesh: one contraction a chip and XLA's
-            # psum, until the four-chip cell (ROADMAP.md Reach 1)
-            return centered_gram_sharded(x, mean, precision=self.precision) / (n - 1)
+            state = self._comoments_device(x)
+            return state[3] / (n - 1)
+
+    def _comoments_device(self, x):
+        """The co-moments of device rows in blocks: on the device that
+        holds them, or each chip its own rows and one merge across chips."""
+        n = self.num_rows
+        if self.mesh is None:
+            count_resident_blocks(n)
+            return comoment_resident(
+                x, precision=self.precision, center=self.mean_centering
+            )
+        count_sharded_blocks(n, int(self.mesh.shape[DATA_AXIS]))
+        return comoment_sharded(
+            x, self.mesh, precision=self.precision, center=self.mean_centering
+        )
 
     def _device_array_on_mesh(self):
         """The device input honoring a configured mesh: with a mesh set,
@@ -714,9 +721,10 @@ class RowMatrix:
                 raise ValueError(f"need at least 2 rows, got {n}")
             if not 1 <= k <= n_cols:
                 raise ValueError(f"k must be in [1, {n_cols}], got {k}")
-            blocked = self.mesh is None
-            if blocked:
+            if self.mesh is None:
                 count_resident_blocks(n)
+            else:
+                count_sharded_blocks(n, int(self.mesh.shape[DATA_AXIS]))
             with TraceRange("fused device fit", TraceColor.RED), StageRange("solve"):
                 u, explained = _pca_fit_device(
                     self._device_array_on_mesh(),
@@ -725,7 +733,7 @@ class RowMatrix:
                     precision=self.precision,
                     eigen_solver=self.eigen_solver,
                     eigen_iters=self.eigen_iters,
-                    blocked=blocked,
+                    mesh=self.mesh,
                 )
             return u, explained  # device arrays — the caller decides on host
         shape_known = (

@@ -51,7 +51,7 @@ from spark_rapids_ml_tpu.core.persistence import (
     save_data,
     save_metadata,
 )
-from spark_rapids_ml_tpu.ops.knn import knn
+from spark_rapids_ml_tpu.ops.knn import count_knn_blocks, knn
 from spark_rapids_ml_tpu.ops.umap import (
     FuzzyGraph,
     find_ab_params,
@@ -61,7 +61,12 @@ from spark_rapids_ml_tpu.ops.umap import (
     spectral_init,
 )
 from spark_rapids_ml_tpu.utils.envknobs import env_choice
-from spark_rapids_ml_tpu.utils.tracing import TraceColor, TraceRange
+from spark_rapids_ml_tpu.utils.tracing import (
+    StageRange,
+    TraceColor,
+    TraceRange,
+    bump_counter,
+)
 
 _SPECTRAL_CAP = 8192  # dense-Laplacian eigh above this would dominate fit time
 
@@ -89,6 +94,13 @@ class _UMAPParams(Params):
         "kNN graph build: brute (exact) | brute_approx (hardware top-k)",
         toString,
     )
+    precision = Param(
+        "_", "precision",
+        "matmul precision of the kNN distance product: highest/f32 (6 bf16 "
+        "passes, the default) | high (3 passes) | bf16x3 | default/bf16 "
+        "(1 pass)",
+        toString,
+    )
 
     def __init__(self, uid: Optional[str] = None):
         super().__init__(uid)
@@ -108,10 +120,21 @@ class _UMAPParams(Params):
             featuresCol="features",
             outputCol="embedding",
             buildAlgo="brute",
+            precision="highest",
         )
 
     def getBuildAlgo(self) -> str:
         return self.getOrDefault(self.buildAlgo)
+
+    def getPrecision(self) -> str:
+        return self.getOrDefault(self.precision)
+
+    def setPrecision(self, value: str):
+        """The kNN graph's distance product (``ops/precision.py`` spellings).
+        The layout epochs' small products are not affected."""
+        from spark_rapids_ml_tpu.ops.precision import validate_mode
+
+        return self._chain(self.precision, validate_mode(value))
 
     def getNNeighbors(self) -> int:
         return self.getOrDefault(self.nNeighbors)
@@ -236,7 +259,8 @@ class _UMAPParams(Params):
 
 
 def _knn_excluding_self(x: jax.Array, k: int, metric: str, mesh=None,
-                        x_host=None, approx: bool = False):
+                        x_host=None, approx: bool = False,
+                        precision: str = "highest"):
     """kNN of x against itself with the self-match column removed.
 
     ``x_host``: the host copy of ``x`` when the caller still has it — the
@@ -253,10 +277,12 @@ def _knn_excluding_self(x: jax.Array, k: int, metric: str, mesh=None,
         items, item_mask = shard_items(host, mesh, metric=metric)
         d, idx = knn_sharded(
             x, items.astype(x.dtype), item_mask.astype(x.dtype), mesh, k + 1,
-            metric=metric, approx=approx,
+            metric=metric, approx=approx, precision=precision,
         )
     else:
-        d, idx = knn(x, x, k + 1, metric=metric, approx=approx)
+        count_knn_blocks(x.shape[0], x.shape[0])
+        d, idx = knn(x, x, k + 1, metric=metric, approx=approx,
+                     precision=precision)
     # The self column is wherever idx == row (ties can displace it from 0);
     # mask it out then take the first k of the rest.
     rows = jnp.arange(x.shape[0])[:, None]
@@ -303,6 +329,60 @@ class UMAP(_UMAPParams, Estimator, MLReadable):
         self._init_embedding = arr
         return self
 
+    def _layout(self, x, graph, n, dim, k_init, k_opt, n_epochs, a, b,
+                tail_plan, tail_cfg, tail_interpret):
+        """The initial layout and the epoch SGD (dispatched, not waited on)."""
+        if self._init_embedding is not None:
+            if self._init_embedding.shape != (n, dim):
+                raise ValueError(
+                    f"init embedding shape {self._init_embedding.shape} != "
+                    f"({n}, {dim})"
+                )
+            emb0 = jnp.asarray(self._init_embedding)
+        elif self.getInit() == "spectral" and n <= _SPECTRAL_CAP:
+            emb0 = spectral_init(graph, n, dim, k_init)
+        else:
+            emb0 = 10.0 * jax.random.uniform(
+                k_init, (n, dim), minval=-1.0, maxval=1.0
+            )
+        sgd = dict(
+            n_epochs=n_epochs,
+            neg_rate=self.getNegativeSampleRate(),
+            neg_pool=self.getNegativePoolSize(),
+            learning_rate=self.getLearningRate(),
+            repulsion=self.getRepulsionStrength(),
+            a=a,
+            b=b,
+        )
+        if self.mesh is not None:
+            # Mesh fit: the epoch SGD shards its edges over the data
+            # axis too (one delta psum per epoch) — both heavy stages
+            # (kNN graph AND layout optimization) are distributed.
+            from spark_rapids_ml_tpu.ops.umap import optimize_layout_sharded
+
+            return optimize_layout_sharded(
+                self.mesh, emb0.astype(jnp.float32), graph, k_opt, **sgd
+            )
+        tail = dict(
+            tail_plan=tail_plan, tail_cfg=tail_cfg, tail_interpret=tail_interpret
+        )
+        # Preemption tolerance is OPT-IN for UMAP (TPUML_CHECKPOINT_UMAP=1
+        # on top of the global knobs): only the epoch SGD checkpoints —
+        # the kNN graph and the init recompute deterministically on
+        # resume. Single-device fits only (the sharded epoch program
+        # keeps its state inside shard_map).
+        from spark_rapids_ml_tpu.robustness.checkpoint import umap_opt_in
+
+        if umap_opt_in():
+            from spark_rapids_ml_tpu.ops.umap import optimize_layout_resumable
+
+            ckpt = self._fit_checkpointer("umap.layout", data=(x, emb0))
+            if ckpt is not None:
+                return optimize_layout_resumable(
+                    emb0.astype(jnp.float32), graph, k_opt, ckpt, **sgd, **tail
+                )
+        return optimize_layout(emb0.astype(jnp.float32), graph, k_opt, **sgd, **tail)
+
     def _fit(self, dataset: Any) -> "UMAPModel":
         from spark_rapids_ml_tpu.core.membudget import fit_memory_guard
 
@@ -338,20 +418,12 @@ class UMAP(_UMAPParams, Estimator, MLReadable):
             from spark_rapids_ml_tpu.core.ingest import place_array
 
             x = place_array(x_in, dtype=jnp.float32)
-            dists, idx = _knn_excluding_self(
-                x, k, self.getMetric(), self.mesh,
-                x_host=None if device_in else x_in,
-                approx=self.getBuildAlgo() == "brute_approx",
-            )
-            graph = fuzzy_simplicial_set(idx, dists)
             # Tail-scatter backend: the edge list is static
             # per fit, so 'pallas' sorts it by tail ONCE here and the epoch
             # SGD accumulates tail gradients densely per tile instead of
             # XLA's per-element scatter. 'auto' engages it on the TPU
             # backend; elsewhere (and under a mesh, whose sharded epoch
             # keeps its own scatter) the XLA path stands.
-            tail_plan = tail_cfg = None
-            tail_interpret = False
             scatter_mode = env_choice(
                 "TPUML_UMAP_SCATTER", ("auto", "pallas", "xla"), "auto"
             )
@@ -359,107 +431,70 @@ class UMAP(_UMAPParams, Estimator, MLReadable):
             want_pallas = scatter_mode == "pallas" or (
                 scatter_mode == "auto" and on_tpu
             )
+            use_plan = False
             if want_pallas and self.mesh is None:
                 from spark_rapids_ml_tpu.ops.pallas.umap import (
                     build_tail_plan,
                     plan_feasible,
                 )
 
-                if plan_feasible(n, k, dim):
-                    tail_plan, tail_cfg = build_tail_plan(
-                        np.asarray(idx), n, dim
-                    )
-                    tail_interpret = not on_tpu
-            if self._init_embedding is not None:
-                if self._init_embedding.shape != (n, dim):
-                    raise ValueError(
-                        f"init embedding shape {self._init_embedding.shape} != "
-                        f"({n}, {dim})"
-                    )
-                emb0 = jnp.asarray(self._init_embedding)
-            elif self.getInit() == "spectral" and n <= _SPECTRAL_CAP:
-                emb0 = spectral_init(graph, n, dim, k_init)
-            else:
-                emb0 = 10.0 * jax.random.uniform(
-                    k_init, (n, dim), minval=-1.0, maxval=1.0
+                use_plan = plan_feasible(n, k, dim)
+            # Stage ``knn`` ends at the host's one read of the graph, the
+            # tail plan's indices (without a plan nothing is read here and
+            # the graph's device time falls in ``layout``'s closing wait).
+            with StageRange("knn"):
+                dists, idx = _knn_excluding_self(
+                    x, k, self.getMetric(), self.mesh,
+                    x_host=None if device_in else x_in,
+                    approx=self.getBuildAlgo() == "brute_approx",
+                    precision=self.getPrecision(),
                 )
-            if self.mesh is not None:
-                # Mesh fit: the epoch SGD shards its edges over the data
-                # axis too (one delta psum per epoch) — both heavy stages
-                # (kNN graph AND layout optimization) are distributed.
-                import functools
-
-                from spark_rapids_ml_tpu.ops.umap import optimize_layout_sharded
-
-                optimizer = functools.partial(optimize_layout_sharded, self.mesh)
-            else:
-                optimizer = optimize_layout
-            # Preemption tolerance is OPT-IN for UMAP (TPUML_CHECKPOINT_UMAP=1
-            # on top of the global knobs): only the epoch SGD checkpoints —
-            # the kNN graph and the init recompute deterministically on
-            # resume. Single-device fits only (the sharded epoch program
-            # keeps its state inside shard_map).
-            ckpt = None
-            if self.mesh is None:
-                from spark_rapids_ml_tpu.robustness.checkpoint import umap_opt_in
-
-                if umap_opt_in():
-                    ckpt = self._fit_checkpointer("umap.layout", data=(x, emb0))
-            if ckpt is not None:
-                from spark_rapids_ml_tpu.ops.umap import optimize_layout_resumable
-
-                emb = optimize_layout_resumable(
-                    emb0.astype(jnp.float32),
-                    graph,
-                    k_opt,
-                    ckpt,
-                    n_epochs=self._auto_epochs(n),
-                    neg_rate=self.getNegativeSampleRate(),
-                    neg_pool=self.getNegativePoolSize(),
-                    learning_rate=self.getLearningRate(),
-                    repulsion=self.getRepulsionStrength(),
-                    a=a,
-                    b=b,
-                    tail_plan=tail_plan,
-                    tail_cfg=tail_cfg,
-                    tail_interpret=tail_interpret,
+                graph = fuzzy_simplicial_set(idx, dists)
+                idx_host = np.asarray(idx) if use_plan else None
+            tail_plan = tail_cfg = None
+            tail_interpret = False
+            if use_plan:
+                with StageRange("plan"):
+                    tail_plan, tail_cfg = build_tail_plan(idx_host, n, dim)
+                tail_interpret = not on_tpu
+                bump_counter("umap.layout.pallas")
+            n_epochs = self._auto_epochs(n)
+            with StageRange("layout"):
+                emb = self._layout(
+                    x, graph, n, dim, k_init, k_opt, n_epochs, a, b,
+                    tail_plan, tail_cfg, tail_interpret,
                 )
-            else:
-                tail_kw = {}
-                if self.mesh is None:
-                    tail_kw = dict(
-                        tail_plan=tail_plan, tail_cfg=tail_cfg,
-                        tail_interpret=tail_interpret,
-                    )
-                emb = optimizer(
-                    emb0.astype(jnp.float32),
-                    graph,
-                    k_opt,
-                    n_epochs=self._auto_epochs(n),
-                    neg_rate=self.getNegativeSampleRate(),
-                    neg_pool=self.getNegativePoolSize(),
-                    learning_rate=self.getLearningRate(),
-                    repulsion=self.getRepulsionStrength(),
-                    a=a,
-                    b=b,
-                    **tail_kw,
-                )
+                bump_counter("umap.layout.epochs", n_epochs)
+                # the one stage that waits for the device at its end: the
+                # fit returns a finished embedding, as a host-input fit's
+                # float64 copy below always made it
+                emb = jax.block_until_ready(emb)
 
         # Device fits keep embedding + train rows resident; the model's
-        # host float64 views convert lazily (the PCAModel contract).
+        # host float64 views convert lazily (the PCAModel contract). The
+        # directed kNN graph rides along on the device either way.
         model = UMAPModel(
             self.uid,
             embedding=emb if device_in else np.asarray(emb, dtype=np.float64),
             trainData=x_in if device_in else np.asarray(x_in, dtype=np.float64),
             a=a,
             b=b,
+            knnIndices=idx,
+            knnDists=dists,
         )
         return self._copyValues(model)
 
 
 class UMAPModel(_UMAPParams, Model, LazyHostState):
     """Fitted model: ``embedding`` (n, dim); transform embeds NEW points
-    against the frozen training layout."""
+    against the frozen training layout.
+
+    ``knnIndices`` / ``knnDists`` (n, nNeighbors): the fit's directed kNN
+    graph, each row's neighbours nearest first without the row itself
+    (cuML's ``graph_`` source, umap-learn's ``_knn_indices`` /
+    ``_knn_dists``). Kept where the fit made them (on the device), host
+    views converted on first read. NOT persisted: a loaded model has
+    ``None`` for both, and the saved format is unchanged."""
 
     def __init__(
         self,
@@ -468,6 +503,8 @@ class UMAPModel(_UMAPParams, Model, LazyHostState):
         trainData: Optional[np.ndarray] = None,
         a: float = 1.577,
         b: float = 0.895,
+        knnIndices=None,
+        knnDists=None,
     ):
         super().__init__(uid)
         # Fitted state keeps its residence (device-fit state stays on
@@ -477,13 +514,27 @@ class UMAPModel(_UMAPParams, Model, LazyHostState):
         self._train_raw = trainData
         self._emb_np: Optional[np.ndarray] = None
         self._train_np: Optional[np.ndarray] = None
+        self._knn_idx_raw = knnIndices
+        self._knn_dist_raw = knnDists
+        self._knn_idx_np: Optional[np.ndarray] = None
+        self._knn_dist_np: Optional[np.ndarray] = None
         self.a = a
         self.b = b
 
     _lazy_host_fields = {
         "_emb_raw": ("_emb_np", np.float64),
         "_train_raw": ("_train_np", np.float64),
+        "_knn_idx_raw": ("_knn_idx_np", None),
+        "_knn_dist_raw": ("_knn_dist_np", None),
     }
+
+    @property
+    def knnIndices(self) -> Optional[np.ndarray]:
+        return self._lazy_host_view("_knn_idx_raw")
+
+    @property
+    def knnDists(self) -> Optional[np.ndarray]:
+        return self._lazy_host_view("_knn_dist_raw")
 
     @property
     def embedding(self) -> Optional[np.ndarray]:
@@ -494,7 +545,8 @@ class UMAPModel(_UMAPParams, Model, LazyHostState):
         return self._lazy_host_view("_train_raw")
 
     def copy(self, extra=None) -> "UMAPModel":
-        that = UMAPModel(self.uid, self._emb_raw, self._train_raw, self.a, self.b)
+        that = UMAPModel(self.uid, self._emb_raw, self._train_raw, self.a, self.b,
+                         self._knn_idx_raw, self._knn_dist_raw)
         return self._copyValues(that, extra)
 
     def transform(self, dataset: Any) -> Any:
@@ -535,7 +587,8 @@ class UMAPModel(_UMAPParams, Model, LazyHostState):
         )
 
         with TraceRange("umap transform", TraceColor.PURPLE):
-            dists, idx = knn(x, train, k, metric=self.getMetric())
+            dists, idx = knn(x, train, k, metric=self.getMetric(),
+                             precision=self.getPrecision())
             sigmas, rhos = smooth_knn_dist(dists, float(k))
             w = jnp.exp(
                 -jnp.maximum(dists - rhos[:, None], 0.0) / sigmas[:, None]

@@ -77,20 +77,6 @@ def centered_gram(x: jax.Array, mean: jax.Array, precision: str = "highest") -> 
     return dot(b.T, b)
 
 
-@partial(jax.jit, static_argnames=("precision",))
-def centered_gram_sharded(x: jax.Array, mean: jax.Array, precision: str = "highest") -> jax.Array:
-    """:func:`centered_gram` of rows SHARDED over a mesh's data axis (the
-    mesh fits' one contraction a chip, XLA inserting the all-reduce):
-    always the plain ``make_dot(precision)(b.T, b)``. On the four-pass form
-    XLA all-reduces ``h^T h``, ``m^T m`` and ``S`` apart, three (d, d)
-    matrices where this is one (compiled for a described v5e:2x2, PR 37),
-    so these callers keep ``HIGHEST`` until the four-chip cell moves them
-    onto a per-chip :func:`comoment_resident` (ROADMAP.md Reach 1)."""
-    bump_counter("gram.matmul")
-    b = x - mean
-    return make_dot(precision)(b.T, b)
-
-
 def mean_and_covariance(x: jax.Array, precision: str = "highest"):
     """Single-device path: returns (column means, covariance) of resident
     rows, summed in blocks (:func:`comoment_resident`).
@@ -321,13 +307,11 @@ def comoment_init(d: int, dtype=jnp.float64) -> tuple:
     )
 
 
-@jax.jit
-def comoment_merge(a: tuple, b: tuple) -> tuple:
-    """Chan's pairwise merge of two co-moment states. Exact algebra, no
-    approximation of the two-pass form: ``sum_b (X_b - m)^T (X_b - m) =
-    sum_b [(X_b - m_b)^T (X_b - m_b) + n_b (m_b - m)(m_b - m)^T]``."""
-    count_a, mean_a, lo_a, m_a = a
-    count_b, mean_b, lo_b, m_b = b
+def _merge_means(a: tuple, b: tuple) -> tuple:
+    """The ``(count, mean, mean_lo)`` part of :func:`comoment_merge`, and
+    the ``delta`` of the means and ``weight`` of ``b`` its ``M`` needs."""
+    count_a, mean_a, lo_a = a
+    count_b, mean_b, lo_b = b
     count = count_a + count_b
     # counts are weight sums under ``weightCol``: any positive total divides
     weight = count_b / jnp.where(count > 0, count, 1)
@@ -335,11 +319,19 @@ def comoment_merge(a: tuple, b: tuple) -> tuple:
     # rounding matters) differ by an exact float; the trailing pieces ride
     # along, and what the new leading piece rounds off joins them.
     delta_hi, delta_lo = mean_b - mean_a, lo_b - lo_a
-    delta = delta_hi + delta_lo
     shift = delta_hi * weight
     mean = mean_a + shift
     lo = lo_a + delta_lo * weight + (shift - (mean - mean_a))
-    m = m_a + m_b + jnp.outer(delta, delta) * (count_a * weight)
+    return count, mean, lo, delta_hi + delta_lo, weight
+
+
+@jax.jit
+def comoment_merge(a: tuple, b: tuple) -> tuple:
+    """Chan's pairwise merge of two co-moment states. Exact algebra, no
+    approximation of the two-pass form: ``sum_b (X_b - m)^T (X_b - m) =
+    sum_b [(X_b - m_b)^T (X_b - m_b) + n_b (m_b - m)(m_b - m)^T]``."""
+    count, mean, lo, delta, weight = _merge_means(a[:3], b[:3])
+    m = a[3] + b[3] + jnp.outer(delta, delta) * (a[0] * weight)
     return (count, mean, lo, m)
 
 
@@ -473,3 +465,63 @@ def comoment_resident(
     if n % step:
         state = add(state, n - n % step, n % step)
     return state
+
+
+@lru_cache(maxsize=None)
+def _comoment_sharded_fn(mesh, precision: str, center: bool):
+    """The jitted ``shard_map`` program of :func:`comoment_sharded` for one
+    (mesh, policy) — cached, as jit's cache is keyed on the function."""
+    from jax.sharding import PartitionSpec as P
+
+    from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS
+    from spark_rapids_ml_tpu.utils.compat import shard_map
+
+    chips = int(mesh.shape[DATA_AXIS])
+
+    def local(x):
+        # this chip's rows, summed in blocks as on one device
+        count, mean, lo, m = comoment_resident(x, precision=precision, center=center)
+        count = jnp.asarray(count, x.dtype)
+        counts, means, los = (
+            jax.lax.all_gather(a, DATA_AXIS) for a in (count, mean, lo)
+        )
+        # every chip folds the chips' (count, mean, mean_lo) alike, in
+        # comoment_merge's order and algebra
+        merged = (counts[0], means[0], los[0])
+        for c in range(1, chips):
+            merged = _merge_means(merged, (counts[c], means[c], los[c]))[:3]
+        # this chip's M about the merged means, then ONE (d, d) all-reduce:
+        # the two-pass identity that comoment_merge applies pair by pair
+        delta = (mean - merged[1]) + (lo - merged[2])
+        m = m + jnp.outer(delta, delta) * count
+        return (*merged, jax.lax.psum(m, DATA_AXIS))
+
+    fn = shard_map(
+        local,
+        mesh=mesh,
+        in_specs=(P(DATA_AXIS, None),),
+        out_specs=(P(), P(), P(), P()),
+        # the merged state is the same on every chip by construction
+        check_vma=False,
+    )
+    return jax.jit(fn)
+
+
+def comoment_sharded(
+    x: jax.Array, mesh, precision: str = "highest", center: bool = True
+) -> tuple:
+    """The co-moment state ``(count, mean, mean_lo, M)`` of rows SHARDED
+    over ``mesh``'s data axis, replicated: each chip sums its own rows in
+    blocks of :data:`GRAM_BLOCK_ROWS` (:func:`comoment_resident` under
+    ``shard_map``; a ``lax.scan`` over a sharded axis would gather every
+    block), then one merge across chips: the chips' counts and means
+    gathered (d floats a chip) and their ``M`` all-reduced ONCE about the
+    merged means. Rows must divide the data axis (a shard per chip)."""
+    return _comoment_sharded_fn(mesh, precision, center)(x)
+
+
+def count_sharded_blocks(n: int, chips: int) -> None:
+    """:func:`count_resident_blocks` of :func:`comoment_sharded`: every
+    chip's blocks of its own ``n / chips`` rows."""
+    bump_counter("gram.blocks", chips * -(-(n // chips) // GRAM_BLOCK_ROWS))
+    bump_counter("gram.rows", n)

@@ -25,14 +25,17 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from spark_rapids_ml_tpu.ops.linalg import _dot_precision
+from spark_rapids_ml_tpu.ops.precision import as_dot, make_dot
 from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS
+from spark_rapids_ml_tpu.utils.tracing import bump_counter
 
 
-def _block_sq_distances(q: jax.Array, xb: jax.Array, q_sq: jax.Array, prec) -> jax.Array:
-    """(nq, B) squared euclidean distances of queries to one item block."""
+def _block_sq_distances(q: jax.Array, xb: jax.Array, q_sq: jax.Array, dot) -> jax.Array:
+    """(nq, B) squared euclidean distances of queries to one item block;
+    ``dot`` is the policy's product (``ops/precision.py::make_dot``) or a
+    bare ``lax.Precision``."""
     xb_sq = jnp.sum(xb * xb, axis=1)
-    cross = jnp.matmul(q, xb.T, precision=prec)
+    cross = as_dot(dot)(q, xb.T)
     d2 = q_sq[:, None] - 2.0 * cross + xb_sq[None, :]
     return jnp.maximum(d2, 0.0)
 
@@ -44,6 +47,14 @@ def _auto_block_items(nq: int, n_items: int) -> int:
     shrinks blocks for large query batches (memory safety), floored at
     1024 so the scan stays coarse."""
     return min(n_items, 65536, max(1024, (1 << 29) // max(nq, 1)))
+
+
+def count_knn_blocks(nq: int, n_items: int, block_items: int | None = None) -> None:
+    """``knn.blocks``: the item blocks one :func:`knn_sq_euclidean` of
+    ``nq`` queries over ``n_items`` items scans (each a (nq, block)
+    distance product and a top-k merge), from the shapes, at dispatch."""
+    block = min(block_items or _auto_block_items(nq, n_items), n_items)
+    bump_counter("knn.blocks", -(-n_items // block))
 
 
 @partial(jax.jit, static_argnames=("k", "block_items", "precision", "approx"))
@@ -82,7 +93,7 @@ def knn_sq_euclidean(
         raise ValueError(f"k must be in [1, {n_items}], got {k}")
     if block_items is None:
         block_items = _auto_block_items(queries.shape[0], n_items)
-    prec = _dot_precision(precision)
+    dot = make_dot(precision)
     dtype = queries.dtype
     nq = queries.shape[0]
     q_sq = jnp.sum(queries * queries, axis=1)
@@ -105,7 +116,7 @@ def knn_sq_euclidean(
     def step(carry, blk):
         best_d, best_i = carry
         xb, mb, start = blk
-        d2 = _block_sq_distances(queries, xb, q_sq, prec)
+        d2 = _block_sq_distances(queries, xb, q_sq, dot)
         if need_mask:
             d2 = jnp.where(mb[None, :] > 0, d2, jnp.inf)
             # Masked (padded) items keep index -1 so that when k exceeds
@@ -182,10 +193,9 @@ def _merge_block_topk(best_d, best_i, queries, q_sq, xb, start, k,
     """One streamed-block update of the running (nq, k) top-k state —
     the same candidate-merge math as :func:`knn_sq_euclidean`'s scan step,
     jitted standalone so a HOST loop can drive it block by block."""
-    prec = _dot_precision(precision)
     nq = queries.shape[0]
     block = xb.shape[0]
-    d2 = _block_sq_distances(queries, xb, q_sq, prec)
+    d2 = _block_sq_distances(queries, xb, q_sq, make_dot(precision))
     idx = start + jnp.arange(block, dtype=jnp.int32)
     if approx:
         # A block smaller than k (ragged tail, fine-grained sources)
@@ -305,7 +315,7 @@ def _sharded_knn_fn(mesh, k: int, n_shard: int, precision: str, approx: bool = F
     from spark_rapids_ml_tpu.utils.compat import shard_map
     from jax.sharding import PartitionSpec as P
 
-    prec = _dot_precision(precision)
+    dot = make_dot(precision)
     k_loc = min(k, n_shard)
 
     def _local(q, x_blk, m_blk):
@@ -314,7 +324,7 @@ def _sharded_knn_fn(mesh, k: int, n_shard: int, precision: str, approx: bool = F
         # shard_map's varying-axis tracking; see test_knn).
         shard_i = lax.axis_index(DATA_AXIS)
         q_sq = jnp.sum(q * q, axis=1)
-        d2 = _block_sq_distances(q, x_blk, q_sq, prec)
+        d2 = _block_sq_distances(q, x_blk, q_sq, dot)
         d2 = jnp.where(m_blk[None, :] > 0, d2, jnp.inf)
         if approx:
             # Hardware partial-reduce per shard; the all-gathered

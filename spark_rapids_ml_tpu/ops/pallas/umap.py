@@ -103,7 +103,12 @@ def build_tail_plan(
     last = np.ceil(stop / edges_per_block).astype(np.int64)
     nblk = np.maximum(last - base, 0)
     nblk[stop <= start] = 0
-    max_nblocks = max(int(nblk.max()), 1) if n_tiles else 1
+    # The widest run is the grid's static extent: rounded up to a power of
+    # two so that the layout program is one a shape and not one a graph
+    # (each new graph recompiled it: 63 s a fit at 250,000 rows on the
+    # chip, PERF.md section 6); steps past a tile's run are skipped.
+    widest = max(int(nblk.max()), 1) if n_tiles else 1
+    max_nblocks = 1 << (widest - 1).bit_length()
 
     cfg = TailCfg(
         n=n, dim=dim, sub=dim + (-dim) % 8, e=e, e_pad=e_pad, n_pad=n_pad,

@@ -27,6 +27,13 @@ import numpy as np
 from jax import lax
 
 
+def _pool_dot(a: jax.Array, b: jax.Array) -> jax.Array:
+    """The negative pool's products (``y @ pool.T``, ``c @ pool``) at
+    ``HIGHEST``: the layout's coordinates go to tens or more, and one bf16
+    pass of them is no distance (see :func:`optimize_layout`)."""
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
 class FuzzyGraph(NamedTuple):
     """Directed kNN edge list with symmetrized membership weights.
 
@@ -258,7 +265,12 @@ def _make_epoch_fn(
             pool = (y if target is None else target)[pool_idx]  # (s, dim)
             y2 = jnp.sum(y * y, axis=1)  # (n,)
             p2 = jnp.sum(pool * pool, axis=1)  # (s,)
-            cross = y @ pool.T  # (n, s) GEMM
+            # Both pool products at HIGHEST: at the chip's default (one
+            # bf16 pass) the norm expansion below cancels, near pairs read
+            # d2n ~ 0, the clip's cap / sqrt(d2n) lets the coefficient reach
+            # ~1e6 and rows are flung (embedding spread 5,324 at 250,000 x
+            # 3000 on the chip, PERF.md section 6).
+            cross = _pool_dot(y, pool.T)  # (n, s) GEMM
             d2n = jnp.maximum(y2[:, None] + p2[None, :] - 2.0 * cross, 0.0)
             rep = (2.0 * repulsion * b) / (
                 (0.001 + d2n) * (1.0 + a * jnp.power(d2n, b))
@@ -273,7 +285,7 @@ def _make_epoch_fn(
             cap = 4.0 * k * neg_rate / neg_pool
             c = jnp.minimum(c, cap / jnp.sqrt(d2n + 1e-12))
             g_rep_head = (
-                jnp.sum(c, axis=1, keepdims=True) * y - c @ pool
+                jnp.sum(c, axis=1, keepdims=True) * y - _pool_dot(c, pool)
             )  # (n, dim): sum_p c_ip (y_i - pool_p), factorized
             grad_head = jnp.sum(g_att, axis=1) + g_rep_head
         else:
@@ -463,7 +475,7 @@ def _sharded_layout_fn(
                 pool = y[pool_idx]  # (s, dim) — y is replicated
                 yh2 = jnp.sum(yh * yh, axis=1)
                 p2 = jnp.sum(pool * pool, axis=1)
-                cross = yh @ pool.T  # (n_local, s)
+                cross = _pool_dot(yh, pool.T)  # (n_local, s), HIGHEST as above
                 d2n = jnp.maximum(
                     yh2[:, None] + p2[None, :] - 2.0 * cross, 0.0
                 )
@@ -473,7 +485,7 @@ def _sharded_layout_fn(
                 c = rep * (w_sum_b[:, None] * (neg_rate / neg_pool))
                 cap = 4.0 * k_nbrs * neg_rate / neg_pool
                 c = jnp.minimum(c, cap / jnp.sqrt(d2n + 1e-12))
-                g_rep_head = jnp.sum(c, axis=1, keepdims=True) * yh - c @ pool
+                g_rep_head = jnp.sum(c, axis=1, keepdims=True) * yh - _pool_dot(c, pool)
                 grad_head = jnp.sum(g_att, axis=1) + g_rep_head
             else:
                 neg_idx = jax.random.randint(

@@ -281,16 +281,24 @@ NvtxRange = TraceRange
 
 # --- stages: the leaves of the host fit path ---
 
-#: Every stage a fit path opens. The counters ``fit.stage.<stage>.ns`` and
-#: ``.calls`` (and ``.bytes`` for ``densify`` and ``place``, whose sites know
-#: the size: :meth:`StageRange.count_bytes`) are what
+#: Every stage the host fit path opens. The counters ``fit.stage.<stage>.ns``
+#: and ``.calls`` (and ``.bytes`` for ``densify`` and ``place``, whose sites
+#: know the size: :meth:`StageRange.count_bytes`) are what
 #: ``perfbench/metrics/host_*`` read.
 STAGES = ("admit", "densify", "convert", "place", "solve")
-_STAGE_COUNTERS = {s: (f"fit.stage.{s}.ns", f"fit.stage.{s}.calls") for s in STAGES}
+#: The stages of a UMAP fit after its admission and placement
+#: (``models/umap.py``): the kNN graph up to the host's read of its
+#: indices, the host sort of its edges by tail, the layout epochs; read by
+#: ``perfbench/metrics/umap_*_ms``.
+UMAP_STAGES = ("knn", "plan", "layout")
+_STAGE_COUNTERS = {
+    s: (f"fit.stage.{s}.ns", f"fit.stage.{s}.calls") for s in STAGES + UMAP_STAGES
+}
 
 
 class StageRange(TraceRange):
-    """A :class:`TraceRange` named for one of :data:`STAGES` whose exit also
+    """A :class:`TraceRange` named for one of :data:`STAGES` (or
+    :data:`UMAP_STAGES`) whose exit also
     adds its duration to ``fit.stage.<stage>.ns`` and 1 to
     ``fit.stage.<stage>.calls``.
 
@@ -304,13 +312,15 @@ class StageRange(TraceRange):
     PlacementWindow``): in a pass over host partitions it holds the
     placement call AND the wait for room before it (the read of the oldest
     placement in flight; ``ingest.place.wait_ns`` is that part alone).
+    UMAP's ``layout`` ends by waiting for the embedding (``models/umap.py``:
+    the fit returns a finished one), so it holds the epochs' device time.
     """
 
     __slots__ = ()
 
     def __init__(self, stage: str, color: Optional[TraceColor] = None):
         if stage not in _STAGE_COUNTERS:
-            raise ValueError(f"unknown stage {stage!r}: one of {STAGES}")
+            raise ValueError(f"unknown stage {stage!r}: one of {STAGES + UMAP_STAGES}")
         super().__init__(stage, color)
 
     def __exit__(self, exc_type=None, exc=None, tb=None) -> None:

@@ -10,9 +10,10 @@ import pytest
 
 from spark_rapids_ml_tpu.ops import precision
 from spark_rapids_ml_tpu.ops.covariance import (
+    GRAM_BLOCK_ROWS,
     centered_gram,
-    centered_gram_sharded,
     comoment_resident,
+    comoment_sharded,
 )
 from spark_rapids_ml_tpu.utils.tracing import clear_counters, counters
 
@@ -106,17 +107,70 @@ def test_every_other_case_is_the_plain_product_bit_for_bit(rng, mode):
     assert np.array_equal(np.asarray(got), np.asarray(want))
 
 
-def test_rows_sharded_over_a_mesh_keep_the_plain_product(rng):
-    """``centered_gram_sharded`` (the mesh fits' one contraction a chip) is
-    the plain ``HIGHEST`` product at every precision, counted as such."""
-    x = jnp.asarray(_rows(rng, 2_000, 70, off=3.0))
-    mean = jnp.mean(x, axis=0)
-    _fresh_counters()
-    got = centered_gram_sharded(x, mean)
-    assert counters("gram.") == {"gram.matmul": 1}
-    b = x - mean
-    want = jax.jit(lambda b: precision.make_dot("highest")(b.T, b))(b)
-    assert np.array_equal(np.asarray(got), np.asarray(want))
+def _mesh4():
+    from jax.sharding import Mesh
+
+    from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS
+
+    return Mesh(np.array(jax.devices()[:4]), (DATA_AXIS,))
+
+
+def _on_mesh(x, mesh):
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS
+
+    return jax.device_put(x, NamedSharding(mesh, P(DATA_AXIS, None)))
+
+
+def _float64_moments(x):
+    x = np.asarray(x, dtype=np.float64)
+    mean = x.mean(axis=0)
+    c = x - mean
+    return len(x), mean, c.T @ c
+
+
+@pytest.mark.parametrize("center", [True, False])
+def test_rows_sharded_over_a_mesh_sum_in_blocks_a_chip(rng, center):
+    """``comoment_sharded`` on 4 devices, 2.5 blocks a chip, float32 columns
+    far from zero: the merged state against a float64 sum of all the rows,
+    and as close to it as one device's blocked sum."""
+    n = 4 * (2 * GRAM_BLOCK_ROWS + GRAM_BLOCK_ROWS // 2)
+    x32 = _rows(rng, n, 6, off=50.0)
+    mesh = _mesh4()
+    count, mean, lo, m = comoment_sharded(_on_mesh(jnp.asarray(x32), mesh), mesh, center=center)
+    want_n, want_mean, want_m = _float64_moments(x32)
+    if not center:
+        want_m = x32.astype(np.float64).T @ x32.astype(np.float64)
+        want_mean = np.zeros_like(want_mean)
+    scale = np.sqrt(np.outer(np.diag(want_m), np.diag(want_m)))
+    assert float(count) == want_n
+    np.testing.assert_allclose(np.asarray(mean) + np.asarray(lo), want_mean, rtol=1e-7, atol=1e-7)
+    err = np.max(np.abs(np.asarray(m, dtype=np.float64) - want_m) / scale)
+    one = comoment_resident(jnp.asarray(x32), center=center)[3]
+    one_err = np.max(np.abs(np.asarray(one, dtype=np.float64) - want_m) / scale)
+    assert err < 1e-6 and err < 4 * one_err + 1e-9, (err, one_err)
+
+
+def test_the_mesh_pca_fit_reads_float64(rng):
+    """``PCA(mesh=...)`` on device rows sharded over 4 devices: the fused fit
+    on the per-chip blocked co-moments against float64, and a model fitted
+    on one chip's share of the rows (the four-chip cell's ``one_chip_rows``
+    fault) is told apart by its explained variance."""
+    from spark_rapids_ml_tpu.feature import PCA
+
+    n, d = 4 * 12_000, 10
+    spread = np.linspace(3.0, 0.5, d)
+    x32 = (rng.normal(size=(n, d)) * spread + 40.0).astype(np.float32)
+    mesh = _mesh4()
+    model = PCA(mesh=mesh).setK(3).fit(_on_mesh(jnp.asarray(x32), mesh))
+    _, _, m = _float64_moments(x32)
+    w = np.linalg.eigvalsh(m)[::-1]
+    want = w[:3] / w.sum()
+    got = np.asarray(model.explainedVariance)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    share = PCA().setK(3).fit(jnp.asarray(x32[: n // 4]))
+    assert np.max(np.abs(np.asarray(share.explainedVariance) - want) / want) > 1e-4
 
 
 @pytest.mark.parametrize(

@@ -299,9 +299,10 @@ class TestBlockedGramFourPasses:
         self._check(compiled, 3001)
 
     def test_the_mesh_fit_all_reduces_one_gram(self):
-        """Rows sharded over a described v5e:2x2 (the mesh fit's one
-        contraction a chip): the four-pass form would all-reduce three
-        (d, d) matrices, so this route keeps the plain product and its ONE."""
+        """Rows sharded over a described v5e:2x2 (``pca_3000.mesh4``'s
+        program): each chip sums its 500,000 rows in blocks of four
+        single-pass products, and the merge across chips all-reduces ONE
+        (d, d) matrix, not ``h^T h``, ``m^T m`` and ``S`` apart."""
         import re
 
         from jax.experimental import topologies
@@ -318,9 +319,11 @@ class TestBlockedGramFourPasses:
         rows = jax.ShapeDtypeStruct(
             (2_000_000, 3000), jnp.float32, sharding=NamedSharding(mesh, P(DATA_AXIS, None))
         )
-        text = _pca_fit_device.lower(
+        compiled = _pca_fit_device.lower(
             rows, 3, center=True, precision="highest", eigen_solver="topk",
-            eigen_iters=8, blocked=False,
-        ).compile().as_text()
+            eigen_iters=8, mesh=mesh,
+        ).compile()
+        self._check(compiled, 3000)
+        text = compiled.as_text()
         reduced = re.findall(r"= (.+?) all-reduce(?:-start)?\(", text)
         assert sum(r.count("f32[3000,3000]") for r in reduced) == 1, reduced

@@ -400,3 +400,126 @@ class TestTailScatterPallas:
         )
         t_xla = manifold.trustworthiness(x[sub], fit("xla")[sub], n_neighbors=10)
         assert abs(t_pallas - t_xla) < 0.05
+
+
+def _wide_blobs(n=2000, d=64, seed=11):
+    """The benchmark's law at a CPU size: 3 centres uniform in (-10, 10),
+    unit noise (perfbench/data.py::blobs, sklearn's make_blobs)."""
+    rng = np.random.default_rng(seed)
+    mu = rng.uniform(-10, 10, size=(3, d))
+    return (mu[rng.integers(0, 3, size=n)] + rng.normal(size=(n, d))).astype(np.float32)
+
+
+class TestFittedGraph:
+    """The directed kNN graph a fit keeps, its precision, its stages."""
+
+    def test_graph_is_the_exact_neighbours_and_distances(self):
+        x = _wide_blobs()
+        model = UMAP().setNNeighbors(15).setNEpochs(30).setSeed(3).fit(x)
+        idx, dist = model.knnIndices, model.knnDists
+        assert idx.shape == dist.shape == (2000, 15)
+        # the plain reference: float64 differences, the row itself left out
+        x64 = x.astype(np.float64)
+        d2 = np.sum((x64[:, None, :] - x64[None, :, :]) ** 2, axis=2)
+        np.fill_diagonal(d2, np.inf)
+        want = np.sqrt(np.sort(d2, axis=1)[:, :15])
+        got = np.sqrt(np.take_along_axis(d2, idx.astype(np.int64), axis=1))
+        # every returned neighbour is as near as the reference's j-th (ties
+        # within float32's rounding of a squared distance may swap places)
+        assert np.max((got - want) / want) < 1e-5
+        assert np.mean(np.sort(idx, axis=1) == np.sort(np.argsort(d2, axis=1)[:, :15], axis=1)) > 0.99
+        np.testing.assert_allclose(dist, got, rtol=2e-5)
+        assert np.all(np.diff(dist, axis=1) >= 0)
+        assert not np.any(idx == np.arange(2000)[:, None])
+
+    def test_precision_reaches_the_knn_product(self, monkeypatch):
+        import spark_rapids_ml_tpu.models.umap as umap_mod
+
+        seen = []
+        real = umap_mod.knn
+
+        def spy(*args, **kwargs):
+            seen.append(kwargs.get("precision"))
+            return real(*args, **kwargs)
+
+        monkeypatch.setattr(umap_mod, "knn", spy)
+        x = _wide_blobs(n=600)
+        exact = UMAP().setNEpochs(5).fit(x)
+        assert UMAP().getPrecision() == "highest" and seen == ["highest"]
+        one_pass = UMAP().setPrecision("bf16").setNEpochs(5).fit(x)
+        assert seen == ["highest", "bf16"]
+        # one bf16 pass rounds the product: other distances, other neighbours
+        assert np.max(np.abs(one_pass.knnDists - exact.knnDists) / exact.knnDists) > 1e-4
+        with pytest.raises(ValueError):
+            UMAP().setPrecision("half")
+
+    @pytest.mark.parametrize("scatter", ["xla", "pallas"])
+    def test_stages_and_counters(self, monkeypatch, scatter):
+        from spark_rapids_ml_tpu.utils import tracing
+
+        monkeypatch.setenv("TPUML_UMAP_SCATTER", scatter)
+        x = _wide_blobs(n=1200, d=32)
+        before = tracing.counters()
+        UMAP().setNNeighbors(15).setNEpochs(30).fit(x)
+        got = {k: v - before.get(k, 0) for k, v in tracing.counters().items()}
+        plan = scatter == "pallas"
+        assert got["fit.stage.knn.calls"] == got["fit.stage.layout.calls"] == 1
+        assert got.get("fit.stage.plan.calls", 0) == int(plan)
+        assert got.get("umap.layout.pallas", 0) == int(plan)
+        assert got["umap.layout.epochs"] == 30
+        assert got["knn.blocks"] == 1  # 1,200 items fit one block
+        assert got["fit.stage.knn.ns"] > 0 and got["fit.stage.layout.ns"] > 0
+
+    def test_knn_blocks_count_the_scan(self):
+        from spark_rapids_ml_tpu.ops.knn import _auto_block_items, count_knn_blocks
+        from spark_rapids_ml_tpu.utils import tracing
+
+        before = tracing.counter_value("knn.blocks")
+        count_knn_blocks(250_000, 250_000)
+        assert _auto_block_items(250_000, 250_000) == 2147
+        assert tracing.counter_value("knn.blocks") - before == 117
+
+    def test_graph_is_not_saved(self, tmp_path):
+        import os
+
+        x = _wide_blobs(n=300, d=16)
+        model = UMAP().setNEpochs(10).setSeed(4).fit(x)
+        assert model.knnIndices is not None
+        path = str(tmp_path / "umap")
+        model.save(path)
+        loaded = UMAPModel.load(path)
+        assert loaded.knnIndices is None and loaded.knnDists is None
+        np.testing.assert_allclose(model.embedding, loaded.embedding, atol=1e-12)
+        from spark_rapids_ml_tpu.core.persistence import load_data
+
+        assert set(load_data(path)) == {"embedding", "trainData"}
+        # the same files as a model that never had a graph
+        bare = str(tmp_path / "bare")
+        UMAPModel(model.uid, model.embedding, model.trainData, model.a, model.b).save(bare)
+        assert sorted(os.listdir(path)) == sorted(os.listdir(bare))
+        copied = model.copy()
+        np.testing.assert_array_equal(copied.knnIndices, model.knnIndices)
+
+
+def test_layout_products_are_full_precision():
+    """The negative pool's two products (``y @ pool.T``, ``c @ pool``) ask
+    for ``HIGHEST``: at a chip's default one bf16 pass the squared distances
+    cancel to nought for near pairs and the clipped repulsion flings rows
+    (a CPU computes float32 whatever is asked, so only the request is
+    checkable here)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from spark_rapids_ml_tpu.ops.umap import FuzzyGraph, optimize_layout
+
+    n, k = 64, 5
+    graph = FuzzyGraph(jnp.zeros((n, k), jnp.int32), jnp.ones((n, k), jnp.float32),
+                       jnp.ones(n, jnp.float32), jnp.zeros(n, jnp.float32))
+    text = optimize_layout.lower(
+        jnp.zeros((n, 2), jnp.float32), graph, jax.random.key(0), n_epochs=2, neg_pool=16
+    ).as_text()
+    dots = re.findall(r"dot_general.*", text)
+    assert len(dots) == 2, dots
+    assert all("precision = [HIGHEST, HIGHEST]" in d for d in dots), dots
